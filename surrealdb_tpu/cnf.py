@@ -278,9 +278,9 @@ KV_2PC_RESOLVE_INTERVAL_S = env_float(
     "SURREAL_KV_2PC_RESOLVE_INTERVAL_S", 0.5
 )
 
-# -- accelerator backend init watchdog (bench.py / __graft_entry__.py,
-# generalized to serving by the device supervisor's init watchdog) -----------
-# device discovery that exceeds this degrades to CPU instead of hanging
+# -- accelerator backend init watchdog (device supervisor, bench.py) ----------
+# a runner whose device discovery exceeds this is killed: the serving
+# path degrades to host execution (auto) or fails the query (require)
 BACKEND_INIT_TIMEOUT_S = env_float("SURREAL_BACKEND_INIT_TIMEOUT_S", 240.0)
 
 # -- device execution supervisor (device/supervisor.py) ----------------------
@@ -318,11 +318,6 @@ DEVICE_PROMOTE_SUCCESSES = env_int("SURREAL_DEVICE_PROMOTE_SUCCESSES", 2)
 DEVICE_BATCH_PIPELINE = env_int("SURREAL_DEVICE_BATCH_PIPELINE", 2)
 DEVICE_BATCH_PIPELINE_MIN = env_int("SURREAL_DEVICE_BATCH_PIPELINE_MIN",
                                     32)
-# persistent XLA compilation cache (device/compile_cache.py): compiled
-# kernels survive runner restarts and degrade→re-promote cycles.
-# "" resolves to <datastore dir>/.xla-cache for disk-backed stores,
-# else ~/.cache/surrealdb-tpu/xla; "off" disables.
-DEVICE_COMPILE_CACHE_DIR = env_str("SURREAL_DEVICE_COMPILE_CACHE_DIR", "")
 # power-of-two query-bucket ladder pre-warmed right after a vec store
 # ships to the runner ("" disables). With the persistent compile cache
 # warm these are near-free; cold, they front-load the XLA compiles so

@@ -1,22 +1,22 @@
 """Persistent XLA compilation cache for the device runner.
 
-The supervisor's crash/degrade/restart discipline (PR 4) made the
-runner crash-only — but every restart paid cold XLA compiles for every
-kernel shape before serving at full speed. Initializing
-`jax.experimental.compilation_cache` (SNIPPETS.md [1]/[3]:
-`cc.initialize_cache`) persists compiled executables to disk, so a
-respawned runner (and a degrade→re-promote cycle) resumes at full
-speed: the in-process "miss" becomes a cache-file load.
+The supervisor's crash/degrade/restart discipline made the runner
+crash-only, and every start of the program compiles every kernel shape
+before serving at full speed. jax's persistent compilation cache keeps
+compiled executables on disk, so a respawned runner — and the next run
+of the program — loads them instead.
 
-Directory resolution (first match wins):
-  1. `SURREAL_DEVICE_COMPILE_CACHE_DIR` — `off` disables entirely;
-  2. a process default registered by a disk-backed Datastore
-     (`<datastore dir>/.xla-cache` — the cache lives with the data);
-  3. `~/.cache/surrealdb-tpu/xla`.
+The directory is part of the cache key's lookup, so it must not move:
 
-This module never imports jax at module level (the serving process
-imports it for dir resolution; only the runner/inline host calls
-`initialize()`, which is where jax is already live).
+  1. `JAX_COMPILATION_CACHE_DIR` set: jax reads it natively and this
+     module sets no other (the environment places the cache);
+  2. otherwise one fixed path in the checkout, `<repo>/.jax_cache`,
+     derived from this package's location — never from a datastore
+     directory, the home directory, a temporary name, a pid or a clock.
+
+Only the dedicated runner subprocess calls `initialize()`; inline mode
+shares the serving process's jax and gets a cache only through (1).
+This module never imports jax at module level.
 """
 
 from __future__ import annotations
@@ -24,94 +24,53 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-from surrealdb_tpu import cnf
-
-_DEFAULT_DIR: Optional[str] = None
 _INITIALIZED: Optional[dict] = None
 
-
-def set_default_dir(path: Optional[str]):
-    """Register the datastore-derived default cache dir (a disk-backed
-    Datastore calls this with <its dir>/.xla-cache). Explicit env
-    configuration still wins."""
-    global _DEFAULT_DIR
-    _DEFAULT_DIR = path
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+)))
 
 
-def configured_dir() -> Optional[str]:
-    """An EXPLICITLY configured dir (env knob or registered datastore
-    default) — no home fallback. None when unconfigured or off."""
-    configured = cnf.env_str("SURREAL_DEVICE_COMPILE_CACHE_DIR",
-                             cnf.DEVICE_COMPILE_CACHE_DIR)
-    if configured:
-        return None if configured.lower() == "off" else configured
-    return _DEFAULT_DIR
+def cache_dir() -> str:
+    """The one directory compiled kernels persist in."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def resolve_dir() -> Optional[str]:
-    """The cache directory this process would use; None = disabled.
-    Like `configured_dir` but with the home-dir fallback the dedicated
-    runner subprocess uses when nothing was configured."""
-    configured = cnf.env_str("SURREAL_DEVICE_COMPILE_CACHE_DIR",
-                             cnf.DEVICE_COMPILE_CACHE_DIR)
-    if configured and configured.lower() == "off":
-        return None
-    return (configured_dir()
-            or os.path.join(os.path.expanduser("~"), ".cache",
-                            "surrealdb-tpu", "xla"))
+def entry_count(path: str) -> int:
+    """Cache entries on disk (jax writes `<key>-cache` payloads next
+    to `<key>-atime` access stamps; only the payloads are entries)."""
+    try:
+        return sum(1 for e in os.scandir(path)
+                   if not e.name.endswith("-atime"))
+    except OSError:
+        return 0
 
 
-def initialize(path: Optional[str] = None) -> dict:
-    """Point jax's persistent compilation cache at the resolved dir.
-    Idempotent; returns {"dir": ..., "entries": N} on success or
-    {"disabled": reason}. Never raises — a broken cache dir must cost
-    speed, not serving."""
+def initialize() -> dict:
+    """Point jax's persistent compilation cache at `cache_dir()` and
+    cache every kernel regardless of size or compile time. Idempotent;
+    returns {"dir", "entries", "from_env"}."""
     global _INITIALIZED
     if _INITIALIZED is not None:
         return _INITIALIZED
-    d = path or resolve_dir()
-    if d is None:
-        _INITIALIZED = {"disabled": "configured off"}
-        return _INITIALIZED
-    try:
-        os.makedirs(d, exist_ok=True)
-        import jax
+    import jax
 
+    d = cache_dir()
+    from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    os.makedirs(d, exist_ok=True)
+    if not from_env:
         jax.config.update("jax_compilation_cache_dir", d)
-        # small serving kernels compile in well under the default 1s
-        # floor — cache everything, the bucket ladder bounds the count
-        for knob, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass  # knob not present on this jax version
-        try:
-            # jax latches its cache handle at the first compile: a
-            # process that already compiled something without a dir
-            # (inline mode after serving traffic) must drop the latch
-            # or the new dir is silently ignored
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-        try:
-            entries = sum(1 for _ in os.scandir(d))
-        except OSError:
-            entries = 0
-        _INITIALIZED = {"dir": d, "entries": entries}
-    except Exception as e:
-        _INITIALIZED = {"disabled": f"{e.__class__.__name__}: {e}"}
+    # small serving kernels compile in well under the default 1 s
+    # floor — cache everything, the bucket ladder bounds the count
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _INITIALIZED = {"dir": d, "entries": entry_count(d),
+                    "from_env": from_env}
     return _INITIALIZED
 
 
 def reset_for_tests():
-    """Drop the idempotence latch (the restart-survival test
-    re-initializes against a fresh tmpdir)."""
+    """Drop the idempotence latch."""
     global _INITIALIZED
     _INITIALIZED = None

@@ -46,19 +46,20 @@ def _vec_estimate(n: int, dim: int, itemsize: int, meta: dict,
     )
 
 
+def _store_ndev(store) -> int:
+    """Devices a store's arrays actually live on, for REPORTING: the
+    mesh stores' width, and equally the legacy VecStore's when it
+    self-sharded over `jax.devices()` (device/vecstore.py `ensure`).
+    Budget admission keeps reading `mesh_ndev`, which the legacy store
+    lacks on purpose — its estimate is already a per-chip share."""
+    mesh = getattr(store, "mesh", None)
+    return int(mesh.devices.size) if mesh is not None else 1
+
+
 class DeviceHost:
     """Per-runner registry of vector + CSR block caches."""
 
     def __init__(self):
-        # inline mode shares the serving process's jax: only point it at
-        # a persistent compile cache when one was explicitly configured
-        # (env knob or a disk-backed datastore default) — the home-dir
-        # fallback is for the dedicated runner subprocess only
-        from surrealdb_tpu.device import compile_cache
-
-        d = compile_cache.configured_dir()
-        if d is not None:
-            compile_cache.initialize(d)
         self.vec: OrderedDict = OrderedDict()  # key -> (tag, VecStore)
         self.csr: OrderedDict = OrderedDict()  # key -> (tag, CsrStore)
         self.ann: OrderedDict = OrderedDict()  # key -> (tag, AnnStore)
@@ -85,6 +86,9 @@ class DeviceHost:
         ) << 20
         self.oom_refusals = 0
         self.budget_evictions = 0
+        # dispatches answered "ok" per op since this runner started —
+        # the runner's own evidence of what ran on the device
+        self.op_counts: dict = {}  # robust: mem-account (one int per op name, bounded by the op table)
         # multipart install reservations: key -> final install SHARE
         # (device-0 bytes) admitted at *_load_begin but not yet
         # resident. Counted by mem_used()/mem_used_device0() so a
@@ -237,7 +241,10 @@ class DeviceHost:
         fn = getattr(self, f"op_{op}", None)
         if fn is None:
             raise ValueError(f"unknown device op {op!r}")
-        return fn(meta, bufs)
+        out = fn(meta, bufs)
+        if out[0] == "ok":
+            self.op_counts[op] = self.op_counts.get(op, 0) + 1
+        return out
 
     def op_ping(self, meta, bufs):
         return "ok", {}, []
@@ -250,12 +257,28 @@ class DeviceHost:
 
         def _sharded(cache):
             return sum(1 for _t, s in cache.values()
-                       if getattr(s, "mesh_ndev", 1) > 1)
+                       if _store_ndev(s) > 1)
 
         devs = jax.devices()
+        per_device = []
+        for d in devs:
+            # None where the backend keeps no allocator stats (cpu)
+            ms = d.memory_stats() or {}
+            per_device.append({
+                "id": d.id,
+                "bytes_in_use": ms.get("bytes_in_use"),
+                "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
+                "bytes_limit": ms.get("bytes_limit"),
+            })
+        cdir = jax.config.jax_compilation_cache_dir
         return "ok", {
             "platform": devs[0].platform if devs else "none",
+            "device_kind": devs[0].device_kind if devs else None,
             "device_count": len(devs),
+            "devices": per_device,
+            "ops": dict(self.op_counts),
+            "rank_modes": sorted({str(s.rank_mode)
+                                  for _t, s in self.vec.values()}),
             "mesh": dict(devmesh.describe(),
                          sharded_vec=_sharded(self.vec),
                          sharded_ann=_sharded(self.ann),
@@ -271,8 +294,12 @@ class DeviceHost:
             "mem_budget": self.budget_bytes,
             "oom_refusals": self.oom_refusals,
             "budget_evictions": self.budget_evictions,
-            "compile_cache": compile_cache.initialize()
-            if compile_cache.configured_dir() else {"disabled": "unset"},
+            "compile_cache": {
+                "dir": cdir, "entries": compile_cache.entry_count(cdir),
+            } if cdir else {"disabled": "unset"},
+            "compile": dict(
+                kernelstats.COMPILE, backend_compile_s=dict(
+                    kernelstats.COMPILE["backend_compile_s"])),
             "cc": kernelstats.snapshot(),
         }, []
 
@@ -293,7 +320,7 @@ class DeviceHost:
         while len(self.vec) > MAX_VEC_STORES:
             self.vec.popitem(last=False)
         return "ok", {"rank_mode": st.rank_mode,
-                      "mesh_ndev": getattr(st, "mesh_ndev", 1)}, []
+                      "mesh_ndev": _store_ndev(st)}, []
 
     @staticmethod
     def _vec_store(key, vecs, valid, meta, ndev: int):
@@ -365,7 +392,7 @@ class DeviceHost:
         while len(self.vec) > MAX_VEC_STORES:
             self.vec.popitem(last=False)
         return "ok", {"rank_mode": st.rank_mode,
-                      "mesh_ndev": getattr(st, "mesh_ndev", 1)}, []
+                      "mesh_ndev": _store_ndev(st)}, []
 
     def op_vec_drop(self, meta, bufs):
         self.vec.pop(meta["key"], None)
@@ -379,7 +406,7 @@ class DeviceHost:
             return "stale", {}, []
         self.vec.move_to_end(meta["key"])
         out_meta, out_bufs = ent[1].knn(bufs[0], int(meta["k"]))
-        out_meta.setdefault("mesh_ndev", getattr(ent[1], "mesh_ndev", 1))
+        out_meta.setdefault("mesh_ndev", _store_ndev(ent[1]))
         return "ok", out_meta, out_bufs
 
     def _prewarm_shapes(self, cache, meta, field, warm_one):
@@ -440,7 +467,7 @@ class DeviceHost:
         self.ann[key] = (list(tag), st)
         while len(self.ann) > MAX_ANN_STORES:
             self.ann.popitem(last=False)
-        return "ok", {"mesh_ndev": getattr(st, "mesh_ndev", 1)}, []
+        return "ok", {"mesh_ndev": _store_ndev(st)}, []
 
     def op_ann_load(self, meta, bufs):
         graph, x8, arow, x2q = bufs
@@ -511,7 +538,7 @@ class DeviceHost:
         self.ann.move_to_end(meta["key"])
         cand = ent[1].search(bufs[0], int(meta["kc"]))
         return "ok", {"mode": "cand",
-                      "mesh_ndev": getattr(ent[1], "mesh_ndev", 1)}, \
+                      "mesh_ndev": _store_ndev(ent[1])}, \
             [cand]
 
     def op_ann_prewarm(self, meta, bufs):
@@ -559,7 +586,7 @@ class DeviceHost:
         mask = ent[1].multi_hop(
             bufs[0], int(meta["hops"]), bool(meta["union"])
         )
-        return "ok", {"mesh_ndev": getattr(ent[1], "mesh_ndev", 1)}, \
+        return "ok", {"mesh_ndev": _store_ndev(ent[1])}, \
             [mask]
 
     def op_csr_prewarm(self, meta, bufs):

@@ -23,8 +23,44 @@ _SEEN: set = set()
 MESH_LAST = {"ndev": 0}
 
 
+# jax's own compile accounting (jax.monitoring listeners, installed by
+# the runner): seconds inside XLA backend compile per jitted function
+# — a persistent-cache hit spends only its load time there — and the
+# persistent cache's hit/miss counts. What `chip_smoke.py` reports as
+# cold/warm compile time per kernel.
+# lint: mem-account(one float per jitted function name in the tree, plus two counters)
+COMPILE = {"backend_compile_s": {}, "persistent_hits": 0,
+           "persistent_misses": 0}
+# called with the kernel name right before a first-shape dispatch
+# compiles: the runner tells the supervisor, whose dispatch window then
+# covers a compile instead of reading it as a wedge
+ON_COMPILE = None
+
+
 def note_compile(kernel: str):
     COUNTS["misses"] += 1
+    if ON_COMPILE is not None:
+        ON_COMPILE(kernel)
+
+
+def install_jax_listeners():
+    """Feed COMPILE from jax.monitoring (call once, jax already up)."""
+    import jax.monitoring as mon
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            by_fn = COMPILE["backend_compile_s"]
+            name = str(kw.get("fun_name", "?"))
+            by_fn[name] = by_fn.get(name, 0.0) + float(secs)
+
+    def on_event(event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            COMPILE["persistent_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            COMPILE["persistent_misses"] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)
+    mon.register_event_listener(on_event)
 
 
 def note_hit(kernel: str):
@@ -56,7 +92,7 @@ def note_shape(kernel: str, shape_key) -> bool:
     if len(_SEEN) >= _SEEN_MAX:
         _SEEN.clear()
     _SEEN.add(key)
-    COUNTS["misses"] += 1
+    note_compile(kernel)
     return False
 
 

@@ -23,10 +23,20 @@ runner is SIGKILLed and the state degrades; a wait cut short by a small
 query budget merely orphans that one request (the runner may be healthy
 and mid-kernel — killing it would thrash under tight deadlines).
 
+A compile is not a wedge. The runner announces every first-shape
+dispatch with a `compiling` frame before it enters XLA; from then until
+that dispatch replies, no waiter — the compiling one or those queued
+behind the single-threaded runner — is timed out by the dispatch
+window, only by the load window (`SURREAL_DEVICE_LOAD_TIMEOUT_S`, the
+bound on one compile) or its own query budget. When the reply lands,
+queued waiters get a fresh dispatch window.
+
 Modes (`SURREAL_DEVICE`): `off` (host paths only), `auto` (default:
-supervised subprocess, degrade-and-recover), `require` (failures
-surface as query errors instead of silently degrading — benchmarking
-the flagship path), `inline` (no subprocess; ops run in-process —
+supervised subprocess, degrade-and-recover), `require` (the device path
+IS the contract: failures surface as query errors instead of degrading,
+and a runner that comes up on anything but a TPU is an init error
+unless `JAX_PLATFORMS` names that platform — chip_smoke.py and
+bench.py run here), `inline` (no subprocess; ops run in-process —
 debug/tests only, forfeits isolation).
 """
 
@@ -107,7 +117,9 @@ class DeviceSupervisor:
             if promote_successes is None else promote_successes)
         self.state = "off" if self.mode == "off" else "cold"
         self.platform: Optional[str] = None
+        self.device_kind: Optional[str] = None
         self.device_count = 0
+        self.versions: Optional[dict] = None  # jax/jaxlib/libtpu (runner)
         self.last_error: Optional[str] = None
         self.counters = {
             "device_spawns": 0, "device_restarts": 0,
@@ -115,6 +127,13 @@ class DeviceSupervisor:
             "device_fallbacks": 0, "device_host_routed": 0,
             "device_oom_refusals": 0,
         }
+        # wall seconds spent shipping block caches to the runner
+        self.ship_s = 0.0
+        # compile-aware dispatch window (see module docstring): the seq
+        # the runner announced as compiling, and the monotonic time
+        # before which no dispatch may be declared timed out
+        self._compiling_seq = None
+        self._no_wedge_before = 0.0
         # stores the runner refused under its byte budget: key -> tag.
         # ensure_loaded fails these fast (typed DeviceOutOfMemory →
         # host paths) until the store's tag changes (a rebuilt, smaller
@@ -310,6 +329,7 @@ class DeviceSupervisor:
         # is DETECTED — require mode rewraps the exception as SdbError
         # before it would reach a handler here, and the recording must
         # survive that
+        t0 = time.monotonic()
         if (op == "vec_load"
                 and bufs[0].nbytes > self.LOAD_PART_BYTES):
             self._multipart_vec_load(key, tag, meta, bufs[0], bufs[1])
@@ -319,6 +339,7 @@ class DeviceSupervisor:
         else:
             self.call(op, meta, bufs, timeout_s=self.load_timeout_s)
         with self._lock:
+            self.ship_s += time.monotonic() - t0
             self._loaded[key] = tag
             self._oom_keys.pop(key, None)
         if self.mode != "inline":
@@ -450,7 +471,10 @@ class DeviceSupervisor:
             "state": self.state,
             "mode": self.mode,
             "platform": self.platform,
+            "device_kind": self.device_kind,
             "device_count": self.device_count,
+            "versions": self.versions,
+            "ship_s": round(self.ship_s, 3),
             "restarts": self.counters["device_restarts"],
             "dispatch_timeouts": self.counters["device_dispatch_timeouts"],
             "dispatch_errors": self.counters["device_dispatch_errors"],
@@ -488,6 +512,14 @@ class DeviceSupervisor:
     def runner_pid(self) -> Optional[int]:
         p = self._proc
         return p.pid if p is not None else None
+
+    def runner_status(self) -> dict:
+        """The runner's own account (device/handlers.py op_status):
+        per-op dispatch counts, resident blocks, rank modes, mesh
+        placement, per-device memory, compile seconds per kernel and
+        persistent-cache hits. One RPC; raises like `call`."""
+        _t, meta, _b = self.call("status", {})
+        return meta
 
     def shutdown(self):
         """Stop the runner and every background thread (server drain).
@@ -556,6 +588,7 @@ class DeviceSupervisor:
             try:
                 _t, st, _b = host.handle("status", {}, [])
                 self.platform = st.get("platform")
+                self.device_kind = st.get("device_kind")
                 self.device_count = st.get("device_count", 0)
             except BaseException:
                 pass
@@ -589,21 +622,11 @@ class DeviceSupervisor:
             "from surrealdb_tpu.device.runner import main; "
             "main(int(sys.argv[1]))"
         )
-        env = dict(os.environ)
-        if not env.get("SURREAL_DEVICE_COMPILE_CACHE_DIR"):
-            # hand the runner the resolved persistent-cache dir (the
-            # datastore-registered default lives in THIS process)
-            from surrealdb_tpu.device.compile_cache import resolve_dir
-
-            d = resolve_dir()
-            if d is not None:
-                env["SURREAL_DEVICE_COMPILE_CACHE_DIR"] = d
         try:
             proc = subprocess.Popen(
                 [sys.executable, "-c", code, str(child.fileno()),
                  pkg_root],
                 pass_fds=(child.fileno(),),
-                env=env,
             )
         except OSError as e:
             _close_sock(parent)
@@ -642,6 +665,13 @@ class DeviceSupervisor:
             )
             self._abort_spawn(proc, parent)
             return False
+        platform = meta.get("platform")
+        refusal = require_refusal(self.mode, platform,
+                                  os.environ.get("JAX_PLATFORMS", ""))
+        if refusal is not None:
+            self.last_error = refusal
+            self._abort_spawn(proc, parent)
+            return False
         parent.settimeout(None)
         with self._lock:
             self._spawning = None
@@ -654,8 +684,12 @@ class DeviceSupervisor:
             self._proc = proc
             self._sock = parent
             self._loaded.clear()
-            self.platform = meta.get("platform")
+            self.platform = platform
+            self.device_kind = meta.get("device_kind")
             self.device_count = int(meta.get("device_count", 0))
+            self.versions = meta.get("versions")
+            self._compiling_seq = None
+            self._no_wedge_before = 0.0
             if meta.get("compile_cache") is not None:
                 self.compile_cache_info = meta["compile_cache"]
             if meta.get("mesh") is not None:
@@ -803,10 +837,16 @@ class DeviceSupervisor:
         meta = dict(meta)
         meta["seq"] = seq
         sq.put((op, meta, bufs))
-        end = time.monotonic() + eff
+        start = time.monotonic()
+        end = start + eff
         cancelled = False
         while not ev.is_set():
-            left = end - time.monotonic()
+            # a declared compile (ours or one we queue behind) holds
+            # the wedge clock; the query's own budget still applies
+            limit = max(end, self._no_wedge_before)
+            if budget is not None:
+                limit = min(limit, start + max(budget, 0.0))
+            left = limit - time.monotonic()
             if left <= 0:
                 break
             ev.wait(min(left, 0.05))
@@ -882,12 +922,24 @@ class DeviceSupervisor:
                 if self._is_current(gen):
                     self._mark_degraded(f"runner died: {e}")
                 return
+            if tag == "compiling":
+                with self._lock:
+                    self._compiling_seq = meta.get("seq")
+                    self._no_wedge_before = \
+                        time.monotonic() + self.load_timeout_s
+                continue
             cc = meta.get("cc")
             if isinstance(cc, dict):
                 self.compile_counts = cc
             seq = meta.get("seq")
             with self._lock:
                 slot = self._pending.pop(seq, None)
+                if seq == self._compiling_seq:
+                    # compile over: dispatches queued behind it get a
+                    # fresh window from here
+                    self._compiling_seq = None
+                    self._no_wedge_before = \
+                        time.monotonic() + self.dispatch_timeout_s
             if slot is not None:
                 slot[1] = (tag, meta, bufs)
                 slot[0].set()
@@ -896,6 +948,21 @@ class DeviceSupervisor:
         with self._lock:
             return gen == self._gen and not self._stop.is_set() \
                 and self.state in ("ready", "degraded", "probing")
+
+
+def require_refusal(mode: str, platform, jax_platforms: str):
+    """Why a runner that came up on `platform` is an init error, or
+    None. `require` means the chip: jax quietly choosing another
+    backend must not pass for it. Naming the platform in JAX_PLATFORMS
+    is the one deliberate way to run elsewhere (tests, rehearsals)."""
+    if mode != "require" or platform == "tpu" \
+            or platform in jax_platforms.lower().split(","):
+        return None
+    return (
+        f"SURREAL_DEVICE=require needs a TPU but the runner came up on "
+        f"{platform!r} (set JAX_PLATFORMS={platform} to run there "
+        f"deliberately)"
+    )
 
 
 def _query_remaining():

@@ -916,47 +916,43 @@ def _csr_pair_pattern(g1, g2):
     return g1.what[0][0], g2.what[0][0], g1.dir
 
 
-def _csr_pair_hop(val, g1, g2, ctx):
-    """Device fast path for `->edge->node` pairs over big frontiers inside
-    recursion (where set semantics apply): the two `~`-key scans become one
-    CSR gather+scatter hop on the TPU (SURVEY §3.4 / §7 step 5). Returns
-    None when the pattern or scale doesn't apply. NOTE: results are
-    deduplicated — only used where dedup is already the semantics."""
-    from surrealdb_tpu.expr.ast import PGraph as _PG
+def _csr_pair_hop(frontier, g1, g2, ctx):
+    """Device path for one BFS level of `+collect` recursion (where set
+    semantics apply) through a plain `->edge->node` step: a frontier of
+    at least TPU_FRONTIER_THRESHOLD nodes expands as ONE CSR
+    gather+scatter hop in the device runner instead of two `~`-key
+    scans per node (SURVEY §3.4 / §7 step 5). Returns the reached
+    nodes, deduplicated, in ascending record-key order (a level served
+    here is a set: the per-node walk's discovery order does not
+    survive a mask) — or None when the pattern or scale doesn't apply
+    and the caller walks node by node."""
+    from surrealdb_tpu.graph import TPU_FRONTIER_THRESHOLD
 
-    if not isinstance(g2, _PG):
+    if len(frontier) < TPU_FRONTIER_THRESHOLD:
+        return None
+    pat = _csr_pair_pattern(g1, g2)
+    if pat is None:
         return None
     if ctx.version is not None:
         return None  # CSR caches HEAD state; VERSION reads use key scans
-    for g in (g1, g2):
-        if (
-            g.cond is not None
-            or g.expr is not None
-            or g.dir not in ("out", "in")
-            or len(g.what) != 1
-            or g.what[0][1] is not None
-        ):
-            return None
-    if g1.dir != g2.dir:
+    edge_tb, node_tb, direction = pat
+    if any(not isinstance(r, RecordId) or r.tb != node_tb
+           for r in frontier):
         return None
-    rids = _collect_rids(val, ctx)
-    from surrealdb_tpu.graph import TPU_FRONTIER_THRESHOLD
-
-    if len(rids) < TPU_FRONTIER_THRESHOLD:
-        return None
-    edge_tb = g1.what[0][0]
-    node_tb = g2.what[0][0]
-    src_tbs = {r.tb for r in rids}
-    if src_tbs != {node_tb}:
-        return None
-    ns0, db0 = ctx.need_ns_db()
-    if (ns0, db0, edge_tb) in getattr(ctx.txn, "_graph_dirty", ()):
+    ns, db = ctx.need_ns_db()
+    if (ns, db, edge_tb) in getattr(ctx.txn, "_graph_dirty", ()):
         return None  # uncommitted edge writes in this txn
+    # same alignment guard as the bag path below: the key-scan CSR
+    # build pairs one IN with one OUT key per edge record, which only
+    # holds when the first table is a declared RELATION
+    tdef = ctx.txn.peek_val(K.tb_def(ns, db, edge_tb))
+    if tdef is None or getattr(tdef, "kind", None) != "relation":
+        return None
     from surrealdb_tpu.graph.csr import get_csr
 
-    csr = get_csr(ctx.ds, ctx, node_tb, edge_tb, g1.dir)
-    keys = csr.multi_hop([r.id for r in rids], 1)
-    return [RecordId(node_tb, k) for k in keys]
+    csr = get_csr(ctx.ds, ctx, node_tb, edge_tb, direction)
+    keys = csr.multi_hop([r.id for r in frontier], 1)
+    return [RecordId(node_tb, k) for k in sorted(keys, key=K.enc_value)]
 
 
 def _csr_bag_pair_hop(val, g1, g2, ctx, hops=1):
@@ -1405,17 +1401,30 @@ def _apply_recurse(val, part: PRecurse, tail, ctx):
         collected = []
         frontier = list(start_items)
         depth = 0
-        while depth < rmax and frontier:
-            nxt = []
-            for node in frontier:
-                children, islist = step(node)
-                was_list = was_list or islist
-                for ch in children:
-                    h = hashable(ch)
-                    if h in visited:
-                        continue
+        def first_seen(children):
+            out = []
+            for ch in children:
+                h = hashable(ch)
+                if h not in visited:
                     visited.add(h)
-                    nxt.append(ch)
+                    out.append(ch)
+            return out
+
+        while depth < rmax and frontier:
+            reached = (
+                _csr_pair_hop(frontier, parts[0], parts[1], ctx)
+                if len(parts) == 2 else None
+            )
+            if reached is not None:
+                # the whole level in one device hop
+                was_list = True
+                nxt = first_seen(reached)
+            else:
+                nxt = []
+                for node in frontier:
+                    children, islist = step(node)
+                    was_list = was_list or islist
+                    nxt.extend(first_seen(children))
             depth += 1
             if depth >= rmin:
                 collected.extend(nxt)

@@ -862,7 +862,11 @@ class SegmentedAnn:
                     tag=[int(seg.seq), int(lo), int(hi)],
                 )
             except (DeviceUnavailable, DeviceOpError):
-                cand = None  # numpy mirror below
+                # numpy mirror below — counted like every other degrade
+                from surrealdb_tpu.device import get_supervisor
+
+                get_supervisor().note_fallback()
+                cand = None
         if cand is None:
             cfg = eng._ann_search_cfg()
             width = min(max(cfg["width"], kc), m)

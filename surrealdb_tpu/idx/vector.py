@@ -1084,7 +1084,12 @@ class TpuVectorIndex:
             try:
                 cand = self._ann_device_search(ann, qs32, kc)
             except (DeviceUnavailable, DeviceOpError):
-                cand = None  # degrade to the numpy descent below
+                # degrade to the numpy descent below — counted, so a
+                # run that meant to measure the device can tell
+                from surrealdb_tpu.device import get_supervisor
+
+                get_supervisor().note_fallback()
+                cand = None
         if cand is None:
             cfg = self._ann_search_cfg()
             width = min(max(cfg["width"], kc), ann.built_n)

@@ -134,12 +134,12 @@ class Datastore:
             from surrealdb_tpu.kvs.lsm import LsmBackend
 
             self.backend = LsmBackend(path[len("lsm://"):])
-            self._register_compile_cache_dir(path[len("lsm://"):])
+            self._register_ann_cache_dir(path[len("lsm://"):])
         elif path.startswith("file://") or path.startswith("skv://"):
             from surrealdb_tpu.kvs.file import FileBackend
 
             self.backend = FileBackend(path.split("://", 1)[1])
-            self._register_compile_cache_dir(path.split("://", 1)[1])
+            self._register_ann_cache_dir(path.split("://", 1)[1])
         elif path.startswith("remote://"):
             # distributed mode: stateless database node over a shared
             # transactional KV service (reference kvs/tikv/mod.rs:32);
@@ -356,20 +356,14 @@ class Datastore:
         self._edge_oplog = {}
         self._edge_oplog_totals = {}
 
-    def _register_compile_cache_dir(self, store_path: str):
-        """Disk-backed stores anchor the device runner's persistent
-        XLA compile cache next to the data (unless the env knob picked
-        somewhere explicit) — compiled kernels then survive server AND
-        runner restarts together. The persisted-ANN artifact dir
-        (idx/cagra.py save_index) anchors beside it for the same
-        reason: a restart reloads a 1M-row graph build in seconds."""
+    def _register_ann_cache_dir(self, store_path: str):
+        """Disk-backed stores anchor the persisted-ANN artifact dir
+        (idx/cagra.py save_index) next to the data: a restart reloads
+        a 1M-row graph build in seconds."""
         import os as _os
-
-        from surrealdb_tpu.device import compile_cache
 
         base = store_path if _os.path.isdir(store_path) \
             else _os.path.dirname(_os.path.abspath(store_path))
-        compile_cache.set_default_dir(_os.path.join(base, ".xla-cache"))
         self.ann_snapshot_dir = _os.path.join(base, ".ann-cache")
 
     def start_node_tasks(self, interval_s: float = 10.0,

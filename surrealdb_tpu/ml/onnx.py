@@ -3,9 +3,10 @@
 The reference links the ONNX Runtime C library (surrealml/core — `ort`).
 This environment has neither onnxruntime nor the `onnx` python package, so
 the ModelProto protobuf is decoded directly (protobuf wire format is
-simple: varint tags + length-delimited fields) and the graph executes as
-jitted JAX — which is the point of this build: model inference rides the
-same XLA/TPU path as the vector kernels instead of a separate C runtime.
+simple: varint tags + length-delimited fields) and the graph executes
+through jax.numpy on XLA's CPU backend, in the serving process. It does
+NOT share the accelerator: a chip belongs to one process, and that
+process is the DeviceRunner (`_pin_host_backend`).
 
 Covered operator set (the sklearn/torch-exported MLP/linear family the
 reference's surrealml tooling produces): MatMul, Gemm, Add, Sub, Mul, Div,
@@ -213,6 +214,23 @@ class OnnxGraph:
 # JAX execution
 # ---------------------------------------------------------------------------
 
+def _pin_host_backend():
+    """Hold this process's jax to the CPU backend BEFORE its first
+    backend init. `run_graph` executes on a query thread of the serving
+    process; left to its default, jax would open the TPU here — taking
+    the chip from the DeviceRunner that owns it, or failing because the
+    runner already has it. A config update (not the environment: the
+    runner inherits that) placed before any array is created is the
+    whole pin (repeating it is free). `SURREAL_DEVICE=inline` runs
+    device ops in this process by design and is left alone."""
+    from surrealdb_tpu.device import get_supervisor
+
+    if get_supervisor().mode != "inline":
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+
+
 
 def _softmax(x, axis):
     import jax.numpy as jnp
@@ -289,6 +307,7 @@ def _pool(x, a, op):
 
 def run_graph(g: OnnxGraph, feed: dict[str, np.ndarray]) -> list:
     """Execute the graph; returns the output arrays (numpy)."""
+    _pin_host_backend()
     import jax.numpy as jnp
 
     env: dict[str, Any] = {k: jnp.asarray(v) for k, v in g.weights.items()}

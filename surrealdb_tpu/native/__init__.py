@@ -1,8 +1,10 @@
 """ctypes binding + lazy build of the native C++ MVCC memtable.
 
-The shared library is compiled once (g++ -O2) into the package directory and
-cached; loading falls back gracefully to None so the pure-Python engine
-keeps working on systems without a toolchain.
+The shared library is compiled once (g++ -O2, from memtable.cpp and
+nothing else) into the package directory and cached. Without a working
+toolchain `load()` returns None and the pure-Python engine serves — but
+never silently: the failed build is reported once on stderr with the
+compiler's own output.
 
 Values read out of the store are copied into malloc'd buffers on the C++
 side under the store mutex and freed here via sdb_buf_free — so a
@@ -13,6 +15,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -21,6 +24,22 @@ _SO = os.path.join(_HERE, "_memtable.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_reported = False
+
+
+def _report(what: str, e: BaseException):
+    """Say ONCE that the pure-Python memtable serves, and why."""
+    global _reported
+    if _reported:
+        return
+    _reported = True
+    stderr = getattr(e, "stderr", None) or b""
+    print(
+        f"[surrealdb-tpu] native memtable {what} "
+        f"({e.__class__.__name__}: {e}); the pure-Python memtable "
+        f"serves instead\n{stderr.decode(errors='replace')[-2000:]}",
+        file=sys.stderr, flush=True,
+    )
 
 
 def _build() -> bool:
@@ -32,7 +51,8 @@ def _build() -> bool:
             timeout=120,
         )
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        _report("build failed", e)
         return False
 
 
@@ -51,7 +71,8 @@ def load():
         try:
             lib = ctypes.CDLL(_SO)
             lib.sdb_scan_extract_f32  # symbol probe: stale prebuilt .so?
-        except OSError:
+        except OSError as e:
+            _report("did not load", e)
             return None
         except AttributeError:
             # an old library without the current ABI: rebuild once, else
@@ -61,7 +82,8 @@ def load():
             try:
                 lib = ctypes.CDLL(_SO)
                 lib.sdb_scan_extract_f32
-            except (OSError, AttributeError):
+            except (OSError, AttributeError) as e:
+                _report("did not load after a rebuild", e)
                 return None
         c_char_pp = ctypes.POINTER(ctypes.c_char_p)
         i64 = ctypes.c_int64

@@ -31,6 +31,13 @@ from surrealdb_tpu.ops.metrics import (  # noqa: F401 (re-export)
 )
 
 
+# these are the EXACT kernels (non-ranking paths: brute scans, the mesh
+# exact store): their f32 matmuls run at full f32 on the MXU, not the
+# TPU's default single bf16 pass. The bf16/int8 RANKING matmuls live in
+# ops/topk.py and stay fast on purpose.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 @partial(jax.jit, static_argnames=("metric",))
 def distance_matrix(xs, qs, metric: str = EUCLIDEAN, p: float = 3.0):
     """[B, N] distances between each query row and every stored vector."""
@@ -40,15 +47,15 @@ def distance_matrix(xs, qs, metric: str = EUCLIDEAN, p: float = 3.0):
         # |x-q|^2 = |x|^2 - 2 x.q + |q|^2  (one MXU matmul)
         x2 = jnp.sum(xs * xs, axis=-1)[None, :]
         q2 = jnp.sum(qs * qs, axis=-1)[:, None]
-        xq = jnp.einsum("nd,bd->bn", xs, qs)
+        xq = jnp.einsum("nd,bd->bn", xs, qs, precision=_EXACT)
         d2 = jnp.maximum(x2 + q2 - 2.0 * xq, 0.0)
         return jnp.sqrt(d2)
     if metric == COSINE:
         xn = xs / jnp.maximum(jnp.linalg.norm(xs, axis=-1, keepdims=True), 1e-30)
         qn = qs / jnp.maximum(jnp.linalg.norm(qs, axis=-1, keepdims=True), 1e-30)
-        return 1.0 - jnp.einsum("nd,bd->bn", xn, qn)
+        return 1.0 - jnp.einsum("nd,bd->bn", xn, qn, precision=_EXACT)
     if metric == DOT:
-        return -jnp.einsum("nd,bd->bn", xs, qs)
+        return -jnp.einsum("nd,bd->bn", xs, qs, precision=_EXACT)
     if metric == MANHATTAN:
         return jnp.sum(jnp.abs(qs[:, None, :] - xs[None, :, :]), axis=-1)
     if metric == CHEBYSHEV:
@@ -65,7 +72,7 @@ def distance_matrix(xs, qs, metric: str = EUCLIDEAN, p: float = 3.0):
         qc = qs - jnp.mean(qs, axis=-1, keepdims=True)
         xn = xc / jnp.maximum(jnp.linalg.norm(xc, axis=-1, keepdims=True), 1e-30)
         qn = qc / jnp.maximum(jnp.linalg.norm(qc, axis=-1, keepdims=True), 1e-30)
-        return 1.0 - jnp.einsum("nd,bd->bn", xn, qn)
+        return 1.0 - jnp.einsum("nd,bd->bn", xn, qn, precision=_EXACT)
     if metric == JACCARD:
         # continuous jaccard distance: 1 - sum(min)/sum(max)
         mn = jnp.sum(jnp.minimum(qs[:, None, :], xs[None, :, :]), axis=-1)

@@ -25,15 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# the 0.4.x/0.5.x shard_map + axis_size gate lives in device/meshcompat
-# so this module and the mesh execution subsystem (device/mesh.py)
-# resolve the same callables
-from surrealdb_tpu.device.meshcompat import (
-    axis_size as _axis_size,
-    shard_map as _shard_map,
-)
-
 DATA_AXIS = "data"
+# the [B, kc, D] rescore contractions promise exact f32 distances: full
+# f32 on the MXU, not the TPU's default single bf16 pass (ops/topk.py)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def default_mesh(devices=None) -> Mesh:
@@ -109,12 +104,12 @@ def _rank_rescore_shard(xr, xf, x2, norms, valid, qs, k: int, kc: int,
         diff = rows - qs[:, None, :]
         d = jnp.sqrt(jnp.maximum((diff * diff).sum(axis=-1), 0.0))
     elif metric == "cosine":
-        dd = jnp.einsum("bkd,bd->bk", rows, qs,
+        dd = jnp.einsum("bkd,bd->bk", rows, qs, precision=_EXACT,
                         preferred_element_type=jnp.float32)
         qn = jnp.maximum(jnp.linalg.norm(qs, axis=-1), 1e-30)
         d = 1.0 - dd / jnp.maximum(norms[cand] * qn[:, None], 1e-30)
     else:  # dot
-        d = -jnp.einsum("bkd,bd->bk", rows, qs,
+        d = -jnp.einsum("bkd,bd->bk", rows, qs, precision=_EXACT,
                         preferred_element_type=jnp.float32)
     d = jnp.where(valid[cand], d, jnp.inf)
     gids = (cand + base).astype(jnp.int32)
@@ -132,7 +127,7 @@ def _rank_rescore_jit(mesh: Mesh, k: int, kc: int, metric: str,
     # jit(shard_map(partial(...))) per call defeats jit's trace cache and
     # pays full XLA compile on every query batch (~150x on the hot path)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             partial(_rank_rescore_shard, k=k, kc=kc, metric=metric,
                     recall_target=recall_target),
             mesh=mesh,
@@ -237,7 +232,7 @@ def _rank_rescore_shard_hier(xr, xf, x2, norms, valid, qs, k: int, kc: int,
     axis first (intra-host), then only the per-host [B, k] winners cross
     the DCN axis for the final merge — the expensive inter-host hop
     carries k candidates per host, not kc x devices."""
-    ici_sz = _axis_size(DATA_AXIS)
+    ici_sz = jax.lax.axis_size(DATA_AXIS)
     base = (
         jax.lax.axis_index(DCN_AXIS) * ici_sz
         + jax.lax.axis_index(DATA_AXIS)
@@ -255,12 +250,12 @@ def _rank_rescore_shard_hier(xr, xf, x2, norms, valid, qs, k: int, kc: int,
         diff = rows - qs[:, None, :]
         d = jnp.sqrt(jnp.maximum((diff * diff).sum(axis=-1), 0.0))
     elif metric == "cosine":
-        dd = jnp.einsum("bkd,bd->bk", rows, qs,
+        dd = jnp.einsum("bkd,bd->bk", rows, qs, precision=_EXACT,
                         preferred_element_type=jnp.float32)
         qn = jnp.maximum(jnp.linalg.norm(qs, axis=-1), 1e-30)
         d = 1.0 - dd / jnp.maximum(norms[cand] * qn[:, None], 1e-30)
     else:
-        d = -jnp.einsum("bkd,bd->bk", rows, qs,
+        d = -jnp.einsum("bkd,bd->bk", rows, qs, precision=_EXACT,
                         preferred_element_type=jnp.float32)
     d = jnp.where(valid[cand], d, jnp.inf)
     gids = (cand + base).astype(jnp.int32)
@@ -283,7 +278,7 @@ def _rank_rescore_hier_jit(mesh: Mesh, k: int, kc: int, metric: str,
     spec_rows = P((DCN_AXIS, DATA_AXIS), None)
     spec_vec = P((DCN_AXIS, DATA_AXIS))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             partial(_rank_rescore_shard_hier, k=k, kc=kc, metric=metric,
                     recall_target=recall_target),
             mesh=mesh,

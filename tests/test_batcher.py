@@ -351,79 +351,56 @@ def test_pipelined_second_dispatch_overlaps(monkeypatch):
 
 # -- compile cache ------------------------------------------------------------
 
-def test_compile_cache_survives_runner_restart(tmp_path, monkeypatch):
-    """Inline-mode restart simulation: the cache dir is configured via
-    env, jax is pointed at it, and a 'restarted' host re-initializes
-    against the SAME directory (entries persist on disk)."""
+def test_compile_cache_dir_from_environment(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places the cache: the runner reports
+    it and sets no directory of its own (jax reads the variable
+    natively). The subprocess rehearsal in test_chip_smoke.py shows
+    entries landing there."""
     import jax
 
-    from surrealdb_tpu.device import compile_cache, kernelstats
-    from surrealdb_tpu.device.handlers import DeviceHost
+    from surrealdb_tpu.device import compile_cache
 
-    cache_dir = str(tmp_path / "xla")
-    monkeypatch.setenv("SURREAL_DEVICE_COMPILE_CACHE_DIR", cache_dir)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    calls = []
+    real = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (calls.append(name), real(name, val)))
     old_dir = jax.config.jax_compilation_cache_dir
     compile_cache.reset_for_tests()
     try:
         info = compile_cache.initialize()
-        assert info.get("dir") == cache_dir, info
-        assert os.path.isdir(cache_dir)
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-
-        # run one kernel through an inline host so a compile happens —
-        # shapes deliberately unique to this test, so XLA cannot serve
-        # them from executables other tests already compiled in-process
-        host = DeviceHost()
-        rng = np.random.default_rng(1)
-        vecs = rng.normal(size=(67, 9)).astype(np.float32)
-        valid = np.ones(67, np.uint8)
-        host.handle("vec_load", {
-            "key": "k", "tag": [0, 0], "metric": "euclidean",
-            "mink_p": 3.0, "cfg": {
-                "hbm_budget": 1 << 30, "score_budget": 1 << 20,
-                "query_chunk": 64, "int8_oversample": 8,
-                "block_rows": 1 << 20,
-            },
-        }, [vecs, valid])
-        t, meta, bufs = host.handle(
-            "vec_knn", {"key": "k", "tag": [0, 0], "k": 3},
-            [rng.normal(size=(2, 9)).astype(np.float32)],
-        )
-        assert t == "ok"
-        before = kernelstats.snapshot()
-        assert before["misses"] >= 1  # something compiled
-        # XLA persisted the compiled kernels to the configured dir
-        assert len(os.listdir(cache_dir)) >= 1, \
-            "no compile-cache entries written"
-
-        # "runner restart": fresh process state, same cache dir
-        compile_cache.reset_for_tests()
-        kernelstats.reset()
-        info2 = compile_cache.initialize()
-        assert info2.get("dir") == cache_dir
-        # whatever XLA persisted is still there for the new runner
-        assert info2.get("entries", 0) >= 1
-        host2 = DeviceHost()
-        host2.handle("vec_load", {
-            "key": "k", "tag": [0, 0], "metric": "euclidean",
-            "mink_p": 3.0, "cfg": {
-                "hbm_budget": 1 << 30, "score_budget": 1 << 20,
-                "query_chunk": 64, "int8_oversample": 8,
-                "block_rows": 1 << 20,
-            },
-        }, [vecs, valid])
-        t2, _m, _b = host2.handle(
-            "vec_knn", {"key": "k", "tag": [0, 0], "k": 3},
-            [rng.normal(size=(2, 9)).astype(np.float32)],
-        )
-        assert t2 == "ok"
+        assert info["dir"] == str(tmp_path / "jc") and info["from_env"]
+        assert "jax_compilation_cache_dir" not in calls
+        assert jax.config.jax_compilation_cache_dir == old_dir
     finally:
         compile_cache.reset_for_tests()
-        kernelstats.reset()
-        try:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
-        except Exception:
-            pass
+
+
+def test_compile_cache_dir_fixed_in_checkout(tmp_path, monkeypatch):
+    """Without the variable: one fixed path in the checkout, whatever
+    the working directory, home or datastore."""
+    import jax
+
+    from surrealdb_tpu import Datastore
+    from surrealdb_tpu.device import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.chdir(tmp_path)
+    Datastore(f"file://{tmp_path}/store.skv").close()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.cache_dir() == want
+    old_dir = jax.config.jax_compilation_cache_dir
+    compile_cache.reset_for_tests()
+    try:
+        info = compile_cache.initialize()
+        assert info["dir"] == want and not info["from_env"]
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        compile_cache.reset_for_tests()
+        jax.config.update("jax_compilation_cache_dir", old_dir)
 
 
 def test_prewarm_op_compiles_bucket_ladder():

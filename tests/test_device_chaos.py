@@ -254,19 +254,42 @@ def test_query_budget_bounds_wedged_dispatch(sub_sup, chaos_ds):
             pass
 
 
-def test_require_mode_surfaces_device_loss(chaos_ds):
+def test_require_refuses_a_runner_that_is_not_a_tpu():
+    """`require` means the chip: a runner on any other platform is an
+    init error unless JAX_PLATFORMS named that platform."""
+    from surrealdb_tpu.device.supervisor import require_refusal
+
+    assert "needs a TPU" in require_refusal("require", "cpu", "")
+    assert "needs a TPU" in require_refusal("require", "cpu", "tpu")
+    assert require_refusal("require", "cpu", "cpu") is None
+    assert require_refusal("require", "cpu", "tpu,cpu") is None
+    assert require_refusal("require", "tpu", "") is None
+    assert require_refusal("auto", "cpu", "") is None
+
+
+def test_require_mode_surfaces_device_loss(chaos_ds, tmp_path,
+                                           monkeypatch):
     """SURREAL_DEVICE=require: a degraded device is a query ERROR (the
-    flagship-path posture), never a silent host fallback."""
+    flagship-path posture), never a silent host fallback. And a compile
+    is not a wedge: from an EMPTY compile cache the first query's XLA
+    compile outlasts the 0.2 s dispatch window many times over, yet the
+    runner — which announced it — is neither timed out nor killed."""
     ds, vecs = chaos_ds
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     sup = DeviceSupervisor(
-        mode="require", dispatch_timeout_s=1.0, init_timeout_s=120.0,
+        mode="require", dispatch_timeout_s=0.2, init_timeout_s=120.0,
         probe_interval_s=30.0, promote_successes=1,
     )
     old = set_supervisor(sup)
     try:
         assert sup.wait_ready(120)
+        pid = sup.runner_pid()
         ok = ds.query(_knn_sql(vecs[0]))[0]
         assert len(ok) == 5
+        assert sup.runner_pid() == pid
+        assert sup.counters["device_dispatch_timeouts"] == 0
+        assert sup.counters["device_restarts"] == 0
+        assert sup.runner_status()["compile"]["persistent_misses"] >= 1
         os.kill(sup.runner_pid(), signal.SIGKILL)
         time.sleep(0.2)
         res = ds.execute(_knn_sql(vecs[0]), ns="test", db="test")
@@ -293,10 +316,6 @@ def test_ann_reship_after_sigkill_midload(sub_sup, chaos_ds, monkeypatch):
     # margin: the invariant under test is the reship cycle, not the
     # quantization edge
     monkeypatch.setattr(_cnf, "KNN_ANN_OVERSAMPLE", 20)
-    # crash detection here is recv-EOF, not the watchdog: leave room
-    # for the first descent-kernel compile (the 1s chaos window reads
-    # a cold XLA compile as a wedge and kills the runner itself)
-    sub_sup.dispatch_timeout_s = 15.0
     sql = _knn_sql(vecs[0])
     ds.query(sql)  # instantiate the index engine
     ix = next(iter(ds.vector_indexes.values()))

@@ -77,21 +77,27 @@ def test_csr_rebuild_on_write(ds):
 
 
 def test_recursion_csr_fast_path_matches_host(ds):
-    """Recursion BFS uses the CSR device hop over the threshold; results
-    must match the host walk (both are visited-set deduplicated)."""
+    """+collect recursion expands a level over the threshold as one CSR
+    device hop (declared RELATION tables only); results must match the
+    host walk (both are visited-set deduplicated)."""
     import surrealdb_tpu.graph as G
+    from surrealdb_tpu.device import get_supervisor
 
+    ds.execute("DEFINE TABLE e TYPE RELATION", ns="t", db="t")
     _build_graph(ds, n_nodes=30, seed=2)
     old = G.TPU_FRONTIER_THRESHOLD
     try:
         q = "RETURN array::sort(n:0.{..+collect}(->e->n))"
         host = ds.query(q, ns="t", db="t")[0]
+        hops0 = get_supervisor().runner_status()["ops"].get("csr_hop", 0)
         G.TPU_FRONTIER_THRESHOLD = 2
         dev = ds.query(q, ns="t", db="t")[0]
         assert sorted(r.render() for r in host) == sorted(
             r.render() for r in dev
         )
         assert len(host) > 3
+        # the device hop really ran (it was dead code once)
+        assert get_supervisor().runner_status()["ops"]["csr_hop"] > hops0
     finally:
         G.TPU_FRONTIER_THRESHOLD = old
 

@@ -353,7 +353,6 @@ def test_seg_snapshot_persist_reload(seg_cnf, tmp_path, monkeypatch):
     ix = _mk_engine()
     ix.snapshot_dir = str(tmp_path)
     vs = rng.normal(size=(500, DIM))
-    _apply(ix, _sets(ix, vs, 0))
     builds = []
     real_build = cagra.build_index
 
@@ -361,7 +360,10 @@ def test_seg_snapshot_persist_reload(seg_cnf, tmp_path, monkeypatch):
         builds.append(1)
         return real_build(*a, **kw)
 
+    # counted BEFORE the rows land: applying them already kicks the
+    # background seal + build, which may finish first
     monkeypatch.setattr(cagra, "build_index", counting_build)
+    _apply(ix, _sets(ix, vs, 0))
     assert ix.ensure_ann()
     n_first = len(builds)
     assert n_first >= 1

@@ -112,10 +112,8 @@ def main(argv=None) -> int:
             "mesh_shape": agg.get("mesh_shape", [0]),
             "parsed": parsed,
         }
-        reason = next(
-            (c["error"] for c in counts if c.get("error")), None)
-        if reason or not mesh:
-            out["fallback_reason"] = reason or "no knn_mesh lines"
+        if not mesh:
+            out["error"] = "no knn_mesh lines"
         dest = os.path.join(
             args.out_dir, f"MULTICHIP_r{args.round:02d}.json")
         with open(dest, "w", encoding="utf-8") as f:
