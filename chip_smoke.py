@@ -25,13 +25,19 @@ reference written here (f64 numpy brute force, a numpy BFS). Stages:
   ml     one `ml::` call on a tiny ONNX model, then one more KNN: the
          serving process must not have taken the chip.
 
-It exits non-zero, and prints no result line, unless the runner came up
-on a TPU, every stage passed its check, vec_knn / ann_search / csr_hop
+It exits non-zero, and prints nothing on stdout, unless the runner came
+up on a TPU, every stage passed its check, vec_knn / ann_search / csr_hop
 were each dispatched, and the supervisor counted no fallback, host
 route, restart, dispatch timeout, dispatch error or budget refusal.
 
+On success stdout holds two JSON lines. The first is the report: sizes,
+set-up seconds, compile cache, runner evidence, supervisor counters. The
+last is the verdict, exactly
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
+with the device as the runner's jax reports it.
+
 `--rehearsal` (needs JAX_PLATFORMS=cpu) walks the same code at tiny
-sizes on the CPU for the test suite; its line says `"rehearsal": true`.
+sizes on the CPU for the test suite; its report says `"rehearsal": true`.
 `--break-check STAGE` corrupts that stage's reference, to prove that a
 failed check fails the run.
 """
@@ -653,11 +659,13 @@ def main() -> int:
         if not all(ops.values()):
             raise CheckFailed(f"an op never reached the device: {ops}")
         compile_s = rs["compile"]["backend_compile_s"]
-        result = {
+        verdict = {
             "ok": True,
-            "device": {"platform": rs["platform"],
-                       "kind": rs["device_kind"],
-                       "count": rs["device_count"]},
+            "device": {"platform": str(rs["platform"]),
+                       "kind": str(rs["device_kind"]),
+                       "count": int(rs["device_count"])},
+        }
+        report = {
             "rehearsal": bool(args.rehearsal),
             "versions": st["versions"],
             "seed": args.seed,
@@ -712,7 +720,8 @@ def main() -> int:
             srv.server_close()
         reset_supervisor()  # stops the runner subprocess
         ds.close()
-    print(json.dumps(result), flush=True)
+    print(json.dumps(report))
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

@@ -1,6 +1,6 @@
 """chip_smoke.py on the CPU: three subprocess runs side by side — a
-rehearsal at tiny sizes (last-line schema, cache placed by the
-environment), a rehearsal whose first check is made to fail, and the
+rehearsal at tiny sizes (report and last-line verdict schema, cache
+placed by the environment), a rehearsal whose first check is made to fail, and the
 plain command, which without a TPU must fail and print no result."""
 
 from __future__ import annotations
@@ -51,10 +51,14 @@ def test_chip_smoke_rehearsal_break_check_and_no_chip(tmp_path):
     assert "not a TPU" in err
     rc, out, err = done["ok"]
     assert rc == 0, err[-4000:]
-    res = json.loads(out.strip().splitlines()[-1])
-    assert res["ok"] is True and res["rehearsal"] is True
-    assert res["seed"] == 3
-    assert res["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    lines = out.strip().splitlines()
+    assert len(lines) == 2, out[-2000:]
+    # the last line is the verdict, with these keys and no others
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    res = json.loads(lines[0])
+    assert res["rehearsal"] is True and res["seed"] == 3
     assert set(res["versions"]) == {"jax", "jaxlib", "libtpu"}
     for stage in ("exact", "ann", "graph", "ml"):
         assert res["stages"][stage]["checked"] is True
