@@ -198,7 +198,7 @@ class AnnStore:
         up to a power of two so compiled shapes stay a bounded ladder."""
         import jax.numpy as jnp
 
-        from surrealdb_tpu.device.kernelstats import note_shape
+        from surrealdb_tpu.device.kernelstats import ANN, note_shape, phase
 
         dev = self._ensure()
         n = self.x8.shape[0]
@@ -228,5 +228,20 @@ class AnnStore:
         static = (self.metric, width, iters, expand, kc)
         note_shape("ann_descent", (self.x8.shape, self.graph.shape[1],
                                    bucket) + static)
-        cand = _descent_jit(dev + (jnp.asarray(qsb),), static)
-        return np.ascontiguousarray(np.asarray(cand)[:b], np.int32)
+        # the op's timeline, as in VecStore.knn (kernelstats.phase): the
+        # one output's arrival on the host ends `device`
+        with phase("h2d"):
+            qsd = jnp.asarray(qsb)
+        with phase("device"):
+            cand = np.asarray(_descent_jit(dev + (qsd,), static))
+        with phase("d2h"):
+            out = np.ascontiguousarray(cand[:b], np.int32)
+        # the loop is a fixed fori_loop over static shapes, so the rows
+        # a descent scores are known here, from shapes: every iteration
+        # scores expand*d_out neighbours a rider, after one probe of p
+        # rows a search. Counted for the riders that asked, not the
+        # bucket they were padded to.
+        ANN["searches"] += 1
+        ANN["rows_scored"] += \
+            b * iters * expand * int(self.graph.shape[1]) + p
+        return out

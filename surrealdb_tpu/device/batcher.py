@@ -28,15 +28,23 @@ On top of the PR-1 coalescer this adds:
   poisoned rider can never fail its batchmates.
 - **Batching telemetry**: every dispatch records its size into a
   process-wide stats block surfaced as `device_batch_size_last/avg/max`
-  gauges and in `INFO FOR SYSTEM`.
+  gauges and in `INFO FOR SYSTEM`, and its timeline into the stage
+  table (telemetry.stage_record): once per rider `batch_wait` (enqueue
+  until a dispatcher grabbed the queue: queued behind the dispatch in
+  flight) and `batch_ride` (that grab until the rider holds its result
+  again: its batch's dispatch plus its own wake-up), once per dispatch
+  `batch_dispatch` (wall time of `_run`, degrade tiers included). A
+  withdrawn rider records neither of the first two.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Optional
 
 from surrealdb_tpu import cnf
+from surrealdb_tpu.telemetry import stage_record
 
 
 class BatchStats:
@@ -140,7 +148,8 @@ class DeviceBatcher:
         from surrealdb_tpu.inflight import current as _q_current
         from surrealdb_tpu.inflight import remaining as _q_remaining
 
-        slot = [None, None, False]  # [result, exception, done]
+        # [result, exception, done, ns when a dispatcher grabbed it]
+        slot = [None, None, False, None]
         entry = (payload, slot)
         batch = None
         handle = _q_current()
@@ -157,6 +166,7 @@ class DeviceBatcher:
                     cond.notify_all()
 
             handle.cancel.add_waker(waker)
+        t_enq = time.monotonic_ns()
         try:
             with self.cond:
                 self.queue.append(entry)
@@ -166,6 +176,9 @@ class DeviceBatcher:
                         # everything queued so far (including itself)
                         batch, self.queue = self.queue, []
                         self.inflight += 1
+                        t_grab = time.monotonic_ns()
+                        for _p, s in batch:
+                            s[3] = t_grab
                         break
                     if _q_cancelled():
                         # withdraw and unwind typed
@@ -199,24 +212,26 @@ class DeviceBatcher:
         finally:
             if waker is not None:
                 handle.cancel.remove_waker(waker)
-        if batch is None:
-            # our payload rode someone else's dispatch
-            if slot[1] is not None:
-                raise slot[1]
-            return slot[0]
-        try:
-            self._run(batch)
-        finally:
-            with self.cond:
-                self.inflight -= 1
-                self.cond.notify_all()
-        if not slot[2]:
-            # pipelined corner: this thread dispatched a NEWER batch
-            # while its own entry rode an older, still-running one —
-            # wait for that dispatch to attribute our slot
-            with self.cond:
-                while not slot[2]:
-                    self.cond.wait(0.05)
+        if batch is not None:
+            # (else our payload rode someone else's dispatch)
+            t_run = time.monotonic_ns()
+            try:
+                self._run(batch)
+            finally:
+                stage_record("batch_dispatch",
+                             time.monotonic_ns() - t_run)
+                with self.cond:
+                    self.inflight -= 1
+                    self.cond.notify_all()
+            if not slot[2]:
+                # pipelined corner: this thread dispatched a NEWER batch
+                # while its own entry rode an older, still-running one —
+                # wait for that dispatch to attribute our slot
+                with self.cond:
+                    while not slot[2]:
+                        self.cond.wait(0.05)
+        stage_record("batch_wait", slot[3] - t_enq)
+        stage_record("batch_ride", time.monotonic_ns() - slot[3])
         if slot[1] is not None:
             raise slot[1]
         return slot[0]

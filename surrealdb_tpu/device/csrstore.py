@@ -85,6 +85,8 @@ class CsrStore:
         kernel shapes stay a bounded ladder under dynamic batching."""
         import jax.numpy as jnp
 
+        from surrealdb_tpu.device.kernelstats import phase
+
         rows_d, cols_d = self._ensure()
         single = start.ndim == 1
         masks = start[None, :] if single else start
@@ -97,9 +99,15 @@ class CsrStore:
                 [masks, np.zeros((bucket - b, masks.shape[1]),
                                  masks.dtype)]
             )
-        out = _multi_hop_jit(
-            rows_d, cols_d, jnp.asarray(masks.astype(bool)),
-            self.n_nodes, int(hops), bool(union),
-        )
-        out = np.asarray(out)[:b].astype(np.uint8)
+        # the op's timeline, as in VecStore.knn (kernelstats.phase): the
+        # one output's arrival on the host ends `device`
+        with phase("h2d"):
+            masks_d = jnp.asarray(masks.astype(bool))
+        with phase("device"):
+            out = np.asarray(_multi_hop_jit(
+                rows_d, cols_d, masks_d,
+                self.n_nodes, int(hops), bool(union),
+            ))
+        with phase("d2h"):
+            out = out[:b].astype(np.uint8)
         return out[0] if single else out

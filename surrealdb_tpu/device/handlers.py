@@ -301,7 +301,33 @@ class DeviceHost:
                 kernelstats.COMPILE, backend_compile_s=dict(
                     kernelstats.COMPILE["backend_compile_s"])),
             "cc": kernelstats.snapshot(),
+            # the serve loop's idle/busy nanoseconds and the ANN
+            # descents' row counts (kernelstats.LOOP / ANN)
+            "loop": kernelstats.loop_snapshot(),
+            "ann": dict(kernelstats.ANN),
         }, []
+
+    def op_profile(self, meta, bufs):
+        """A profiler window the program owns (DeviceSupervisor.profile):
+        `start` opens jax's trace into `dir`, `stop` closes and writes
+        it. Only this process holds the chip, so only it can trace it;
+        the runner's `runner:*` spans (kernelstats.phase, runner.serve)
+        land in the same file as the device's operations. The Python
+        function tracer stays off: it would slow the very host work the
+        spans are there to time, and it is most of what `stop_trace`
+        has to write."""
+        import time
+
+        import jax
+
+        if meta["action"] == "start":
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(meta["dir"], profiler_options=opts)
+            return "ok", {"started": time.monotonic()}, []
+        t0 = time.monotonic()
+        jax.profiler.stop_trace()
+        return "ok", {"stopping": t0, "stopped": time.monotonic()}, []
 
     def op_vec_load(self, meta, bufs):
         key = meta["key"]

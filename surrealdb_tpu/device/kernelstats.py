@@ -1,4 +1,6 @@
-"""Kernel compile-shape accounting for the device runner.
+"""Kernel compile-shape accounting for the device runner, and the
+runner's own clock: what one op spent in each phase, and how the serve
+loop's time divides into waiting for work and doing it.
 
 A "miss" is a dispatch that had to compile a new (kernel, shape)
 combination in this process; a "hit" reuses an already-compiled
@@ -15,6 +17,9 @@ gauge by one sample (same discipline as telemetry.StageStat).
 """
 
 from __future__ import annotations
+
+import time
+from contextlib import contextmanager
 
 COUNTS = {"hits": 0, "misses": 0, "sharded": 0}
 _SEEN: set = set()
@@ -35,6 +40,71 @@ COMPILE = {"backend_compile_s": {}, "persistent_hits": 0,
 # compiles: the runner tells the supervisor, whose dispatch window then
 # covers a compile instead of reading it as a wedge
 ON_COMPILE = None
+
+
+# nanoseconds the op being served has spent in each phase so far
+# (`h2d`, `device`, `d2h`): the runner clears it before an op and sends
+# it in the reply's `t`, where the supervisor turns it into the
+# `runner_*` stages
+# lint: mem-account(one int per phase name, three names in the tree)
+PHASES: dict = {}
+# the serve loop's time since the runner announced `ready`: blocked in
+# recv_msg (`idle_ns`) or anything else (`busy_ns`); `op_status` reports
+# it as `loop`
+# lint: mem-account(fixed-key int counters, not derived state)
+LOOP = {"idle_ns": 0, "busy_ns": 0}
+_op_t0 = None  # monotonic_ns when the op in hand was received
+# graph-ANN descents (device/annstore.py search): `rows_scored` is what
+# the answers needed, unpadded riders x iters*expand*d_out plus the
+# probe rows once a search; `op_status` reports it as `ann`
+# lint: mem-account(fixed-key int counters, not derived state)
+ANN = {"searches": 0, "rows_scored": 0}
+
+
+@contextmanager
+def phase(name: str):
+    """Times one phase of the op in hand into PHASES and writes it into
+    the profiler's trace as `runner:<name>` (free unless a trace is
+    being taken), so the runner's host spans and the device's
+    operations lie on one clock."""
+    from jax.profiler import TraceAnnotation
+
+    t0 = time.monotonic_ns()
+    with TraceAnnotation("runner:" + name):
+        try:
+            yield
+        finally:
+            PHASES[name] = PHASES.get(name, 0) + time.monotonic_ns() - t0
+
+
+def loop_received(t_mark: int) -> int:
+    """The serve loop has an op in hand: everything since `t_mark` was
+    waiting for it. Returns now (the reply's `t.recv`)."""
+    global _op_t0
+    now = _op_t0 = time.monotonic_ns()
+    LOOP["idle_ns"] += now - t_mark
+    PHASES.clear()
+    return now
+
+
+def loop_replied() -> int:
+    """The op in hand is answered: everything since it was received was
+    work. Returns now (the next wait's `t_mark`)."""
+    global _op_t0
+    now = time.monotonic_ns()
+    LOOP["busy_ns"] += now - _op_t0
+    _op_t0 = None
+    return now
+
+
+def loop_snapshot() -> dict:
+    """LOOP as of now: the op in hand (the `status` that asks) counts
+    as busy up to this moment, so two snapshots differ by exactly the
+    wall time between them."""
+    out = dict(LOOP)
+    if _op_t0 is not None:
+        out["busy_ns"] += time.monotonic_ns() - _op_t0
+    return out
 
 
 def note_compile(kernel: str):

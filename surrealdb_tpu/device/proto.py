@@ -29,6 +29,13 @@ import struct
 import numpy as np
 
 _HDR = struct.Struct(">I")
+# a reply's `t`, the op's timeline on the runner: monotonic_ns when the
+# request was read and decoded (`recv`) and just before the reply is
+# sent (`ready`), then the nanoseconds the op spent in its `h2d`,
+# `device` and `d2h` phases. Packed, not a map: the codec is Python and
+# sits on every RPC's path (a five-key map cost 8 us more to encode and
+# decode than these 40 bytes)
+REPLY_T = struct.Struct("<5q")
 # device frames carry whole block caches (a sharded store re-ship after
 # a runner restart), so the cap is far above the KV wire's 256 MB
 MAX_FRAME = 16 << 30

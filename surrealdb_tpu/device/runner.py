@@ -18,6 +18,15 @@ Protocol (device/proto.py frames):
                                  window then covers the compile
   runner -> supervisor:          ("ok"|"stale"|"err", {seq, ...}, bufs)
 
+Every "ok"/"stale" reply's meta carries `cc` (compile-shape counters)
+and `t` (proto.REPLY_T), the op's timeline on CLOCK_MONOTONIC
+(time.monotonic_ns, the clock the supervisor stamps with too): `recv`
+when the request was read and decoded, `ready` just before the reply
+is sent, and the nanoseconds spent in the phases the op timed (`h2d`,
+`device`, `d2h`; kernelstats.phase). The same spans go into the
+profiler's trace as `runner:<op>`, `runner:<phase>` and `runner:idle`
+(blocked in recv).
+
 The loop is deliberately single-threaded and crash-only: any internal
 corruption is allowed to kill the process — the supervisor restarts it
 and the serving side re-ships block caches from KV truth."""
@@ -28,6 +37,7 @@ import os
 import signal
 import socket
 import sys
+import time
 import traceback
 
 
@@ -89,11 +99,16 @@ def serve(sock) -> None:
                      "jaxlib": jaxlib.__version__, "libtpu": libtpu},
         "compile_cache": cache_info, "mesh": mesh_info,
     })
+    from jax.profiler import TraceAnnotation
+
+    t_mark = time.monotonic_ns()
     while True:
         try:
-            op, meta, bufs = proto.recv_msg(sock)
+            with TraceAnnotation("runner:idle"):
+                op, meta, bufs = proto.recv_msg(sock)
         except ConnectionError:
             return  # supervisor went away: die with it
+        t_recv = kernelstats.loop_received(t_mark)
         if op == "shutdown":
             try:
                 proto.send_msg(sock, "ok", {"seq": meta.get("seq")})
@@ -102,13 +117,18 @@ def serve(sock) -> None:
             return
         seq = current["seq"] = meta.get("seq")
         try:
-            tag, out_meta, out_bufs = host.handle(op, meta, bufs)
+            with TraceAnnotation("runner:" + op, seq=seq):
+                tag, out_meta, out_bufs = host.handle(op, meta, bufs)
             out_meta = dict(out_meta)
             out_meta["seq"] = seq
             # compile-shape counters piggyback on every reply so the
             # supervisor's gauges track the subprocess without a
             # dedicated RPC per scrape
             out_meta["cc"] = kernelstats.snapshot()
+            ph = kernelstats.PHASES
+            out_meta["t"] = proto.REPLY_T.pack(
+                t_recv, time.monotonic_ns(), ph.get("h2d", 0),
+                ph.get("device", 0), ph.get("d2h", 0))
             proto.send_msg(sock, tag, out_meta, out_bufs)
         except ConnectionError:
             return
@@ -125,6 +145,7 @@ def serve(sock) -> None:
                 proto.send_msg(sock, "err", reply)
             except OSError:
                 return
+        t_mark = kernelstats.loop_replied()
 
 
 def main(fd: int) -> None:

@@ -239,7 +239,8 @@ class DeviceSupervisor:
         """One dispatch -> (tag, meta, bufs). Raises DeviceUnavailable
         (degrade to host), DeviceOpError (this op failed), or SdbError
         (mode=require and the device can't serve). Wall time lands in
-        the `device_rpc` stage stat."""
+        the `device_rpc` stage stat, and a live runner's reply cuts it
+        into six more (`_record_rpc_parts`)."""
         from surrealdb_tpu.telemetry import stage_record
 
         if self.mode == "off" or self._stop.is_set():
@@ -520,6 +521,53 @@ class DeviceSupervisor:
         persistent-cache hits. One RPC; raises like `call`."""
         _t, meta, _b = self.call("status", {})
         return meta
+
+    # `stop_trace` took 3-8 s on a v5e (PERF.md), and the first
+    # `start_trace` of a process sets the profiler up: far past a
+    # dispatch window, and no wedge
+    PROFILE_TIMEOUT_S = 120.0
+
+    def profile(self, dir: str, seconds: float) -> dict:
+        """Take a profiler trace of the runner for `seconds` while it
+        serves: jax's `.xplane.pb` under `dir`/plugins/profile/, with
+        the device's operations and the runner's own `runner:<op>`,
+        `runner:h2d|device|d2h` and `runner:idle` spans on one clock.
+        Blocks the caller for the window plus the time `stop_trace`
+        takes to write it; dispatches queued behind the start or the
+        stop wait for it (they are not timed out as a wedge) and see
+        its seconds in their `rpc_out`. Returns {dir, window_s,
+        stop_s}."""
+        started = self._profile_call({"action": "start", "dir": dir})
+        stopped = None
+        try:
+            time.sleep(max(float(seconds), 0.0))
+            stopped = self._profile_call({"action": "stop"})
+        finally:
+            if stopped is None:
+                # interrupted, or the stop itself failed: a trace left
+                # open would make every later `start_trace` raise
+                try:
+                    self._profile_call({"action": "stop"})
+                except Exception:
+                    pass  # runner gone or restarting: no trace left
+        return {"dir": dir,
+                "window_s": stopped["stopping"] - started["started"],
+                "stop_s": stopped["stopped"] - stopped["stopping"]}
+
+    def _profile_call(self, meta: dict) -> dict:
+        hold = self.PROFILE_TIMEOUT_S
+        with self._lock:
+            # like a declared compile: whoever queues behind this call
+            # keeps waiting instead of killing the runner
+            self._no_wedge_before = max(self._no_wedge_before,
+                                        time.monotonic() + hold)
+        try:
+            _t, out, _b = self.call("profile", meta, timeout_s=hold)
+        finally:
+            with self._lock:
+                self._no_wedge_before = \
+                    time.monotonic() + self.dispatch_timeout_s
+        return out
 
     def shutdown(self):
         """Stop the runner and every background thread (server drain).
@@ -817,6 +865,7 @@ class DeviceSupervisor:
 
     def _call_live(self, op, meta, bufs, base_timeout,
                    health_check=False):
+        t_call = time.monotonic_ns()
         budget = None if health_check else _query_remaining()
         eff = base_timeout if budget is None \
             else min(base_timeout, max(budget, 0.0))
@@ -867,7 +916,10 @@ class DeviceSupervisor:
                     f"(runner wedged)"
                 )
             raise DeviceUnavailable(f"dispatch timed out ({op})")
+        t_wake = time.monotonic_ns()
         tag, rmeta, rbufs = slot[1]
+        if not health_check:
+            _record_rpc_parts(rmeta.get("t"), t_call, t_wake)
         if tag == "err":
             if rmeta.get("_unavail"):
                 raise DeviceUnavailable(rmeta.get("error", "runner died"))
@@ -948,6 +1000,38 @@ class DeviceSupervisor:
         with self._lock:
             return gen == self._gen and not self._stop.is_set() \
                 and self.state in ("ready", "degraded", "probing")
+
+
+def _record_rpc_parts(t, t_call: int, t_wake: int):
+    """One RPC cut in six stages on one clock (time.monotonic_ns is
+    CLOCK_MONOTONIC in both processes), from the reply's `t`
+    (proto.REPLY_T): `rpc_out` from `_call_live`'s entry until the
+    runner had read and decoded the request (the queue to the send
+    thread, encode, socket, decode), `runner_h2d`, `runner_device`,
+    `runner_d2h` as the op timed them (kernelstats.phase; 0 for an op
+    that times none), `runner_other` the rest between the runner's
+    `recv` and `ready` stamps, `rpc_back` from `ready` until the waiter
+    ran again (encode, socket, recv-loop thread, event, the interpreter
+    lock). They partition the call, so they sum to its `device_rpc`. A
+    reply without `t` (an inline host, an error, an older runner) or a
+    negative difference records nothing."""
+    from surrealdb_tpu.device.proto import REPLY_T
+    from surrealdb_tpu.telemetry import stage_record
+
+    if not isinstance(t, bytes) or len(t) != REPLY_T.size:
+        return
+    recv, ready, h2d, dev, d2h = REPLY_T.unpack(t)
+    out = recv - t_call
+    other = ready - recv - h2d - dev - d2h
+    back = t_wake - ready
+    if min(out, h2d, dev, d2h, other, back) < 0:
+        return
+    stage_record("rpc_out", out)
+    stage_record("runner_h2d", h2d)
+    stage_record("runner_device", dev)
+    stage_record("runner_d2h", d2h)
+    stage_record("runner_other", other)
+    stage_record("rpc_back", back)
 
 
 def require_refusal(mode: str, platform, jax_platforms: str):

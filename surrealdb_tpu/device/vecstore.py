@@ -219,11 +219,18 @@ class VecStore:
         self.ensure()
         import jax.numpy as jnp
 
-        from surrealdb_tpu.device.kernelstats import note_shape
+        from surrealdb_tpu.device.kernelstats import note_shape, phase
 
+        # the op's timeline (kernelstats.phase): `h2d` is the jnp.asarray
+        # of the queries; `device` every launch (the eager pad/reshape
+        # programs too) until the FIRST output is on the host, which is
+        # the wait that was always there (a block_until_ready before it
+        # costs one more round trip to the chip, 0.46 ms a query on a
+        # v5e); `d2h` the copies of the other outputs
         cfg = self.cfg
         n = self.vecs.shape[0]
-        qs = jnp.asarray(np.ascontiguousarray(qvs, dtype=np.float32))
+        with phase("h2d"):
+            qs = jnp.asarray(np.ascontiguousarray(qvs, dtype=np.float32))
         if self.mesh is not None:
             if self.device_rank is not None:
                 from surrealdb_tpu.parallel.mesh import sharded_rank_rescore
@@ -242,13 +249,15 @@ class VecStore:
                     qc = np.asarray(qvs[s:s + chunk], dtype=np.float32)
                     if qc.shape[0] < chunk:
                         qc = np.pad(qc, ((0, chunk - qc.shape[0]), (0, 0)))
-                    dc, ic = sharded_rank_rescore(
-                        self.mesh, self.device_rank, self.device_full, qc,
-                        k, kc, self.metric, self.device_x2,
-                        self.device_norms, self.device_valid,
-                    )
-                    d_parts.append(np.asarray(dc))
-                    i_parts.append(np.asarray(ic))
+                    with phase("device"):
+                        dc, ic = sharded_rank_rescore(
+                            self.mesh, self.device_rank, self.device_full,
+                            qc, k, kc, self.metric, self.device_x2,
+                            self.device_norms, self.device_valid,
+                        )
+                        d_parts.append(np.asarray(dc))
+                    with phase("d2h"):
+                        i_parts.append(np.asarray(ic))
                 dists = np.concatenate(d_parts)[:b_total]
                 ids = np.concatenate(i_parts)[:b_total]
             else:
@@ -256,10 +265,14 @@ class VecStore:
 
                 note_shape("sharded_knn",
                            (self.vecs.shape, qs.shape[0], k, self.metric))
-                dists, ids = sharded_knn(
-                    self.mesh, self.device_vecs, qs, self.device_valid, k,
-                    self.metric, self.mink_p,
-                )
+                with phase("device"):
+                    dists, ids = sharded_knn(
+                        self.mesh, self.device_vecs, qs, self.device_valid,
+                        k, self.metric, self.mink_p,
+                    )
+                    dists = np.asarray(dists)
+                with phase("d2h"):
+                    ids = np.asarray(ids)
             return self._pairs(dists, ids)
         if self.rank_mode == "int8":
             from surrealdb_tpu.ops.topk import knn_rank_int8
@@ -273,14 +286,15 @@ class VecStore:
             )
             note_shape("knn_rank_int8",
                        (self.vecs.shape, chunk, kc, self.metric))
-            if bucket != b_total:
-                qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
-            cand = knn_rank_int8(
-                self.device_rank, self.device_arow, self.device_x2,
-                self.device_valid, qs.reshape(r, chunk, -1), kc,
-                self.metric,
-            )
-            cand = np.asarray(cand).reshape(bucket, kc)[:b_total]
+            with phase("device"):
+                if bucket != b_total:
+                    qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
+                cand = knn_rank_int8(
+                    self.device_rank, self.device_arow, self.device_x2,
+                    self.device_valid, qs.reshape(r, chunk, -1), kc,
+                    self.metric,
+                )
+                cand = np.asarray(cand).reshape(bucket, kc)[:b_total]
             return (
                 {"mode": "cand", "rank_mode": self.rank_mode, "kc": kc},
                 [np.ascontiguousarray(cand, np.int32)],
@@ -298,41 +312,49 @@ class VecStore:
             note_shape("knn_rank_rescore",
                        (self.vecs.shape, chunk, min(k, kc), kc,
                         self.metric))
-            if bucket != b_total:
-                qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
-            dists, ids = knn_rank_rescore(
-                self.device_rank, self.device_full,
-                qs.reshape(r, chunk, -1), min(k, kc), kc, self.metric,
-                self.device_x2, self.device_norms, self.device_valid,
-            )
-            dists = np.asarray(dists).reshape(bucket, -1)[:b_total]
-            ids = np.asarray(ids).reshape(bucket, -1)[:b_total]
+            with phase("device"):
+                if bucket != b_total:
+                    qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
+                dists, ids = knn_rank_rescore(
+                    self.device_rank, self.device_full,
+                    qs.reshape(r, chunk, -1), min(k, kc), kc, self.metric,
+                    self.device_x2, self.device_norms, self.device_valid,
+                )
+                dists = np.asarray(dists).reshape(bucket, -1)[:b_total]
+            with phase("d2h"):
+                ids = np.asarray(ids).reshape(bucket, -1)[:b_total]
             return self._pairs(dists, ids)
         if n > cfg["block_rows"]:
             from surrealdb_tpu.ops.topk import knn_search_blocked
 
             note_shape("knn_search_blocked",
                        (self.vecs.shape, qs.shape[0], k, self.metric))
-            dists, ids = knn_search_blocked(
-                self.device_vecs, qs, k, self.metric, self.mink_p,
-                self.device_valid,
-            )
+            with phase("device"):
+                dists, ids = knn_search_blocked(
+                    self.device_vecs, qs, k, self.metric, self.mink_p,
+                    self.device_valid,
+                )
+                dists = np.asarray(dists)
         else:
             from surrealdb_tpu.ops.topk import knn_search
 
             note_shape("knn_search",
                        (self.vecs.shape, qs.shape[0], k, self.metric))
-            dists, ids = knn_search(
-                self.device_vecs, qs, k, self.metric, self.mink_p,
-                self.device_valid,
-            )
+            with phase("device"):
+                dists, ids = knn_search(
+                    self.device_vecs, qs, k, self.metric, self.mink_p,
+                    self.device_valid,
+                )
+                dists = np.asarray(dists)
+        with phase("d2h"):
+            ids = np.asarray(ids)
         return self._pairs(dists, ids)
 
     def _pairs(self, dists, ids):
         return (
             {"mode": "pairs", "rank_mode": self.rank_mode},
             [
-                np.ascontiguousarray(np.asarray(dists), np.float32),
-                np.ascontiguousarray(np.asarray(ids), np.int32),
+                np.ascontiguousarray(dists, np.float32),
+                np.ascontiguousarray(ids, np.int32),
             ],
         )

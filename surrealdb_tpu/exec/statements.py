@@ -5225,6 +5225,7 @@ def _s_info(n: InfoStmt, ctx: Ctx):
         # owns the backend, INFO reads its health snapshot
         from surrealdb_tpu.device import get_supervisor
         from surrealdb_tpu.telemetry import (
+            cpu_usage as _cpu_usage,
             stage_snapshot as _stage_snapshot,
         )
 
@@ -5284,7 +5285,9 @@ def _s_info(n: InfoStmt, ctx: Ctx):
                 repl_info = None
         out = {
             "available_parallelism": _os.cpu_count() or 1,
-            "cpu_usage": 0.0,
+            # CPU seconds over wall seconds since start: cores kept
+            # busy on average (the runner subprocess is not in it)
+            "cpu_usage": _cpu_usage(),
             "load_average": list(_os.getloadavg()),
             "memory_allocated": mem_kb * 1024,
             "memory_usage": mem_kb * 1024,

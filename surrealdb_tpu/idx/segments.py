@@ -58,6 +58,7 @@ import uuid
 import numpy as np
 
 from surrealdb_tpu import cnf, resource
+from surrealdb_tpu.telemetry import stage_record
 
 # process-wide AGGREGATE counters (fixed keys, trivially bounded).
 # Gates that must be isolated from other engines/datastores in the
@@ -734,9 +735,13 @@ class SegmentedAnn:
         hi = segs[-1].hi if segs else 0
         if hi < n:
             lists.append(self._exact_span(qvs, k, hi, n))
+        # stage `knn_post`: the k-way merge of the spans' lists (each
+        # graph span has recorded its own re-rank under the same name)
+        t_merge = time.perf_counter_ns()
         out = []
         for i in range(b):
             out.append(merge_topk(_NOCTX, [l[i] for l in lists], k))
+        stage_record("knn_post", time.perf_counter_ns() - t_merge)
         return out
 
     def _exact_span(self, qvs, k: int, lo: int, hi: int):
@@ -875,6 +880,10 @@ class SegmentedAnn:
                 ann.graph, m, fn, b, width, cfg["iters"],
                 min(cfg["expand"], width), kc, probe_fn=probe_fn,
             )
+        # stage `knn_post`: the host's work on this span once the
+        # candidates are back — the dirty-row merge and the exact
+        # re-rank of every rider
+        t_post = time.perf_counter_ns()
         extra = np.asarray(
             sorted(r for r in dirty if lo <= r < hi), np.int64
         )
@@ -912,6 +921,7 @@ class SegmentedAnn:
                     qvs[i:i + 1], k, lo, min(hi, len(eng.rids))
                 )[0]
             out.append(res)
+        stage_record("knn_post", time.perf_counter_ns() - t_post)
         return out
 
     # -- persisted per-segment artifacts ------------------------------------

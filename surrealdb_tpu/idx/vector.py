@@ -23,6 +23,7 @@ the runner process, never a query worker thread.
 from __future__ import annotations
 
 import threading
+import time
 import uuid
 
 import numpy as np
@@ -31,6 +32,7 @@ from surrealdb_tpu import key as K
 from surrealdb_tpu import resource
 from surrealdb_tpu.device.batcher import DeviceBatcher
 from surrealdb_tpu.err import SdbError
+from surrealdb_tpu.telemetry import stage_record
 from surrealdb_tpu.utils.rwlock import RWLock
 from surrealdb_tpu.val import NONE, RecordId, is_truthy
 
@@ -1098,6 +1100,10 @@ class TpuVectorIndex:
                 ann.graph, ann.built_n, fn, b, width, cfg["iters"],
                 min(cfg["expand"], width), kc, probe_fn=probe_fn,
             )
+        # stage `knn_post`: this dispatch's host work once the
+        # candidates are back — the unseen-rows merge and the exact
+        # re-rank of every rider
+        t_post = time.perf_counter_ns()
         extra_top = self._ann_extra_topk(ann, qvs, k, n)
         out = []
         for i in range(b):
@@ -1129,6 +1135,7 @@ class TpuVectorIndex:
                 if len(res_i) < min(k, int(self.valid.sum())):
                     res_i = self._host_knn_single(qvs[i], k)
             out.append(res_i)
+        stage_record("knn_post", time.perf_counter_ns() - t_post)
         return out
 
     # -- search -------------------------------------------------------------
@@ -1136,11 +1143,7 @@ class TpuVectorIndex:
         """Top-k nearest records. `cond`: optional per-record predicate —
         handled by oversample + host truthiness check + refill
         (SURVEY.md hard-parts: cond-filtered KNN)."""
-        import time as _time
-
-        from surrealdb_tpu.telemetry import stage_record
-
-        t0 = _time.perf_counter_ns()
+        t0 = time.perf_counter_ns()
         with self.lock:
             self._pins += 1  # pin: eviction must not race this query
         try:
@@ -1149,9 +1152,11 @@ class TpuVectorIndex:
         finally:
             with self.lock:
                 self._pins -= 1
-            # wall time inside the index: cache sync + batcher wait +
-            # kernel (device RPC time shows separately as device_rpc)
-            stage_record("index_knn", _time.perf_counter_ns() - t0)
+            # wall time inside the index: the cache sync check, then
+            # the batcher's `batch_wait` + `batch_ride` of this rider;
+            # its batch's `batch_dispatch` (⊃ `device_rpc`, `knn_post`)
+            # is recorded once by the thread that dispatched it
+            stage_record("index_knn", time.perf_counter_ns() - t0)
 
     def _knn(self, q, k: int, ctx, ef=None, cond=None, cond_ctx=None):
         self.sync(ctx)
@@ -1430,6 +1435,9 @@ class TpuVectorIndex:
             # sup.unavailable: SdbError in require mode (the query must
             # fail loudly), DeviceUnavailable (degrade to host) in auto
             raise sup.unavailable("vec cache thrashing")
+        # stage `knn_post`: the host's share of this dispatch after the
+        # RPC — the exact rescore of int8 candidates, ids -> record ids
+        t_post = time.perf_counter_ns()
         self.rank_mode = meta.get("rank_mode")
         nd = int(meta.get("mesh_ndev", 1) or 1)
         if nd > self._dev_mesh:
@@ -1457,16 +1465,18 @@ class TpuVectorIndex:
                     for j in sel
                     if np.isfinite(d[j])
                 ])
-            return out
-        dists, ids = bufs
-        return [
-            [
-                (self.rids[int(i)], float(d))
-                for d, i in zip(drow, irow)
-                if 0 <= i < n and np.isfinite(d)
+        else:
+            dists, ids = bufs
+            out = [
+                [
+                    (self.rids[int(i)], float(d))
+                    for d, i in zip(drow, irow)
+                    if 0 <= i < n and np.isfinite(d)
+                ]
+                for drow, irow in zip(dists, ids)
             ]
-            for drow, irow in zip(dists, ids)
-        ]
+        stage_record("knn_post", time.perf_counter_ns() - t_post)
+        return out
 
     def _host_distances(self, qv, xs=None):
         # the reference accumulates in f64 for most metrics regardless of

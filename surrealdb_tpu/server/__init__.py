@@ -22,6 +22,7 @@ from surrealdb_tpu import inflight as _inflight
 from surrealdb_tpu.err import SdbError, ShedError
 from surrealdb_tpu.kvs.ds import Datastore, Session
 from surrealdb_tpu.rpc import RpcError, RpcSession
+from surrealdb_tpu.telemetry import stage_record
 from surrealdb_tpu.val import to_json
 
 _WS_MAGIC = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
@@ -244,21 +245,25 @@ class SurrealHandler(BaseHTTPRequestHandler):
         except OSError:
             return True
 
-    def _run_watched(self, fn, handle):
+    def _run_watched(self, fn, handle) -> int:
         """Run `fn` in a worker thread while THIS thread watches the
         client socket: a disconnect flips the query's cancel flag, so an
         abandoned request releases its worker slot within one
-        check_deadline interval instead of running to completion."""
+        check_deadline interval instead of running to completion.
+        Returns the worker thread's CPU nanoseconds (the `request`
+        stage's `cpu_ms`: this thread only waits meanwhile)."""
         done = threading.Event()
         out: dict = {}
 
         def run():
+            cpu0 = time.thread_time_ns()
             try:
                 with _inflight.activate(handle):
                     fn()
             except BaseException as e:  # re-raised on the dispatch thread
                 out["exc"] = e
             finally:
+                out["cpu_ns"] = time.thread_time_ns() - cpu0
                 done.set()
 
         t = threading.Thread(target=run, daemon=True,
@@ -272,6 +277,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             done.wait()
         if "exc" in out:
             raise out["exc"]
+        return out["cpu_ns"]
 
     # -- routes -------------------------------------------------------------
     def _dispatch(self, fn):
@@ -302,6 +308,11 @@ class SurrealHandler(BaseHTTPRequestHandler):
                 or (path == "/rpc" and self.command == "GET")):
             fn()
             return
+        # stage `request`: from before admission until `fn` has written
+        # the reply (body read, decode, session, execute, encode, write
+        # are all inside it), with the worker thread's CPU time. A shed
+        # request is none; one whose handler raised has no CPU reading.
+        t0 = time.perf_counter_ns()
         deadline = self._deadline()
         ticket = self.admission.admit(deadline)
         handle = self.ds.inflight.open(
@@ -310,11 +321,13 @@ class SurrealHandler(BaseHTTPRequestHandler):
             f"{self.command} {path}", deadline,
         )
         handle.edge = True  # first ds.execute refines to the real SQL
+        cpu_ns = None
         try:
-            self._run_watched(fn, handle)
+            cpu_ns = self._run_watched(fn, handle)
         finally:
             self.ds.inflight.close(handle)
             ticket.release()
+            stage_record("request", time.perf_counter_ns() - t0, cpu_ns)
 
     def do_GET(self):
         self._dispatch(self._do_GET)
@@ -793,6 +806,10 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     }))
                     continue
                 rid = req.get("id")
+                # stage `request`, as `_dispatch_gated` records it: this
+                # thread does all of a WebSocket request's work
+                t0 = time.perf_counter_ns()
+                cpu0 = time.thread_time_ns()
                 try:
                     # per-REQUEST admission + deadline: one connection
                     # cannot monopolize worker slots between queries,
@@ -826,6 +843,8 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     self._ws_send(pack(
                         {"id": rid, "result": jsonify(out)}
                     ))
+                    stage_record("request", time.perf_counter_ns() - t0,
+                                 time.thread_time_ns() - cpu0)
                 except ShedError as e:
                     self._ws_send(pack({
                         "id": rid,

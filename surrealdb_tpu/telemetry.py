@@ -8,8 +8,6 @@ the same data is surfaced as pull endpoints instead of OTLP push:
 - `/metrics` (server): Prometheus text format — datastore counters,
   query-duration histogram, HTTP/WS/RPC counters.
 - `/telemetry/traces` (server): recent per-query span trees as JSON.
-- `SURREAL_TELEMETRY_FILE`: optional JSONL span export (one span tree
-  per completed query) for offline ingestion.
 
 Spans are thread-local and cheap: `span(name)` context managers nest;
 each query's root span lands in a bounded ring buffer.
@@ -17,8 +15,6 @@ each query's root span lands in a bounded ring buffer.
 
 from __future__ import annotations
 
-import json
-import os
 import threading
 import time
 from contextlib import contextmanager
@@ -32,55 +28,66 @@ class StageStat:
     lock-free: under the GIL a lost increment during a race skews a
     metric by one sample, which is acceptable for observability — a
     per-stage lock would put two atomic ops on every query's hot path
-    for data nobody reads at that granularity."""
+    for data nobody reads at that granularity.
 
-    __slots__ = ("count", "total_ns", "max_ns", "last_ns")
+    `cpu_ns` stays None unless the stage is recorded with the thread's
+    CPU time (only `request` is: server/__init__.py), and `to_dict`
+    then carries `cpu_ms` beside the wall time."""
+
+    __slots__ = ("count", "total_ns", "max_ns", "cpu_ns")
 
     def __init__(self):
         self.count = 0
         self.total_ns = 0
         self.max_ns = 0
-        self.last_ns = 0
+        self.cpu_ns = None
 
-    def add(self, ns: int):
+    def add(self, ns: int, cpu_ns=None):
         self.count += 1
         self.total_ns += ns
-        self.last_ns = ns
         if ns > self.max_ns:
             self.max_ns = ns
+        if cpu_ns is not None:
+            self.cpu_ns = (self.cpu_ns or 0) + cpu_ns
 
     def to_dict(self) -> dict:
         c = self.count
-        return {
+        d = {
             "count": c,
             "total_ms": round(self.total_ns / 1e6, 3),
             "avg_us": round(self.total_ns / max(c, 1) / 1e3, 1),
             "max_us": round(self.max_ns / 1e3, 1),
-            "last_us": round(self.last_ns / 1e3, 1),
         }
+        if self.cpu_ns is not None:
+            d["cpu_ms"] = round(self.cpu_ns / 1e6, 3)
+        return d
 
 
 # Per-stage query timing (the PR-6 overhead strip's measurement hook):
-# process-wide so the serving edge (admission), the datastore (parse,
-# txn open), the executor (envelope, eval) and the device layer
-# (batcher wait, supervisor RPC) all land in ONE table regardless of
-# which Datastore/Telemetry instance they hang off. Stages surface in
-# /metrics, `INFO FOR SYSTEM` and tools/profile_query.py.
+# process-wide so the serving edge (request, admission), the datastore
+# (parse, txn open), the executor (envelope, eval) and the device layer
+# (batcher wait/ride/dispatch, the supervisor's RPC and its six parts)
+# all land in ONE table regardless of which Datastore/Telemetry
+# instance they hang off. Stages surface in /metrics, `INFO FOR SYSTEM`
+# and tools/profile_query.py; doc/operations.md lists them with what
+# contains what.
 _STAGES: dict[str, StageStat] = {}
 
 
-def stage_record(name: str, ns: int):
-    """Record `ns` nanoseconds spent in query stage `name`."""
+def stage_record(name: str, ns: int, cpu_ns=None):
+    """Record `ns` nanoseconds of wall time (and, where the caller
+    measured it, `cpu_ns` of its thread's CPU time) spent in query
+    stage `name`."""
     st = _STAGES.get(name)
     if st is None:
         # dict set is atomic under the GIL; a racing first-record for
         # the same stage leaves one winner and loses one sample
         st = _STAGES.setdefault(name, StageStat())
-    st.add(ns)
+    st.add(ns, cpu_ns)
 
 
 def stage_snapshot() -> dict:
-    """{stage: {count, total_ms, avg_us, max_us, last_us}} sorted by
+    """{stage: {count, total_ms, avg_us, max_us[, cpu_ms]}} sorted by
     total time descending."""
     items = sorted(_STAGES.items(), key=lambda kv: -kv[1].total_ns)
     return {k: v.to_dict() for k, v in items}
@@ -89,6 +96,20 @@ def stage_snapshot() -> dict:
 def stage_reset():
     """Clear stage stats (tools/profile_query.py between runs)."""
     _STAGES.clear()
+
+
+# this module is imported with the datastore, so for a server these are
+# the process's start
+_WALL0 = time.monotonic()
+_CPU0 = time.process_time()
+
+
+def cpu_usage() -> float:
+    """CPU seconds this process has used over the wall seconds it has
+    run (both since this module was imported): the cores it has kept
+    busy on average. `INFO FOR SYSTEM` reports it."""
+    return round((time.process_time() - _CPU0)
+                 / max(time.monotonic() - _WALL0, 1e-3), 4)
 
 
 class Span:
@@ -128,8 +149,6 @@ class Telemetry:
         self.hist_sum_ms = 0.0
         self.hist_count = 0
         self._local = threading.local()
-        self._export_path = os.environ.get("SURREAL_TELEMETRY_FILE") or None
-        self._export_lock = threading.Lock()
         # gauges: name -> zero-arg callable sampled at scrape time (the
         # admission controller and in-flight registry register theirs)
         self.gauges: dict = {}
@@ -206,7 +225,7 @@ class Telemetry:
     @contextmanager
     def span(self, name: str, **attrs):
         """Nested span context; completing the outermost span records the
-        trace into the ring (and the JSONL export, when configured)."""
+        trace into the ring."""
         s = self.start(name, **attrs)
         try:
             yield s
@@ -232,12 +251,6 @@ class Telemetry:
             self.traces.append(s)
             if len(self.traces) > self.ring_size:
                 del self.traces[: self.ring_size // 2]
-        if self._export_path:
-            try:
-                with self._export_lock, open(self._export_path, "a") as f:
-                    f.write(json.dumps(s.to_dict()) + "\n")
-            except OSError:
-                pass
 
     def recent_traces(self, limit: int = 64):
         with self.lock:
