@@ -54,6 +54,7 @@ from __future__ import annotations
 import threading
 import time
 import uuid
+from bisect import bisect_right
 
 import numpy as np
 
@@ -123,16 +124,20 @@ class SealedSegment:
     graph yet — served exact), `ready` (graph serving), `empty` (no
     valid rows at snapshot — skipped). A segment never mutates rows;
     engine-side tombstones/overwrites are observed through `valid` /
-    `_ann_dirty` at query time."""
+    `_ann_dirty` at query time. `live` is the number of valid rows in
+    the span: counted under the table lock when the span is sealed,
+    moved with every flag of the span after that (`SegmentedAnn.flip`),
+    so a query never reduces the mask."""
 
-    __slots__ = ("lo", "hi", "sid", "state", "graph", "_tlock",
+    __slots__ = ("lo", "hi", "sid", "live", "state", "graph", "_tlock",
                  "dev_key", "seq", "acct", "__weakref__")
 
-    def __init__(self, lo: int, hi: int, sid: int, label: str,
+    def __init__(self, lo: int, hi: int, sid: int, live: int, label: str,
                  tlock: threading.Lock):
         self.lo = int(lo)
         self.hi = int(hi)
         self.sid = int(sid)
+        self.live = int(live)
         self.state = "pending"
         self.graph = None  # (AnnIndex, row_map | None), set atomically
         # the coordinator's table lock: graph installs happen under it,
@@ -317,7 +322,24 @@ class SegmentedAnn:
         label = f"{eng.key[2]}.{eng.key[3]}" + (
             f"[{eng.label}]" if eng.label else ""
         )
-        return SealedSegment(lo, hi, self._sid, label, self.lock)
+        # the span's flags are counted under the table lock, which
+        # `flip` holds around every later change to one of them
+        live = int(np.count_nonzero(eng.valid[lo:hi]))
+        return SealedSegment(lo, hi, self._sid, live, label, self.lock)
+
+    def flip(self, row: int, flag: bool):
+        """Set one flag of the engine's mask (to the other value than
+        it has) and move the count of the sealed span that holds the
+        row with it. Called by the engine's tombstone and revive sites
+        under its write lock; the table lock makes flag and count one
+        step against a seal or a merge counting the same span."""
+        with self.lock:
+            self.engine.valid[row] = flag
+            # spans ascend from row 0 without gaps: the first one that
+            # ends above the row holds it, else the row is in the tail
+            i = bisect_right(self.segs, row, key=lambda s: s.hi)
+            if i < len(self.segs):
+                self.segs[i].live += 1 if flag else -1
 
     def _seal_locked(self) -> bool:
         """Apply the seal policy (caller holds the table lock). The
@@ -389,7 +411,7 @@ class SegmentedAnn:
             # the adopted legacy graph counts its build-time dead rows
             # as staleness on purpose — one bounded rebuild compacts
             # them out), so every invalid row in the span is drift
-            dead = int(np.count_nonzero(~valid[seg.lo:seg.hi]))
+            dead = seg.span() - seg.live
         dirty = sum(1 for r in dirty_keys if seg.lo <= r < seg.hi)
         frac = max(float(cnf.KNN_SEG_TOMB_FRAC), 0.01)
         return (max(dead, 0) + dirty) / max(ann.built_n, 1) > frac
@@ -845,11 +867,13 @@ class SegmentedAnn:
         lo, hi = seg.lo, seg.hi
         valid = eng.valid
         m = ann.built_n
-        if row_map is not None:
-            live_graph = int(np.count_nonzero(valid[row_map]))
-        else:
-            live_graph = int(np.count_nonzero(valid[lo:lo + m]))
-        valid_span = int(np.count_nonzero(valid[lo:hi]))
+        # the span's kept count, not a reduction over its flags: numpy
+        # would give the interpreter lock up around it, between the
+        # dispatcher and its RPC. An identity graph covers its whole
+        # span (`_build_ann_for`, `_adopt_legacy`): m == hi - lo
+        valid_span = seg.live
+        live_graph = valid_span if row_map is None \
+            else int(np.count_nonzero(valid[row_map]))
         if valid_span == 0:
             return [[] for _ in range(len(qvs))]
         # per-segment oversampling: a tombstone-dense graph must

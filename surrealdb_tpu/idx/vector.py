@@ -175,9 +175,27 @@ class _Coalescer(DeviceBatcher):
             return rw.read()
         return self.index.lock
 
+    def _stack(self, payloads) -> np.ndarray:
+        """The riders' vectors as one C-contiguous [B, D] array of the
+        index's dtype, built from their buffers: one `bytes.join`, one
+        `frombuffer`. numpy gives the interpreter lock up around every
+        copy of more than 500 elements, and among the request threads
+        the dispatcher then queues to get it back once a rider
+        (`np.stack`, or a row assignment into a preallocated buffer);
+        a join of buffers never lets go. `_as_vector` hands every rider
+        a contiguous 1-D array of that dtype already, so the conversion
+        copies nothing there. The result is read-only."""
+        dtype = getattr(self.index, "dtype", None)
+        if dtype is None:  # a test double: the riders' own
+            dtype = payloads[0][0].dtype
+        rows = [np.ascontiguousarray(q, dtype) for q, _k in payloads]
+        return np.frombuffer(b"".join(rows), dtype).reshape(
+            len(rows), rows[0].size
+        )
+
     def _dispatch(self, payloads):
         kmax = max(k for _q, k in payloads)
-        qvs = np.stack([q for q, _k in payloads])
+        qvs = self._stack(payloads)
         # the routed engine entry when the index has one; test doubles
         # expose only the raw device kernel
         fn = getattr(self.index, "knn_batch", None) \
@@ -194,7 +212,7 @@ class _Coalescer(DeviceBatcher):
 
         get_supervisor().note_fallback()
         kmax = max(k for _q, k in payloads)
-        qvs = np.stack([q for q, _k in payloads])
+        qvs = self._stack(payloads)
         with self._read_lock():
             results = self.index._host_knn_multi(qvs, kmax)
         return [pairs[:k] for (_q, k), pairs in zip(payloads, results)]
@@ -319,6 +337,33 @@ class TpuVectorIndex:
             evict=self._mem_evict_stats, owner=self,
         )
 
+    # -- the tombstone mask and its live-row count --------------------------
+
+    @property
+    def valid(self) -> np.ndarray:
+        return self._valid
+
+    @valid.setter
+    def valid(self, mask: np.ndarray):
+        # a whole new mask (empty store, eviction, append, repack):
+        # `live` is counted here, once a write, so that no query has to
+        # reduce the mask (a reduction over more than 500 flags gives
+        # the interpreter lock up, and every request thread then queues
+        # to get it back). Single flags change through `_flip`.
+        self.live = int(np.count_nonzero(mask))
+        self._valid = mask
+
+    def _flip(self, row: int, flag: bool):
+        """Tombstone or revive one row whose flag differs from `flag`
+        (caller holds the write lock): the mask, the store's live count
+        and the count of the row's sealed span move together."""
+        segs = self._segs
+        if segs is None:
+            self._valid[row] = flag
+        else:
+            segs.flip(row, flag)
+        self.live += 1 if flag else -1
+
     # -- resource accounting ------------------------------------------------
 
     def _vec_mem_bytes(self) -> int:
@@ -421,7 +466,7 @@ class TpuVectorIndex:
                 if self._apply_log(ctx, self.version, ver):
                     self.version = ver
                     frag = (
-                        1.0 - (self.valid.sum() / max(len(self.valid), 1))
+                        1.0 - self.live / len(self.valid)
                         if len(self.valid)
                         else 0.0
                     )
@@ -458,7 +503,7 @@ class TpuVectorIndex:
                 if row < len(self.valid):
                     if self.valid[row]:
                         self._ann_dead += 1
-                    self.valid[row] = False
+                        self._flip(row, False)
                 else:
                     # the row was appended EARLIER IN THIS BATCH and is
                     # still in the pending buffers — dropping the
@@ -470,7 +515,8 @@ class TpuVectorIndex:
             vec = np.frombuffer(raw, dtype=self.dtype)
             if row is not None and row < len(self.vecs):
                 self.vecs[row] = vec
-                self.valid[row] = True
+                if not self.valid[row]:
+                    self._flip(row, True)
                 # the ANN graph/int8 snapshot no longer matches this
                 # row: brute-merge it at query time until a rebuild
                 self._ann_mut += 1
@@ -603,7 +649,7 @@ class TpuVectorIndex:
                     ])
                     self.version = ver
                 if len(self.valid):
-                    frag = 1.0 - (self.valid.sum() / len(self.valid))
+                    frag = 1.0 - self.live / len(self.valid)
             if frag <= 0.25:
                 self._maybe_maintain()
                 return
@@ -628,7 +674,7 @@ class TpuVectorIndex:
         with self.lock:
             self._pins += 1  # pin: eviction must not race this search
         try:
-            n = int(self.valid.sum()) if len(self.valid) else 0
+            n = self.live
             if n == 0:
                 return []
             k = min(k, n)
@@ -649,7 +695,7 @@ class TpuVectorIndex:
     def residency(self) -> dict:
         """Index-serving residency for INFO FOR SYSTEM / /metrics."""
         out = {
-            "rows": int(self.valid.sum()) if len(self.valid) else 0,
+            "rows": self.live,
             "bytes": int(self.vecs.nbytes),
             "version": int(self.version),
             "ann": self._ann_state,
@@ -1132,7 +1178,7 @@ class TpuVectorIndex:
                 # query exactly rather than short (rare path; the
                 # staleness counter is already scheduling a rebuild
                 # when deletions accumulate)
-                if len(res_i) < min(k, int(self.valid.sum())):
+                if len(res_i) < min(k, self.live):
                     res_i = self._host_knn_single(qvs[i], k)
             out.append(res_i)
         stage_record("knn_post", time.perf_counter_ns() - t_post)
@@ -1160,7 +1206,7 @@ class TpuVectorIndex:
 
     def _knn(self, q, k: int, ctx, ef=None, cond=None, cond_ctx=None):
         self.sync(ctx)
-        n = int(self.valid.sum())
+        n = self.live
         if n == 0:
             return []
         qv = _as_vector(q, self.dim, "knn query", self.dtype)
