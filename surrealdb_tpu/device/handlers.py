@@ -305,6 +305,8 @@ class DeviceHost:
             # descents' row counts (kernelstats.LOOP / ANN)
             "loop": kernelstats.loop_snapshot(),
             "ann": dict(kernelstats.ANN),
+            # the bag hops' riders, paths and overflows (kernelstats.CSR)
+            "csr": dict(kernelstats.CSR),
         }, []
 
     def op_profile(self, meta, bufs):
@@ -615,16 +617,44 @@ class DeviceHost:
         return "ok", {"mesh_ndev": _store_ndev(ent[1])}, \
             [mask]
 
+    def op_csr_bag_hop(self, meta, bufs):
+        """A batch of folded `->edge->node` chains with BAG semantics
+        (device/csrstore.py bag_hop): [B, 1 + C0] int32 riders in,
+        every level's true total and the riders' last levels out. A
+        store that lives on a mesh has no bag kernel and says so
+        (`refused`): the serving side walks its host CSR and counts the
+        query as host-routed."""
+        ent = self.csr.get(meta["key"])
+        if ent is None or ent[0] != list(meta["tag"]):
+            return "stale", {}, []
+        self.csr.move_to_end(meta["key"])
+        if not hasattr(ent[1], "bag_hop"):
+            return "refused", {"mesh_ndev": _store_ndev(ent[1])}, []
+        totals, flat = ent[1].bag_hop(bufs[0], meta["caps"])
+        return "ok", {}, [totals, flat]
+
     def op_csr_prewarm(self, meta, bufs):
         """Hop-depth ladder for a CSR graph: the first `->edge->`
         expansion after a ship/restart must not pay an XLA compile
         mid-query (the sql_graph_3hop bench measured 11.4 s of
-        first-query tax)."""
+        first-query tax). For each depth the set hop's two programs
+        and the bag hop's first rung from one start node, at every
+        rider bucket up to `BAG_PREWARM_RIDERS`."""
+        from surrealdb_tpu.device.csrstore import (
+            BAG_PREWARM_RIDERS, bag_caps,
+        )
 
         def warm(st, hops):
             start = np.zeros((1, st.n_nodes), np.uint8)
             for union in (False, True):
                 st.multi_hop(start, hops, union)
+            if not hasattr(st, "bag_hop"):
+                return
+            ladder = bag_caps(st.n_nodes, len(st.cols), 1, hops)
+            b = 1
+            while ladder and b <= BAG_PREWARM_RIDERS:
+                st.bag_hop(np.zeros((b, 2), np.int32), ladder[0])
+                b *= 2
 
         return self._prewarm_shapes(self.csr, meta, "hops", warm)
 

@@ -59,6 +59,14 @@ _op_t0 = None  # monotonic_ns when the op in hand was received
 # probe rows once a search; `op_status` reports it as `ann`
 # lint: mem-account(fixed-key int counters, not derived state)
 ANN = {"searches": 0, "rows_scored": 0}
+# bag hops over resident CSR blocks (device/csrstore.py bag_hop), for the
+# riders a capacity rung answered: `edges_gathered` is their paths summed
+# over every level (one column read each), `paths_out` the last level's
+# (the ids returned); a rider that passed its rung's capacity counts in
+# `overflows` alone. `op_status` reports it as `csr`
+# lint: mem-account(fixed-key int counters, not derived state)
+CSR = {"bag_riders": 0, "paths_out": 0, "edges_gathered": 0,
+       "overflows": 0}
 
 
 @contextmanager

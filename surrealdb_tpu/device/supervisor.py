@@ -202,6 +202,11 @@ class DeviceSupervisor:
         if self.mode != "off":
             self.counters["device_fallbacks"] += 1
 
+    def note_host_routed(self):
+        """A caller with a serving device answered from the host by
+        rule, not by trouble (counted once per query so answered)."""
+        self.counters["device_host_routed"] += 1
+
     def ensure_started(self):
         """Kick the async first spawn (idempotent, never blocks)."""
         if self.mode in ("off", "inline") or self._stop.is_set():

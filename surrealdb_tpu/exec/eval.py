@@ -9,6 +9,7 @@ planner, not from here.
 from __future__ import annotations
 
 import random as _random
+import time as _time
 
 from surrealdb_tpu import key as K
 from surrealdb_tpu.catalog import ParamDef
@@ -956,10 +957,12 @@ def _csr_pair_hop(frontier, g1, g2, ctx):
 
 
 def _csr_bag_pair_hop(val, g1, g2, ctx, hops=1):
-    """Host CSR fast path for plain `->edge->node` chain pairs with BAG
-    semantics. Engages when the adjacency cache is already valid, or the
-    frontier is large enough to amortize a build; returns None to fall
-    back to the per-record `~`-key scans."""
+    """CSR fast path for plain `->edge->node` chain pairs with BAG
+    semantics: the device bag hop when the runner is serving, else the
+    host CSR walk (equal element for element). Engages when the
+    adjacency cache is already valid, or the frontier is large enough to
+    amortize a build; returns None to fall back to the per-record
+    `~`-key scans. Stage `graph_hop` times the folded chain."""
     pat = _csr_pair_pattern(g1, g2)
     if pat is None:
         return None
@@ -993,8 +996,21 @@ def _csr_bag_pair_hop(val, g1, g2, ctx, hops=1):
     csr = get_csr(ctx.ds, ctx, node_tb, edge_tb, g1.dir)
     if not len(csr.rows):
         return None  # empty adjacency: per-record scans are authoritative
-    idxs = csr.hop_bag_idx([r.id for r in rids], hops)
-    return csr.materialize_rids(idxs, node_tb)
+    from surrealdb_tpu.device import get_supervisor
+    from surrealdb_tpu.telemetry import stage_record
+
+    t0 = _time.monotonic_ns()
+    try:
+        keys = [r.id for r in rids]
+        if get_supervisor().fast_path():
+            # a serving runner serves (KNN's rule): the chain rides the
+            # batcher to the resident CSR; None = the host has to walk
+            out = csr.hop_bag_served(keys, hops, node_tb)
+            if out is not None:
+                return out
+        return csr.materialize_rids(csr.hop_bag_idx(keys, hops), node_tb)
+    finally:
+        stage_record("graph_hop", _time.monotonic_ns() - t0)
 
 
 def _apply_graph(val, g: PGraph, ctx: Ctx):

@@ -16,6 +16,7 @@ degraded, or disabled — graph queries always complete on host.
 from __future__ import annotations
 
 import threading
+import time
 import uuid
 
 import numpy as np
@@ -37,6 +38,22 @@ def pack_csr(rows: np.ndarray, cols: np.ndarray, n_nodes: int):
     return np.cumsum(indptr), sorted_cols, order
 
 
+class _BagRider:
+    """One folded `->edge->node` chain on its way through the batcher:
+    the start indexes packed as the device op takes them, and what they
+    were mapped against."""
+
+    __slots__ = ("packed", "hops", "c0", "ladder", "node_epoch")
+
+    def __init__(self, packed: bytes, hops: int, c0: int, ladder: tuple,
+                 node_epoch: int):
+        self.packed = packed          # int32 [1 + c0]: count, indexes
+        self.hops = hops
+        self.c0 = c0
+        self.ladder = ladder          # device/csrstore.py bag_caps
+        self.node_epoch = node_epoch
+
+
 class CsrGraph:
     """node→node adjacency for one (node_tb, edge_tb, direction) pattern."""
 
@@ -53,6 +70,9 @@ class CsrGraph:
         # runner's copy goes stale and re-ships on the next hop
         self._dev_key = f"csr/{uuid.uuid4().hex[:16]}"
         self._dev_epoch = 0
+        # bumped by a full build only: a replay appends nodes, so an
+        # index taken before it still names the same node after it
+        self._node_epoch = 0
         self.indptr = None  # host CSR (sorted by row, stable)
         self.sorted_cols = None
         self.lock = threading.RLock()
@@ -205,6 +225,7 @@ class CsrGraph:
         self.cols = np.asarray(cols, np.int32)
         self.edge_ids = eids
         self._dev_epoch += 1
+        self._node_epoch += 1
         self.indptr = None
         self.sorted_cols = None
         self._node_rids = None  # node identity changed: drop the rid cache
@@ -281,51 +302,100 @@ class CsrGraph:
         entirely in index space — frontiers never materialize id values
         between hops. Returns a numpy array of node indexes."""
         with self.lock:
-            self._ensure_host()
             fr = []
             for idv in start_keys:
                 i = self.node_index.get(K.enc_value(idv))
                 if i is not None:
                     fr.append(i)
-            fr = np.asarray(fr, np.int64)
-            for _ in range(hops):
-                if not len(fr):
-                    break
-                if len(fr) == 1:
-                    i = int(fr[0])
-                    fr = self.sorted_cols[
-                        self.indptr[i]:self.indptr[i + 1]
-                    ].astype(np.int64, copy=False)
-                    continue
-                # vectorized multi-source gather: repeat each source's
-                # slice via cumulative offsets (no per-vertex Python loop)
-                starts = self.indptr[fr]
-                ends = self.indptr[fr + 1]
-                counts = (ends - starts).astype(np.int64)
-                total = int(counts.sum())
-                if total == 0:
-                    fr = fr[:0]
-                    continue
-                # index trick: positions 0..total-1 mapped to per-source
-                # offsets
-                offs = np.repeat(starts, counts)
-                base = np.repeat(np.cumsum(counts) - counts, counts)
-                pos = np.arange(total, dtype=np.int64) - base + offs
-                fr = self.sorted_cols[pos].astype(np.int64, copy=False)
-            return fr
+            return self._bag_walk(np.asarray(fr, np.int64), hops)
+
+    def _bag_walk(self, fr, hops: int):
+        """The host walk from node indexes (caller holds the lock)."""
+        self._ensure_host()
+        for _ in range(hops):
+            if not len(fr):
+                break
+            if len(fr) == 1:
+                i = int(fr[0])
+                fr = self.sorted_cols[
+                    self.indptr[i]:self.indptr[i + 1]
+                ].astype(np.int64, copy=False)
+                continue
+            # vectorized multi-source gather: repeat each source's
+            # slice via cumulative offsets (no per-vertex Python loop)
+            starts = self.indptr[fr]
+            ends = self.indptr[fr + 1]
+            counts = (ends - starts).astype(np.int64)
+            total = int(counts.sum())
+            if total == 0:
+                fr = fr[:0]
+                continue
+            # index trick: positions 0..total-1 mapped to per-source
+            # offsets
+            offs = np.repeat(starts, counts)
+            base = np.repeat(np.cumsum(counts) - counts, counts)
+            pos = np.arange(total, dtype=np.int64) - base + offs
+            fr = self.sorted_cols[pos].astype(np.int64, copy=False)
+        return fr
+
+    def hop_bag_served(self, start_keys: list, hops: int, node_tb: str):
+        """The folded chain on the device: the start keys' indexes ride
+        the cross-query batcher into one `csr_bag_hop` dispatch with
+        whatever other traversals are waiting, and the last level comes
+        back as node indexes, which become RecordIds here. Equal,
+        element for element, to `hop_bag_idx` + `materialize_rids`.
+        Returns None when the host has to walk instead (counted as
+        host-routed): a start list or a level past the capacity
+        ladder, a store on a mesh, or a rebuild that renumbered the
+        nodes meanwhile. Device trouble degrades a rider to the host
+        walk through the batcher's fallback, as a set hop does."""
+        from surrealdb_tpu.device.csrstore import (
+            BAG_MAX_START, bag_caps, pow2_at_least,
+        )
+
+        with self.lock:
+            index, node_epoch = self.node_index, self._node_epoch
+            n_nodes, n_edges = len(self.node_ids), len(self.rows)
+        fr = [i for i in (index.get(K.enc_value(idv)) for idv in start_keys)
+              if i is not None]
+        if not fr:
+            return []
+        c0 = pow2_at_least(len(fr))
+        ladder = bag_caps(n_nodes, n_edges, c0, int(hops)) \
+            if c0 <= BAG_MAX_START else ()
+        idxs = None
+        if ladder:
+            packed = np.asarray(
+                [len(fr)] + fr + [0] * (c0 - len(fr)), np.int32)
+            idxs = self._submit(_BagRider(packed.tobytes(), int(hops), c0,
+                                          ladder, node_epoch))
+        with self.lock:
+            if idxs is not None and self._node_epoch == node_epoch:
+                rids = self._rid_cache(node_tb)
+            else:
+                rids = None
+        if rids is None:
+            from surrealdb_tpu.device import get_supervisor
+
+            get_supervisor().note_host_routed()
+            return None
+        return [rids[j] for j in idxs.tolist()]
+
+    def _rid_cache(self, node_tb: str) -> list:
+        """idx -> RecordId, built once and shared (RecordIds are
+        immutable — handing out the same objects is safe and skips
+        per-row construction). Caller holds the lock."""
+        rids = getattr(self, "_node_rids", None)
+        if rids is None or len(rids) != len(self.node_ids):
+            rids = self._node_rids = [
+                RecordId(node_tb, v) for v in self.node_ids
+            ]
+        return rids
 
     def materialize_rids(self, idxs, node_tb: str) -> list:
-        """Node indexes -> RecordId list via a once-built shared cache
-        (RecordIds are immutable — handing out the same objects is safe
-        and skips per-row construction)."""
+        """Node indexes -> RecordId list via the shared cache."""
         with self.lock:
-            rids = getattr(self, "_node_rids", None)
-            if rids is None or len(rids) != len(self.node_ids):
-                from surrealdb_tpu.val import RecordId as _R
-
-                rids = self._node_rids = [
-                    _R(node_tb, v) for v in self.node_ids
-                ]
+            rids = self._rid_cache(node_tb)
         if hasattr(idxs, "tolist"):
             idxs = idxs.tolist()  # bulk int conversion beats per-element
         return [rids[j] for j in idxs]
@@ -381,6 +451,11 @@ class CsrGraph:
         concurrent traversals coalesce into one stacked-mask device
         call per (hops, union) shape; device trouble degrades each
         rider individually to the numpy multi-hop."""
+        return self._submit((start, hops, union))
+
+    def _submit(self, payload):
+        """One rider through this graph's batcher: a set hop's
+        (mask, hops, union) or a `_BagRider`."""
         b = self._batcher
         if b is None:
             from surrealdb_tpu.device import (
@@ -388,13 +463,66 @@ class CsrGraph:
             )
             from surrealdb_tpu.device.batcher import DeviceBatcher
 
-            b = DeviceBatcher(
-                dispatch=self._hop_dispatch,
-                fallback=self._hop_fallback,
-                retryable=(DeviceUnavailable, DeviceOpError),
-            )
-            self._batcher = b
-        return b.submit((start, hops, union))
+            with self.lock:
+                if self._batcher is None:
+                    self._batcher = DeviceBatcher(
+                        dispatch=self._dispatch,
+                        fallback=self._hop_fallback,
+                        retryable=(DeviceUnavailable, DeviceOpError),
+                    )
+                b = self._batcher
+        return b.submit(payload)
+
+    def _dispatch(self, payloads):
+        """One batch of the batcher: bag riders and set riders each go
+        to their own op."""
+        bag = [i for i, p in enumerate(payloads)
+               if isinstance(p, _BagRider)]
+        if not bag:
+            return self._hop_dispatch(payloads)
+        rest = [i for i, p in enumerate(payloads)
+                if not isinstance(p, _BagRider)]
+        out = [None] * len(payloads)
+        for idxs, run in ((bag, self._bag_dispatch),
+                          (rest, self._hop_dispatch)):
+            if idxs:
+                for i, res in zip(idxs, run([payloads[i] for i in idxs])):
+                    out[i] = res
+        return out
+
+    def _ship(self):
+        """(tag, loader) of the adjacency as it stands: the edges in
+        source order (each source's destinations in edge order), which
+        is the host CSR's own column. Only `loader()` copies anything,
+        and the supervisor calls it only when a ship is due."""
+        with self.lock:
+            self._ensure_host()
+            tag = [int(self._dev_epoch)]
+            n, indptr, cols = len(self.node_ids), self.indptr, \
+                self.sorted_cols
+
+        def loader():
+            return "csr_load", {"n_nodes": n}, [
+                np.repeat(np.arange(n, dtype=np.int32), np.diff(indptr)),
+                np.ascontiguousarray(cols, np.int32),
+            ]
+
+        return tag, loader
+
+    def _call_loaded(self, sup, op: str, meta: dict, bufs: list):
+        """One op on this graph's resident block: ship first if the
+        runner lacks this epoch, once more if it answers `stale`."""
+        tag, loader = self._ship()
+        meta = dict(meta, key=self._dev_key, tag=tag)
+        for _attempt in (0, 1):
+            sup.ensure_loaded(self._dev_key, tag, loader)
+            t, _meta, out = sup.call(op, meta, bufs)
+            if t != "stale":
+                return t, out
+            sup.forget(self._dev_key)
+        # two stale rounds: give up on the device for this batch
+        # (SdbError in require mode — surfaces loudly)
+        raise sup.unavailable("csr cache thrashing")
 
     def _hop_dispatch(self, payloads):
         """Batched hop expansion via the supervised runner: riders with
@@ -406,14 +534,6 @@ class CsrGraph:
         sup = get_supervisor()
         if not sup.fast_path():
             raise sup.unavailable(f"device {sup.state}")
-        tag = [int(self._dev_epoch)]
-
-        def loader():
-            return "csr_load", {"n_nodes": self.n_nodes()}, [
-                np.ascontiguousarray(self.rows),
-                np.ascontiguousarray(self.cols),
-            ]
-
         groups: dict = {}
         for i, (start, hops, union) in enumerate(payloads):
             # mask length rides the group key: a rider that built its
@@ -428,27 +548,68 @@ class CsrGraph:
             stacked = np.stack(
                 [payloads[i][0] for i in idxs]
             ).astype(np.uint8)
-            for _attempt in (0, 1):
-                sup.ensure_loaded(self._dev_key, tag, loader)
-                t, _meta, bufs = sup.call(
-                    "csr_hop",
-                    {"key": self._dev_key, "tag": tag,
-                     "hops": hops, "union": union},
-                    [stacked],
-                )
-                if t == "stale":
-                    sup.forget(self._dev_key)
-                    continue
-                break
-            else:
-                # two stale rounds: give up on the device for this
-                # batch (SdbError in require mode — surfaces loudly)
-                raise sup.unavailable("csr cache thrashing")
+            _t, bufs = self._call_loaded(
+                sup, "csr_hop", {"hops": hops, "union": union}, [stacked])
             masks = bufs[0].astype(bool)
             if masks.ndim == 1:
                 masks = masks[None, :]
             for j, i in enumerate(idxs):
                 out[i] = masks[j]
+        return out
+
+    def _bag_dispatch(self, riders: list) -> list:
+        """Folded chains via the supervised runner: riders of one
+        (start slots, capacities) shape share ONE `csr_bag_hop` call;
+        a few bytes a rider out, its last level back. Returns, in
+        order, each rider's node indexes, or None for a rider the
+        host has to walk (`hop_bag_served`). A rider whose true total
+        at some level passed the capacity it rode goes round again on
+        the lowest rung that holds what is known of it (the runner
+        counts it in `csr.overflows`); past the top rung it is the
+        host's. Raises as `_hop_dispatch` does."""
+        from surrealdb_tpu.device import get_supervisor
+        from surrealdb_tpu.telemetry import stage_record
+
+        sup = get_supervisor()
+        if not sup.fast_path():
+            raise sup.unavailable(f"device {sup.state}")
+        with self.lock:
+            node_epoch = self._node_epoch
+        out = [None] * len(riders)
+        waiting: dict = {}  # (start slots, caps) -> [rider]
+        for i, r in enumerate(riders):
+            if r.node_epoch == node_epoch:
+                waiting.setdefault((r.c0, r.ladder[0]), []).append(i)
+        post_ns = 0
+        while waiting:
+            (c0, caps), idxs = waiting.popitem()
+            # the batch from bytes, not np.stack: PERF.md section 6, PR 26
+            batch = np.frombuffer(
+                b"".join(riders[i].packed for i in idxs), np.int32
+            ).reshape(len(idxs), 1 + c0)
+            t, bufs = self._call_loaded(
+                sup, "csr_bag_hop", {"caps": list(caps)}, [batch])
+            t0 = time.monotonic_ns()
+            if t == "ok":
+                end = 0
+                for i, tot in zip(idxs, bufs[0].tolist()):
+                    over = [lv for lv, (n, c) in enumerate(zip(tot, caps))
+                            if n > c]
+                    if not over:
+                        out[i] = bufs[1][end:end + tot[-1]]
+                        end += tot[-1]
+                        continue
+                    # totals are true up to the first level that
+                    # overflowed; what follows it is cut short
+                    known = tot[:over[0] + 1]
+                    for rung in riders[i].ladder:
+                        if rung[0] > caps[0] and all(
+                                n <= c for n, c in zip(known, rung)):
+                            waiting.setdefault((c0, rung), []).append(i)
+                            break
+            # `refused` (a store on a mesh has no bag kernel): None
+            post_ns += time.monotonic_ns() - t0
+        stage_record("hop_post", post_ns)
         return out
 
     def _hop_fallback(self, payload):
@@ -457,6 +618,13 @@ class CsrGraph:
         from surrealdb_tpu.device import get_supervisor
 
         get_supervisor().note_fallback()
+        if isinstance(payload, _BagRider):
+            fr = np.frombuffer(payload.packed, np.int32)
+            with self.lock:
+                if self._node_epoch != payload.node_epoch:
+                    return None
+                return self._bag_walk(
+                    fr[1:1 + fr[0]].astype(np.int64), payload.hops)
         return self._host_multi_hop(*payload)
 
     def _host_multi_hop(self, start, hops: int, union: bool):

@@ -1277,9 +1277,7 @@ class TpuVectorIndex:
             # the "accelerator" is this host's own CPU (inline debug
             # mode or a CPU-platform runner): one BLAS pass here beats
             # shipping numpy-speed work through jax/IPC
-            sup.counters["device_host_routed"] = (
-                sup.counters.get("device_host_routed", 0) + 1
-            )
+            sup.note_host_routed()
             return False
         return True
 
