@@ -10,7 +10,6 @@ from __future__ import annotations
 import base64
 import hashlib
 import json
-import select
 import socket
 import struct
 import threading
@@ -76,26 +75,36 @@ class SurrealHandler(BaseHTTPRequestHandler):
     server_obj = None
     admission = None  # AdmissionController (None = unbounded dev mode)
     default_timeout_s = 0.0  # server default query budget (0 = none)
+    _watch_fd = None  # this connection's descriptor, once in the hang-up watch
+    _watching = False  # a request of this connection is under the watch now
 
     def log_message(self, fmt, *args):
         pass
 
     # -- helpers ------------------------------------------------------------
-    def _json(self, code: int, payload):
-        body = json.dumps(payload).encode()
+    def _send(self, code: int, body: bytes, ctype=None, headers=()):
+        """The whole reply in ONE `sendall`: status line, `Server`,
+        `Date`, `Content-Type`, `headers`, `Content-Length`, body. The
+        stdlib buffers the head; the body joins that buffer instead of
+        following it in a write of its own (each `sendall` gives the
+        interpreter lock up, and two small segments meet Nagle and the
+        client's delayed ACK). The request leaves the hang-up watch
+        first: once the reply is out the client may close."""
         self.send_response(code)
-        self.send_header("Content-Type", "application/json")
+        if ctype:
+            self.send_header("Content-Type", ctype)
+        for k, v in headers:
+            self.send_header(k, v)
         self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        self._headers_buffer += (b"\r\n", body)
+        self._unwatch()
+        self.flush_headers()
+
+    def _json(self, code: int, payload):
+        self._send(code, json.dumps(payload).encode(), "application/json")
 
     def _text(self, code: int, text: str, ctype="text/plain"):
-        body = text.encode()
-        self.send_response(code)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        self._send(code, text.encode(), ctype)
 
     def _body(self) -> bytes:
         from surrealdb_tpu import cnf
@@ -193,12 +202,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
         else:
             payload = json.dumps(to_json(body_v)).encode()
             hdrs.setdefault("content-type", "application/json")
-        self.send_response(status)
-        for k, v in hdrs.items():
-            self.send_header(k, v)
-        self.send_header("Content-Length", str(len(payload)))
-        self.end_headers()
-        self.wfile.write(payload)
+        self._send(status, payload, headers=hdrs.items())
 
     # -- admission / deadline / cancellation --------------------------------
     def _deadline(self):
@@ -217,67 +221,32 @@ class SurrealHandler(BaseHTTPRequestHandler):
             "error": str(e), "code": 503,
             "retry_after_ms": int(e.retry_after_s * 1000),
         }).encode()
-        self.send_response(503)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Retry-After",
-                         str(max(1, int(e.retry_after_s + 0.999))))
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        self._send(503, body, "application/json", [
+            ("Retry-After", str(max(1, int(e.retry_after_s + 0.999)))),
+        ])
 
-    def _conn_dropped(self) -> bool:
-        """True when the client socket is at EOF (peer went away). TLS
-        sockets reject MSG_PEEK (ValueError) — treat those as alive:
-        no disconnect watch, the deadline still bounds the work.
+    def _watch(self, handle):
+        """Put this request under the server's hang-up watch: a client
+        that goes away has `handle` cancelled, so an abandoned request
+        releases its slot within one check_deadline interval instead
+        of running to completion. The connection's descriptor joins
+        the watch with its first gated request and leaves it in
+        `finish`, before the socket can close."""
+        if self._watch_fd is None:
+            self._watch_fd = self.connection.fileno()
+            self.server.hangups.join(self._watch_fd)
+        self.server.hangups.begin(self._watch_fd, handle)
+        self._watching = True
 
-        Deliberate semantic: a half-close (client shutdown(SHUT_WR)
-        after sending the request) also reads as EOF and cancels the
-        query — the common reverse-proxy/server posture (nginx treats
-        client aborts the same way). Clients that half-close and still
-        expect a response must send a deadline instead."""
-        try:
-            r, _w, _x = select.select([self.connection], [], [], 0)
-            if not r:
-                return False
-            return self.connection.recv(1, socket.MSG_PEEK) == b""
-        except ValueError:
-            return False  # SSLSocket: flags unsupported
-        except OSError:
-            return True
+    def _unwatch(self):
+        if self._watching:
+            self._watching = False
+            self.server.hangups.end(self._watch_fd)
 
-    def _run_watched(self, fn, handle) -> int:
-        """Run `fn` in a worker thread while THIS thread watches the
-        client socket: a disconnect flips the query's cancel flag, so an
-        abandoned request releases its worker slot within one
-        check_deadline interval instead of running to completion.
-        Returns the worker thread's CPU nanoseconds (the `request`
-        stage's `cpu_ms`: this thread only waits meanwhile)."""
-        done = threading.Event()
-        out: dict = {}
-
-        def run():
-            cpu0 = time.thread_time_ns()
-            try:
-                with _inflight.activate(handle):
-                    fn()
-            except BaseException as e:  # re-raised on the dispatch thread
-                out["exc"] = e
-            finally:
-                out["cpu_ns"] = time.thread_time_ns() - cpu0
-                done.set()
-
-        t = threading.Thread(target=run, daemon=True,
-                             name="surreal-query-worker")
-        t.start()
-        try:
-            while not done.wait(0.05):
-                if not handle.cancel.is_set() and self._conn_dropped():
-                    handle.cancel.set()
-        finally:
-            done.wait()
-        if "exc" in out:
-            raise out["exc"]
-        return out["cpu_ns"]
+    def finish(self):
+        if self._watch_fd is not None:
+            self.server.hangups.leave(self._watch_fd)
+        super().finish()
 
     # -- routes -------------------------------------------------------------
     def _dispatch(self, fn):
@@ -310,9 +279,10 @@ class SurrealHandler(BaseHTTPRequestHandler):
             return
         # stage `request`: from before admission until `fn` has written
         # the reply (body read, decode, session, execute, encode, write
-        # are all inside it), with the worker thread's CPU time. A shed
-        # request is none; one whose handler raised has no CPU reading.
+        # are all inside it), wall and CPU time of THIS thread, which
+        # does all of a request's work. A shed request is none.
         t0 = time.perf_counter_ns()
+        cpu0 = time.thread_time_ns()
         deadline = self._deadline()
         ticket = self.admission.admit(deadline)
         handle = self.ds.inflight.open(
@@ -321,13 +291,16 @@ class SurrealHandler(BaseHTTPRequestHandler):
             f"{self.command} {path}", deadline,
         )
         handle.edge = True  # first ds.execute refines to the real SQL
-        cpu_ns = None
         try:
-            cpu_ns = self._run_watched(fn, handle)
+            self._watch(handle)
+            with _inflight.activate(handle):
+                fn()
         finally:
+            self._unwatch()
             self.ds.inflight.close(handle)
             ticket.release()
-            stage_record("request", time.perf_counter_ns() - t0, cpu_ns)
+            stage_record("request", time.perf_counter_ns() - t0,
+                         time.thread_time_ns() - cpu0)
 
     def do_GET(self):
         self._dispatch(self._do_GET)
@@ -420,11 +393,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             except SdbError as e:
                 self._json(404, {"error": str(e)})
                 return
-            self.send_response(200)
-            self.send_header("Content-Type", "application/octet-stream")
-            self.send_header("Content-Length", str(len(raw)))
-            self.end_headers()
-            self.wfile.write(raw)
+            self._send(200, raw, "application/octet-stream")
             return
         if path.startswith("/key/"):
             self._key_route("GET")
@@ -504,21 +473,13 @@ class SurrealHandler(BaseHTTPRequestHandler):
                 if fmt_out == "cbor":
                     from surrealdb_tpu import wire
 
-                    body = wire.encode(payload)
-                    mime = "application/cbor"
+                    self._send(200, wire.encode(payload), "application/cbor")
                 elif fmt_out == "fb":
                     from surrealdb_tpu import fb
 
-                    body = fb.encode(payload)
-                    mime = fb.MIME
+                    self._send(200, fb.encode(payload), fb.MIME)
                 else:
                     self._json(200, payload)
-                    return
-                self.send_response(200)
-                self.send_header("Content-Type", mime)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
 
             req = {}
             try:
@@ -879,6 +840,7 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
                 default_timeout_s=None) -> ThreadingHTTPServer:
     from surrealdb_tpu import cnf
     from surrealdb_tpu.server.admission import AdmissionController
+    from surrealdb_tpu.server.hangup import HangupWatch
 
     if max_inflight is None:
         max_inflight = cnf.HTTP_MAX_INFLIGHT
@@ -904,10 +866,20 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
         request_queue_size = 128
         daemon_threads = True
 
+        def __init__(self, *args):
+            self.admission = admission
+            # the disconnect watch of the gated routes
+            self.hangups = (HangupWatch(ds.telemetry)
+                            if admission is not None else None)
+            super().__init__(*args)  # a failed bind calls server_close
+
+        def server_close(self):
+            super().server_close()
+            if self.hangups is not None:
+                self.hangups.close()
+
     if not tls_cert:
-        srv = _HttpServer((host, port), handler)
-        srv.admission = admission
-        return srv
+        return _HttpServer((host, port), handler)
     # TLS termination in-process (reference ntw: axum_server rustls from
     # --web-crt/--web-key). The handshake runs in the per-connection
     # handler thread — doing it inside accept() would let one stalled
@@ -942,9 +914,7 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
                 return  # failed/stalled handshakes are routine noise
             super().handle_error(request, client_address)
 
-    srv = TlsServer((host, port), handler)
-    srv.admission = admission
-    return srv
+    return TlsServer((host, port), handler)
 
 
 def drain_and_shutdown(srv, ds: Datastore, drain_timeout_s: float) -> bool:

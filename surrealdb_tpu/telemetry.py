@@ -219,6 +219,11 @@ class Telemetry:
         stack = getattr(self._local, "stack", None)
         if stack and stack[-1] is s:
             stack.pop()
+        elif stack and s in stack:
+            # children left open (an exception between a `start` and
+            # its `try`): a thread that serves request after request
+            # must not keep them under every later query
+            del stack[stack.index(s):]
         if not stack:
             self._finish_trace(s)
 
