@@ -124,9 +124,9 @@ T0 = time.monotonic()
 
 
 def clustered_rows(n: int, dim: int, seed: int, std: float = 0.15):
-    """Embedding-shaped rows: n // 100 gaussian clusters (the shape of
-    bench.py `_clustered_rows`; i.i.d. gaussian at high dimension
-    resembles no deployment). Filled in place, chunk by chunk."""
+    """Embedding-shaped rows: n // 100 gaussian clusters (i.i.d.
+    gaussian at high dimension resembles no deployment). Filled in
+    place, chunk by chunk."""
     rng = np.random.default_rng(seed)
     nc = max(n // 100, 8)
     centers = rng.standard_normal((nc, dim), dtype=np.float32)
@@ -243,7 +243,7 @@ class Client:
 
 
 def bulk_vectors(ds, table: str, ix: str, xs, chunk: int = 50_000):
-    """The KV bulk route (bench.py `_bulk_vectors`): records + `he`
+    """The KV bulk route: records + `he`
     index state + the `vn` version, no op log — the first search
     rebuilds from the `he` keys."""
     from surrealdb_tpu import key as K
@@ -269,7 +269,7 @@ def bulk_vectors(ds, table: str, ix: str, xs, chunk: int = 50_000):
 
 
 def bulk_graph(ds, n_nodes: int, src, dst, chunk: int = 100_000):
-    """The KV bulk route for a RELATE graph (bench.py `bench_graph3hop`):
+    """The KV bulk route for a RELATE graph:
     node records, edge records and the four `~` graph keys per edge."""
     from surrealdb_tpu import key as K
     from surrealdb_tpu.kvs.api import serialize

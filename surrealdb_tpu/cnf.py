@@ -278,7 +278,7 @@ KV_2PC_RESOLVE_INTERVAL_S = env_float(
     "SURREAL_KV_2PC_RESOLVE_INTERVAL_S", 0.5
 )
 
-# -- accelerator backend init watchdog (device supervisor, bench.py) ----------
+# -- accelerator backend init watchdog (device supervisor) -------------------
 # a runner whose device discovery exceeds this is killed: the serving
 # path degrades to host execution (auto) or fails the query (require)
 BACKEND_INIT_TIMEOUT_S = env_float("SURREAL_BACKEND_INIT_TIMEOUT_S", 240.0)

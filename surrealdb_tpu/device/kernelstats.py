@@ -23,8 +23,8 @@ from contextlib import contextmanager
 
 COUNTS = {"hits": 0, "misses": 0, "sharded": 0}
 _SEEN: set = set()
-# widest mesh any sharded dispatch actually ran on in this process —
-# the runner-side truth behind the MULTICHIP probe's n_devices_used
+# widest mesh any sharded dispatch actually ran on in this process:
+# `cc.mesh_ndev` in `runner_status()`
 MESH_LAST = {"ndev": 0}
 
 

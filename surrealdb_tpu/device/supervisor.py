@@ -35,8 +35,8 @@ Modes (`SURREAL_DEVICE`): `off` (host paths only), `auto` (default:
 supervised subprocess, degrade-and-recover), `require` (the device path
 IS the contract: failures surface as query errors instead of degrading,
 and a runner that comes up on anything but a TPU is an init error
-unless `JAX_PLATFORMS` names that platform — chip_smoke.py and
-bench.py run here), `inline` (no subprocess; ops run in-process —
+unless `JAX_PLATFORMS` names that platform — chip_smoke.py and the
+benchmark's cells run here), `inline` (no subprocess; ops run in-process —
 debug/tests only, forfeits isolation).
 """
 
@@ -101,8 +101,8 @@ class DeviceSupervisor:
             cnf.env_float("SURREAL_DEVICE_LOAD_TIMEOUT_S",
                           cnf.DEVICE_LOAD_TIMEOUT_S)
             if load_timeout_s is None else load_timeout_s)
-        # init watchdog: SURREAL_BACKEND_INIT_TIMEOUT_S generalized from
-        # bench-only to serving (SURREAL_DEVICE_INIT_TIMEOUT_S overrides)
+        # init watchdog: SURREAL_DEVICE_INIT_TIMEOUT_S, else
+        # SURREAL_BACKEND_INIT_TIMEOUT_S (two names, one window)
         self.init_timeout_s = (
             cnf.env_float("SURREAL_DEVICE_INIT_TIMEOUT_S",
                           cnf.BACKEND_INIT_TIMEOUT_S)

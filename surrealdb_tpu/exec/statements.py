@@ -5310,7 +5310,7 @@ def _s_info(n: InfoStmt, ctx: Ctx):
             # KILL <query-id> target (inflight.py)
             "queries": ctx.ds.inflight.snapshot(),
             # per-stage query timing (PR-6 overhead strip) — the same
-            # table tools/profile_query.py prints and /metrics exports
+            # table /metrics exports
             "stages": _stage_snapshot(),
             # live-query fan-out spine health (server/fanout.py):
             # sessions, dispatch backlog, overflow/drop tallies

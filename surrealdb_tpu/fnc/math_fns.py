@@ -259,16 +259,23 @@ def _mmin(args, ctx):
     return min(a, key=sort_key) if a else float("inf")
 
 
-@register("math::sum")
-def _sum(args, ctx):
+def _fold_sum(xs):
+    """Left-to-right Number sum (the reference's `iter().sum()`): the
+    order the columnar `_group_sum` reproduces bit for bit. Not the
+    builtin `sum`, which compensates float addition since Python 3.12."""
     total = 0
-    for x in _arr(args[0], "math::sum", 1):
+    for x in xs:
         if isinstance(x, bool) or not isinstance(x, (int, float, Decimal)):
             continue
         if isinstance(x, Decimal) and not isinstance(total, Decimal):
             total = Decimal(str(total))
         total = total + x
     return total
+
+
+@register("math::sum")
+def _sum(args, ctx):
+    return _fold_sum(_arr(args[0], "math::sum", 1))
 
 
 @register("math::product")
@@ -290,7 +297,7 @@ def _mean(args, ctx):
     # (reference fnc/util/math/mean — view rolling means surface this)
     from surrealdb_tpu.exec.operators import float_div
 
-    return float_div(sum(ns), len(ns))
+    return float_div(_fold_sum(ns), len(ns))
 
 
 @register("math::median")

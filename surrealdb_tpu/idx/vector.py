@@ -1287,8 +1287,7 @@ class TpuVectorIndex:
         through int8 descent + exact re-rank (`_ann_knn_batch`);
         everything else goes to the device runner or the batched exact
         host kernel by `_use_device`. This is the path the cross-query
-        batcher dispatches AND what bench.py measures as
-        `index_engine_qps` — the serving stack above it is pure tax.
+        batcher dispatches.
         Device trouble raises DeviceUnavailable/DeviceOpError for the
         batcher's per-rider degrade ladder (the ANN path degrades
         internally to its numpy descent instead — falling back to a

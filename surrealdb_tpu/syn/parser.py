@@ -3074,7 +3074,7 @@ class Parser:
         if self.at_op(":") and not self.peek().ws_before:
             nxt = self.peek(1)
             if nxt.kind in (L.INT, L.IDENT, L.UUID_STR, L.STRING,
-                            L.DURATION) or (
+                            L.DURATION) or _alnum_number(nxt) or (
                 nxt.kind == L.OP and nxt.text in ("[", "{", "-", "..", "..=", "⟨", "`")
             ):
                 self.next()  # ':'
@@ -3111,7 +3111,7 @@ class Parser:
             self.next()
             neg = True
             t = self.peek()
-        if t.kind in (L.INT, L.DURATION) or (
+        if t.kind in (L.INT, L.DURATION) or _alnum_number(t) or (
             t.kind == L.IDENT and self._key_adjacent(t)
         ):
             merged = self._merge_key_tokens(neg)
@@ -3173,13 +3173,14 @@ class Parser:
         nxt = self.toks[self.i + 1] if self.i + 1 < len(self.toks) else None
         return (
             nxt is not None
-            and nxt.kind in (L.INT, L.IDENT, L.DURATION)
+            and _is_key_part(nxt)
             and nxt.pos == t.pos + len(t.text)
         )
 
     def _merge_key_tokens(self, neg=False):
         """Merge glued INT/IDENT/DURATION tokens into one alnum record key
-        (ulids like 01JDSK…, keys like 54d6j987… that mis-lex as durations).
+        (ulids like 01JDSK…, keys like 54d6j987… that mis-lex as durations,
+        keys like 2e58ab… that mis-lex as floats).
         Returns the string key, or None when the key is a plain INT."""
         t = self.peek()
         parts = [t.text]
@@ -3188,7 +3189,7 @@ class Parser:
         end = t.pos + len(t.text)
         while j < len(self.toks):
             nxt = self.toks[j]
-            if nxt.kind in (L.INT, L.IDENT, L.DURATION) and nxt.pos == end:
+            if _is_key_part(nxt) and nxt.pos == end:
                 parts.append(nxt.text)
                 kinds.append(nxt.kind)
                 end = nxt.pos + len(nxt.text)
@@ -3211,7 +3212,7 @@ class Parser:
             self.next()
             neg = True
             t = self.peek()
-        if t.kind in (L.INT, L.DURATION) or (
+        if t.kind in (L.INT, L.DURATION) or _alnum_number(t) or (
             t.kind == L.IDENT and self._key_adjacent(t)
         ):
             merged = self._merge_key_tokens(neg)
@@ -3233,6 +3234,18 @@ class Parser:
         if t.kind == L.OP and t.text == "{":
             return self._parse_object()
         raise self.err("invalid record range key")
+
+
+def _alnum_number(t) -> bool:
+    """A FLOAT or DECIMAL token spelled with letters, digits and `_`
+    alone (`2e58`, `1f`, `5dec`): inside a record id such text is a key,
+    or a piece of one, as `render_record_id_key` writes it."""
+    return t.kind in (L.FLOAT, L.DECIMAL) and \
+        t.text.replace("_", "").isalnum()
+
+
+def _is_key_part(t) -> bool:
+    return t.kind in (L.INT, L.IDENT, L.DURATION) or _alnum_number(t)
 
 
 def _is_stmt(node) -> bool:

@@ -68,9 +68,10 @@ class StageStat:
 # (parse, txn open), the executor (envelope, eval) and the device layer
 # (batcher wait/ride/dispatch, the supervisor's RPC and its six parts)
 # all land in ONE table regardless of which Datastore/Telemetry
-# instance they hang off. Stages surface in /metrics, `INFO FOR SYSTEM`
-# and tools/profile_query.py; doc/operations.md lists them with what
-# contains what.
+# instance they hang off. Stages surface in /metrics and `INFO FOR
+# SYSTEM`, and the benchmark's `--trace 1` readers take them from
+# `stage_snapshot()`; doc/operations.md lists them with what contains
+# what.
 _STAGES: dict[str, StageStat] = {}
 
 
@@ -91,11 +92,6 @@ def stage_snapshot() -> dict:
     total time descending."""
     items = sorted(_STAGES.items(), key=lambda kv: -kv[1].total_ns)
     return {k: v.to_dict() for k, v in items}
-
-
-def stage_reset():
-    """Clear stage stats (tools/profile_query.py between runs)."""
-    _STAGES.clear()
 
 
 # this module is imported with the datastore, so for a server these are

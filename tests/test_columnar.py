@@ -207,6 +207,20 @@ def test_aggregate_coverage(ds):
         "GROUP BY s",
         # implicit collect of a non-aggregate projection
         "SELECT s, i FROM rows WHERE i > 45 GROUP BY s",
+        # a float mean is the left-to-right sum over the count on both
+        # executors: the builtin `sum` compensates since Python 3.12 and
+        # differs from that fold in the last place on these rows
+        "SELECT s, count() AS c, math::mean(f) AS mf, count(f > 0) AS cp "
+        "FROM rows GROUP BY s",
+        # the shapes the conformance gate's analytics smoke diffed:
+        # filtered sum + mean, min/max under ORDER BY .. LIMIT, and a
+        # product summed over two keys behind an IN filter
+        "SELECT s, count() AS c, math::sum(i) AS units, math::mean(f) "
+        "AS avg FROM rows WHERE f < 5 AND i > -20 GROUP BY s",
+        "SELECT s, count() AS c, math::min(i) AS lo, math::max(i) AS hi "
+        "FROM rows WHERE i != NONE GROUP BY s ORDER BY c DESC LIMIT 3",
+        "SELECT s, b, math::sum(f * i) AS rev FROM rows "
+        "WHERE s IN ['a', 'b'] GROUP BY s, b",
     ]:
         _assert_same(ds, sql)
 

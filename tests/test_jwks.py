@@ -211,15 +211,19 @@ def test_alg_confusion_blocked(rsa):
     assert sess.auth_level == "record"
 
 
-def test_record_access_with_jwt_roundtrips():
+@pytest.mark.parametrize("target", ["user", "user:2e58abcdefghijklmnop"])
+def test_record_access_with_jwt_roundtrips(target):
     # ADVICE r5 (medium): signup tokens for a record access WITH JWT must
-    # be verifiable by authenticate (signed with the configured key)
+    # be verifiable by authenticate (signed with the configured key).
+    # The token's ID claim is the rendered record id and authenticate
+    # parses it back: a generated key that opens like a float (about one
+    # in 300 does) has to survive that, so one case pins such a key.
     from surrealdb_tpu.iam import signup
 
     ds = Datastore("memory")
     ds.query(
         "DEFINE ACCESS acc ON DATABASE TYPE RECORD "
-        "SIGNUP (CREATE user SET email = $email) "
+        f"SIGNUP (CREATE {target} SET email = $email) "
         "SIGNIN (SELECT * FROM user WHERE email = $email) "
         "WITH JWT ALGORITHM HS256 KEY 'issuerkey'",
         ns="t", db="t")
@@ -233,6 +237,9 @@ def test_record_access_with_jwt_roundtrips():
     sess = Session()
     authenticate(ds, sess, tok)
     assert sess.auth_level == "record" and sess.ac == "acc"
+    assert sess.rid.tb == "user"
+    if ":" in target:
+        assert sess.rid.id == target.split(":")[1]
 
 
 def test_external_token_requires_exp_and_honours_nbf():

@@ -383,8 +383,14 @@ def test_ann_snapshot_persist_reload(tmp_path, monkeypatch, corrupt):
     assert eng.ensure_ann()
     graph0 = eng._ann.graph.copy()
     snapdir = eng.snapshot_dir
-    files = os.listdir(snapdir)
-    assert len(files) == 1 and files[0].endswith(".annsnap")
+    # `ensure_ann` returns once a graph serves: a background build that
+    # the first query started may have installed it and still be
+    # writing the artifact (tmp + rename), so wait for the rename
+    files, end = [], time.monotonic() + 10.0
+    while not files and time.monotonic() < end:
+        files = [f for f in os.listdir(snapdir) if f.endswith(".annsnap")]
+        time.sleep(0.0 if files else 0.02)
+    assert len(files) == 1
     ds.close()
 
     if corrupt:

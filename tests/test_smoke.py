@@ -1,5 +1,7 @@
 """End-to-end smoke tests: the minimum slice of SURVEY.md §7 steps 1-4."""
 
+import pytest
+
 from surrealdb_tpu.val import NONE, Duration, RecordId
 
 
@@ -163,3 +165,19 @@ def test_values_render():
     assert render(Duration.parse("90m")) == "1h30m"
     assert render(RecordId("p", 1)) == "p:1"
     assert render([1, "x"]) == "[1, 'x']"
+
+
+@pytest.mark.parametrize("key", [
+    "tobie", "01JDSK7X", "54d6j987", "2e58abcdefghijklmnop", "7e5",
+    "9e9e9", "1f", "5dec", "12",
+])
+def test_record_key_survives_render_and_parse(key):
+    """`render` writes an alphanumeric key bare, so the parser has to
+    read every such text back as that key, whatever number or duration
+    its first characters would lex as anywhere else."""
+    from surrealdb_tpu.exec.static_eval import static_value
+    from surrealdb_tpu.syn.parser import parse_record_literal
+    from surrealdb_tpu.val import render
+
+    rid = RecordId("user", key)
+    assert static_value(parse_record_literal(render(rid))) == rid

@@ -22,451 +22,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("SURREAL_DEVICE", "inline")
 
 
-def _perf_baseline() -> "tuple[float, float] | None":
-    """(seed sql_knn/index_engine ratio, seed-era index_engine qps
-    fingerprint) from PERF_BASELINE.json, or None. The absolute 0.8×
-    floor is container physics — the seed tree itself measures ~0.2×
-    on the current CI box — so the gate is seed-RELATIVE: it measures
-    regressions, not the machine. The engine-qps fingerprint detects a
-    container-class change (a much faster/slower box makes the
-    recorded ratio meaningless — re-record it there)."""
-    import json
-
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "PERF_BASELINE.json")
-    try:
-        with open(path, encoding="utf-8") as f:
-            d = json.load(f)
-        return float(d["sql_knn_ratio"]), float(
-            d.get("index_engine_qps", 0.0)
-        )
-    except (OSError, ValueError, TypeError, KeyError):
-        return None
-
-
-def perf_smoke(ratio_floor: float = 0.8) -> "str | None":
-    """Serving-tax gate (PR 6, re-anchored PR 15): a small-N sql_knn
-    vs index_engine comparison on the conformance box. The served SQL
-    KNN path (cross-query batcher over the routed engine) must hold
-    either the absolute `ratio_floor` (fast machines) or ≥0.9× the
-    SEED tree's measured ratio from PERF_BASELINE.json — the gate is
-    environment-sensitive in absolute terms (the seed tree scores
-    0.19–0.21× on the current container), so it pins the seed-relative
-    ratio: a serving-stack regression moves it, container physics does
-    not. A failing measurement re-measures once before failing (the
-    first run in a cold process reads ~0.03-0.04x low even on an idle
-    box). Returns None on pass, an error string on fail."""
-    err = _perf_smoke_once(ratio_floor)
-    if err is None:
-        return None
-    return _perf_smoke_once(ratio_floor)
-
-
-def _perf_smoke_once(ratio_floor: float) -> "str | None":
-    """One full measurement + gate application; best-of-two on the
-    served side to absorb CI timer jitter."""
-    import time
-
-    import numpy as np
-
-    from surrealdb_tpu import Datastore
-    from surrealdb_tpu import key as K
-    from surrealdb_tpu.kvs.api import serialize
-    from surrealdb_tpu.val import RecordId
-
-    n, dim, clients, iters = 8192, 64, 32, 256
-    ds = Datastore("memory")
-    ds.query(
-        f"DEFINE TABLE tbl; DEFINE INDEX ix ON tbl FIELDS emb HNSW "
-        f"DIMENSION {dim} DIST COSINE TYPE F32", ns="b", db="b",
-    )
-    rng = np.random.default_rng(3)
-    xs = rng.normal(size=(n, dim)).astype(np.float32)
-    txn = ds.transaction(write=True)
-    try:
-        for i in range(n):
-            txn.set(K.record("b", "b", "tbl", i),
-                    serialize({"id": RecordId("tbl", i)}))
-            txn.set_val(
-                K.ix_state("b", "b", "tbl", "ix", b"he", K.enc_value(i)),
-                xs[i].tobytes(),
-            )
-        txn.set_val(K.ix_state("b", "b", "tbl", "ix", b"vn"), n)
-        txn.commit()
-    except BaseException:
-        txn.cancel()
-        raise
-    qs = rng.normal(size=(32, dim)).astype(np.float32)
-    qlists = [q.tolist() for q in qs]
-    sql = "SELECT id FROM tbl WHERE emb <|10|> $q"
-
-    from concurrent.futures import ThreadPoolExecutor
-
-    def sql_qps() -> float:
-        def one(i):
-            ds.execute(sql, ns="b", db="b",
-                       vars={"q": qlists[i % len(qlists)]})
-
-        with ThreadPoolExecutor(clients) as ex:
-            t0 = time.perf_counter()
-            list(ex.map(one, range(iters)))
-            return iters / (time.perf_counter() - t0)
-
-    sql_qps()  # warm: sync + stat caches + compiled shapes
-    ix = ds.vector_indexes[("b", "b", "tbl", "ix")]
-    big = np.repeat(qs, 16, axis=0)  # 512-query engine batch
-    ix.knn_batch(big, 10)
-    t0 = time.perf_counter()
-    ix.knn_batch(big, 10)
-    engine = len(big) / (time.perf_counter() - t0)
-    served = max(sql_qps(), sql_qps())
-    ratio = served / max(engine, 1e-9)
-    if served >= ratio_floor * engine:
-        print(f"== perf smoke: OK — sql_knn {served:.0f} qps vs "
-              f"index_engine {engine:.0f} qps "
-              f"({ratio:.2f}x, absolute floor {ratio_floor}x)")
-        return None
-    base = _perf_baseline()
-    if base is not None:
-        base_ratio, base_engine = base
-        note = ""
-        if base_engine and not (base_engine / 3 <= engine
-                                <= base_engine * 3):
-            # the box measures a very different engine ceiling than the
-            # one the baseline was recorded on: the recorded seed ratio
-            # may not transfer — surface it loudly either way
-            note = (f" [WARNING: index_engine {engine:.0f} qps vs "
-                    f"baseline fingerprint {base_engine:.0f} qps — "
-                    f"container class changed? re-record "
-                    f"PERF_BASELINE.json]")
-        if ratio >= 0.9 * base_ratio:
-            print(f"== perf smoke: OK — sql_knn {served:.0f} qps vs "
-                  f"index_engine {engine:.0f} qps ({ratio:.2f}x; "
-                  f"seed-relative gate: >= 0.9 x seed "
-                  f"{base_ratio:.2f}x){note}")
-            return None
-        return (f"sql_knn/index_engine {ratio:.2f}x < 0.9 x the seed "
-                f"tree's {base_ratio:.2f}x (PERF_BASELINE.json) — "
-                f"serving tax regrew relative to the seed{note}")
-    # PERF_BASELINE.json is committed with the repo: missing/corrupt
-    # means someone deleted it, and an ungated slow container would
-    # silently wave every regression through — fail closed and name
-    # the fix
-    return (f"sql_knn/index_engine {ratio:.2f}x < {ratio_floor}x "
-            f"absolute and PERF_BASELINE.json is missing/corrupt — "
-            f"restore it (or re-record the seed ratio on this "
-            f"container class) to gate seed-relative")
-
-
-def ann_smoke(recall_floor: float = 0.95) -> "str | None":
-    """Quantized graph-ANN gate (PR 7): on a 100k×256 embedding-shaped
-    (clustered) store, the CAGRA int8-descent + exact-re-rank path must
-    hold recall@10 >= `recall_floor` against brute-force ground truth
-    AND must not be slower than the brute path it replaces. The ≥10×
-    claim lives in the bench configs (the ratio grows with N — measured
-    ~1.7× here, 18× at 250k×768); the gate pins the floor a regression
-    would cross first. Returns None on pass, an error string on fail."""
-    import time
-
-    import numpy as np
-
-    from surrealdb_tpu import cnf
-    from surrealdb_tpu.idx.vector import TpuVectorIndex
-    from surrealdb_tpu.val import RecordId
-
-    n, dim, nc = 100_000, 256, 1000
-    rng = np.random.default_rng(7)
-    centers = rng.normal(size=(nc, dim)).astype(np.float32)
-    xs = (centers[rng.integers(0, nc, n)]
-          + 0.15 * rng.normal(size=(n, dim))).astype(np.float32)
-    qs = (xs[rng.integers(0, n, 64)]
-          + 0.075 * rng.normal(size=(64, dim))).astype(np.float32)
-    ix = TpuVectorIndex("b", "b", "annsmoke", "ix", {
-        "dimension": dim, "distance": "cosine", "vector_type": "f32",
-    })
-    ix.vecs = xs
-    ix.valid = np.ones(n, dtype=bool)
-    ix.rids = [RecordId("annsmoke", i) for i in range(n)]
-    ix.version = 0
-    big = np.repeat(qs, 8, axis=0)
-    old_mode, old_refine = cnf.KNN_ANN_MODE, cnf.KNN_ANN_REFINE
-    cnf.KNN_ANN_MODE, cnf.KNN_ANN_REFINE = "off", 0
-    try:
-        ix.knn_batch(big, 10)  # warm: ship + compile
-        t0 = time.perf_counter()
-        brute_res = ix.knn_batch(big, 10)
-        brute = len(big) / (time.perf_counter() - t0)
-        cnf.KNN_ANN_MODE = "force"
-        if not ix.ensure_ann():
-            return "ann smoke: graph build did not land"
-        ix.knn_batch(big, 10)  # warm: ship + compile the descent ladder
-        t0 = time.perf_counter()
-        ann_res = ix.knn_batch(big, 10)
-        ann = len(big) / (time.perf_counter() - t0)
-    finally:
-        cnf.KNN_ANN_MODE, cnf.KNN_ANN_REFINE = old_mode, old_refine
-    hits = sum(
-        len({r.id for r, _d in a} & {r.id for r, _d in b})
-        for a, b in zip(ann_res, brute_res)
-    )
-    recall = hits / (10 * len(big))
-    if recall < recall_floor:
-        return (f"cagra recall@10 {recall:.4f} < {recall_floor} vs "
-                f"brute-force ground truth")
-    if ann < brute:
-        return (f"cagra {ann:.0f} qps slower than brute-force "
-                f"{brute:.0f} qps at n={n} — the graph path lost its "
-                f"reason to exist")
-    print(f"== ann smoke: OK — recall@10 {recall:.4f}, cagra "
-          f"{ann:.0f} qps vs brute {brute:.0f} qps "
-          f"({ann / max(brute, 1e-9):.2f}x, build "
-          f"{ix._ann.build_s:.1f}s)")
-    return None
-
-
-def knn_churn_smoke(recall_floor: float = 0.95) -> "str | None":
-    """Segmented-ANN churn gate (PR 15): steady mixed insert/delete/
-    query against a segmented index at small scale. Every committed
-    insert must be searchable on the very next query (ingest-to-
-    searchable = one sync, no build in the path), recall@10 vs the
-    brute oracle over the live rows must hold `recall_floor`, and the
-    `ann_full_rebuilds` counter must stay 0 — the whole-index rebuild
-    treadmill is structurally gone, not just rare. Returns None on
-    pass, an error string on fail."""
-    import time
-
-    import numpy as np
-
-    from surrealdb_tpu import Datastore, cnf
-    from surrealdb_tpu.idx import segments
-
-    import bench as _bench
-
-    dim, k = 16, 10
-    rng = np.random.default_rng(15)
-    saved = (cnf.KNN_SEG_MODE, cnf.KNN_SEG_ROWS, cnf.KNN_SEG_FANOUT,
-             cnf.KNN_ANN_MODE)
-    cnf.KNN_SEG_MODE = "force"
-    cnf.KNN_SEG_ROWS = 1024
-    cnf.KNN_SEG_FANOUT = 4
-    cnf.KNN_ANN_MODE = "force"
-    segments.reset_counters()
-    ds = Datastore("memory")
-    try:
-        ds.query(
-            f"DEFINE TABLE tbl; DEFINE INDEX ix ON tbl FIELDS emb "
-            f"HNSW DIMENSION {dim} DIST EUCLIDEAN TYPE F32",
-            ns="b", db="b",
-        )
-        live: dict = {}
-        ver = [0]
-
-        def commit(adds, dels):
-            # the exact write-path shape (he state + hl op log + vn
-            # version) lives in ONE place: bench.py's churn helper
-            ver[0] = _bench._churn_ops(
-                ds, "b", "b", "tbl", "ix", ver[0], adds, dels, live
-            )
-
-        def query(q, kk=k):
-            rows = ds.query_one(
-                f"SELECT id FROM tbl WHERE emb <|{kk}|> $q",
-                ns="b", db="b", vars={"q": q.tolist()},
-            )
-            return [r["id"].id for r in rows]
-
-        nid = 4096
-        commit([(i, v) for i, v in enumerate(
-            rng.normal(size=(nid, dim)).astype(np.float32)
-        )], [])
-        query(rng.normal(size=dim).astype(np.float32))  # engage
-        rounds, hits, total = 14, 0, 0
-        ingest_ms = []
-        for r in range(rounds):
-            adds = [
-                (nid + j, v) for j, v in enumerate(
-                    rng.normal(size=(256, dim)).astype(np.float32)
-                )
-            ]
-            nid += 256
-            dels = [int(i) for i in rng.choice(
-                list(live), size=64, replace=False
-            )]
-            commit(adds, dels)
-            # ingest-to-searchable: the row committed a moment ago
-            # must be in the very next query's answer
-            probe_id, probe_vec = adds[-1]
-            t0 = time.perf_counter()
-            got = query(probe_vec, 1)
-            ingest_ms.append((time.perf_counter() - t0) * 1e3)
-            if got != [probe_id]:
-                return (f"round {r}: freshly committed row "
-                        f"tbl:{probe_id} not searchable on the next "
-                        f"query (got {got})")
-            if r % 4 == 3:
-                ids = np.asarray(sorted(live))
-                mat = np.stack([live[i] for i in ids])
-                for q in rng.normal(size=(8, dim)).astype(np.float32):
-                    d = ((mat.astype(np.float64)
-                          - q.astype(np.float64)) ** 2).sum(axis=1)
-                    truth = set(
-                        ids[np.argsort(d, kind="stable")[:k]].tolist()
-                    )
-                    hits += len(truth & set(query(q)))
-                    total += k
-        recall = hits / max(total, 1)
-        eng = ds.vector_indexes[("b", "b", "tbl", "ix")]
-        if eng._segs is not None:
-            eng._segs.drain()  # settle in-flight background builds
-        # ENGINE-scoped counters: another datastore's (or a leaked
-        # background thread's) activity can never flip this gate
-        c = dict(eng._segs.stats) if eng._segs is not None else {}
-        c["ann_full_rebuilds"] = eng.ann_full_rebuilds
-        st = eng._segs.status() if eng._segs is not None else {}
-        if recall < recall_floor:
-            return (f"churn recall@10 {recall:.4f} < {recall_floor} "
-                    f"(segments={st.get('segments')})")
-        if c["ann_full_rebuilds"] != 0:
-            return (f"{c['ann_full_rebuilds']} whole-index ANN "
-                    f"rebuild(s) observed under churn — the treadmill "
-                    f"is back")
-        if c.get("seg_seals", 0) < 1 or c.get("seg_builds", 0) < 1:
-            return (f"segments never engaged (seals="
-                    f"{c.get('seg_seals', 0)}, builds="
-                    f"{c.get('seg_builds', 0)}) — vacuous churn run")
-        p95 = sorted(ingest_ms)[int(0.95 * (len(ingest_ms) - 1))]
-        print(f"== knn churn smoke: OK — recall@10 {recall:.4f}, "
-              f"ingest-to-searchable p95 {p95:.1f} ms, "
-              f"{c.get('seg_seals', 0)} seals / "
-              f"{c.get('seg_builds', 0)} builds / "
-              f"{c.get('seg_merges', 0)} merges / "
-              f"{c.get('seg_rebuilds', 0)} seg-rebuilds, "
-              f"0 full rebuilds")
-        return None
-    finally:
-        (cnf.KNN_SEG_MODE, cnf.KNN_SEG_ROWS, cnf.KNN_SEG_FANOUT,
-         cnf.KNN_ANN_MODE) = saved
-        ds.close()
-
-
-def analytics_smoke(ratio_floor: float = 5.0) -> "str | None":
-    """Columnar-executor gate (PR 14): a small-N filtered aggregation +
-    GROUP BY must (1) run >= `ratio_floor`x faster through the columnar
-    tiers than through the row-at-a-time interpreter and (2) answer
-    byte-identically — including a forced-scalar run (SURREAL_COLUMNAR
-    =off) that proves every vectorized kernel has a correct fallback.
-    Returns None on pass, an error string on fail."""
-    import time
-
-    from surrealdb_tpu import Datastore, cnf
-    from surrealdb_tpu.kvs.ds import Session
-    from surrealdb_tpu.val import render
-
-    import bench as _bench
-
-    n = 30_000
-    ds = Datastore("memory")
-    ds.query("DEFINE TABLE sales", ns="b", db="b")
-    _bench._bulk_analytics_rows(ds, "b", "b", "sales", n, seed=11)
-    queries = [
-        "SELECT cat, count() AS c, math::sum(qty) AS units, "
-        "math::mean(price) AS avg FROM sales "
-        "WHERE price < 300 AND qty > 5 GROUP BY cat",
-        "SELECT region, count() AS c, math::min(price) AS lo, "
-        "math::max(price) AS hi FROM sales GROUP BY region "
-        "ORDER BY c DESC LIMIT 3",
-        "SELECT cat, region, math::sum(price * qty) AS rev "
-        "FROM sales WHERE region IN ['eu', 'us'] GROUP BY cat, region",
-    ]
-
-    def run(sql, iters, columnar):
-        sess = Session(ns="b", db="b", auth_level="owner")
-        if not columnar:
-            sess.planner_strategy = "compute-only"
-        prev = cnf.COLUMNAR
-        cnf.COLUMNAR = "auto" if columnar else "off"
-        try:
-            out = None
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = ds.execute(sql, session=sess)[-1].unwrap()
-            return iters / (time.perf_counter() - t0), out
-        finally:
-            cnf.COLUMNAR = prev
-
-    worst = None
-    for sql in queries:
-        run(sql, 1, True)  # warm: column-store build
-        col_qps, col_out = run(sql, 4, True)
-        interp_qps, interp_out = run(sql, 1, False)
-        if render(col_out) != render(interp_out):
-            return (f"columnar answer diverged from the forced-scalar "
-                    f"interpreter on: {sql[:80]}")
-        ratio = col_qps / max(interp_qps, 1e-9)
-        if worst is None or ratio < worst[0]:
-            worst = (ratio, col_qps, interp_qps)
-    # fallback-correctness: the streaming tier with the scalar path
-    # forced must also diff clean (exercises the per-row fallback seam
-    # rather than skipping the streaming executor entirely)
-    sess = Session(ns="b", db="b", auth_level="owner")
-    prev = cnf.COLUMNAR
-    cnf.COLUMNAR = "off"
-    try:
-        off_out = ds.execute(queries[0], session=sess)[-1].unwrap()
-    finally:
-        cnf.COLUMNAR = prev
-    on_out = ds.execute(queries[0], session=sess)[-1].unwrap()
-    if render(off_out) != render(on_out):
-        return "SURREAL_COLUMNAR=off diverged on the streaming executor"
-    ratio, col_qps, interp_qps = worst
-    if ratio < ratio_floor:
-        return (f"columnar {col_qps:.1f} qps only {ratio:.1f}x the "
-                f"interpreter ({interp_qps:.2f} qps); floor "
-                f"{ratio_floor}x")
-    print(f"== analytics smoke: OK — columnar {col_qps:.1f} qps, "
-          f"{ratio:.1f}x interpreter (floor {ratio_floor}x), "
-          f"answers identical incl. forced-scalar")
-    return None
-
-
-def live_smoke() -> "str | None":
-    """Live fan-out gate (the push-path overload spine): a small
-    real-socket soak — 8 WS sessions (one frozen mid-stream), writers
-    streaming CREATEs — must deliver every committed write to every
-    live session exactly once in commit order, keep write throughput
-    decoupled from the frozen consumer, and GC every subscription when
-    the sessions disconnect without KILL. Returns None on pass."""
-    from bench import live_soak
-
-    r = live_soak(sessions=8, frozen=1, writers=2, writes=200,
-                  depth=64, settle_s=12.0)
-    n_live = r["sessions"] - r["frozen"]
-    if r["per_session_complete"] != n_live:
-        return (f"only {r['per_session_complete']}/{n_live} live "
-                f"sessions received every committed write "
-                f"(delivered={r['delivered']})")
-    if r["order_violations"]:
-        return (f"{r['order_violations']} commit-order violations in "
-                f"delivered notifications")
-    if r["live_sessions_end"]:
-        return (f"{r['live_sessions_end']} live queries leaked after "
-                f"every session disconnected without KILL")
-    # the hard ±10% decoupling assertion (single frozen subscriber, no
-    # fan-out CPU share) lives in tests/test_live_fanout.py; here the
-    # fleet shares one CI core with 7 live consumers, so the gate only
-    # pins "writers make real progress while a consumer is frozen"
-    if r["decoupling_ratio"] < 0.35:
-        return (f"write throughput collapsed under fan-out: "
-                f"{r['write_qps_fanout']} qps vs "
-                f"{r['write_qps_base']} qps baseline "
-                f"(ratio {r['decoupling_ratio']})")
-    print(f"== live smoke: OK — {r['value']} notif/s to "
-          f"{n_live} sessions, p50 {r['delivery_p50_ms']}ms p99 "
-          f"{r['delivery_p99_ms']}ms, decoupling "
-          f"{r['decoupling_ratio']}x, 0 leaks")
-    return None
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("filter", nargs="?", default=None)
@@ -598,40 +153,6 @@ def main():
         print("== mesh smoke: OK")
     else:
         print(f"== mesh smoke: FAIL — {err}")
-        rc = rc or 1
-    # perf smoke: the serving tax over the raw index engine is gated
-    # (sql_knn >= 0.8 x index_engine on this box, small N)
-    err = perf_smoke()
-    if err is not None:
-        print(f"== perf smoke: FAIL — {err}")
-        rc = rc or 1
-    # analytics smoke: the columnar executor must hold >= 5x over the
-    # row-at-a-time interpreter on the small-N filtered-agg config AND
-    # diff byte-identical against the forced-scalar path
-    err = analytics_smoke()
-    if err is not None:
-        print(f"== analytics smoke: FAIL — {err}")
-        rc = rc or 1
-    # ann smoke: the quantized graph index must keep recall@10 >= 0.95
-    # vs brute-force ground truth and must never be slower than the
-    # brute path it gates in for
-    err = ann_smoke()
-    if err is not None:
-        print(f"== ann smoke: FAIL — {err}")
-        rc = rc or 1
-    # knn churn smoke: segmented ANN under steady insert/delete/query —
-    # recall holds, every commit is immediately searchable, and zero
-    # whole-index rebuilds (ann_full_rebuilds counter)
-    err = knn_churn_smoke()
-    if err is not None:
-        print(f"== knn churn smoke: FAIL — {err}")
-        rc = rc or 1
-    # live smoke: the fan-out spine's small real-socket config —
-    # exactly-once commit-order delivery, frozen-consumer decoupling,
-    # disconnect GC
-    err = live_smoke()
-    if err is not None:
-        print(f"== live smoke: FAIL — {err}")
         rc = rc or 1
     return rc
 
