@@ -50,6 +50,17 @@ def _pow2_chunks(b_total: int, n: int, query_chunk: int,
     return bucket, chunk, bucket // chunk
 
 
+def _chunked(qvs: np.ndarray, bucket: int, chunk: int) -> np.ndarray:
+    """[B, D] queries zero-padded to `bucket` rows and cut into
+    [bucket // chunk, chunk, D], on the host: the copy is at most a
+    query chunk of floats and the reshape is a view."""
+    if bucket != qvs.shape[0]:
+        padded = np.zeros((bucket, qvs.shape[1]), qvs.dtype)
+        padded[:qvs.shape[0]] = qvs
+        qvs = padded
+    return qvs.reshape(bucket // chunk, chunk, -1)
+
+
 class VecStore:
     """Device-resident blocks for ONE vector index cache epoch."""
 
@@ -217,26 +228,30 @@ class VecStore:
         mode "cand": bufs = [cand i32 [B, kc]] — int8 ranking
         candidates for the serving side's exact host rescore."""
         self.ensure()
-        import jax.numpy as jnp
-
         from surrealdb_tpu.device.kernelstats import note_shape, phase
 
-        # the op's timeline (kernelstats.phase): `h2d` is the jnp.asarray
-        # of the queries; `device` every launch (the eager pad/reshape
-        # programs too) until the FIRST output is on the host, which is
-        # the wait that was always there (a block_until_ready before it
-        # costs one more round trip to the chip, 0.46 ms a query on a
-        # v5e); `d2h` the copies of the other outputs
+        # On one device a dispatch is one transfer in, one program and
+        # one copy out: the batch is padded to its bucket and cut into
+        # chunks HERE, in numpy (on a device array either is an eager
+        # program of its own, compiled per rider count), the jitted
+        # kernel takes the numpy batch and its dispatch places it, and
+        # the kernel packs its outputs into one array (ops.topk
+        # pack_pairs), because every copy of a small ready array back to
+        # the host is a round trip to the chip. The op's timeline
+        # (kernelstats.phase) is `device` alone, from the launch until
+        # that array is on the host: the wait that was always there (a
+        # block_until_ready before it costs one more round trip, 0.46 ms
+        # a query on a v5e). The mesh branches keep `h2d` / `d2h` where
+        # they still transfer or copy apart.
         cfg = self.cfg
         n = self.vecs.shape[0]
-        with phase("h2d"):
-            qs = jnp.asarray(np.ascontiguousarray(qvs, dtype=np.float32))
+        qvs = np.ascontiguousarray(qvs, dtype=np.float32)
+        b_total = qvs.shape[0]
         if self.mesh is not None:
             if self.device_rank is not None:
                 from surrealdb_tpu.parallel.mesh import sharded_rank_rescore
 
                 kc = max(2 * k, k + 16)
-                b_total = qs.shape[0]
                 nloc = self.device_rank.shape[0] // self.mesh.devices.size
                 _, chunk, _ = _pow2_chunks(
                     b_total, nloc, cfg["query_chunk"], cfg["score_budget"]
@@ -246,7 +261,7 @@ class VecStore:
                 d_parts = []
                 i_parts = []
                 for s in range(0, b_total, chunk):
-                    qc = np.asarray(qvs[s:s + chunk], dtype=np.float32)
+                    qc = qvs[s:s + chunk]
                     if qc.shape[0] < chunk:
                         qc = np.pad(qc, ((0, chunk - qc.shape[0]), (0, 0)))
                     with phase("device"):
@@ -261,10 +276,14 @@ class VecStore:
                 dists = np.concatenate(d_parts)[:b_total]
                 ids = np.concatenate(i_parts)[:b_total]
             else:
+                import jax.numpy as jnp
+
                 from surrealdb_tpu.parallel.mesh import sharded_knn
 
                 note_shape("sharded_knn",
-                           (self.vecs.shape, qs.shape[0], k, self.metric))
+                           (self.vecs.shape, b_total, k, self.metric))
+                with phase("h2d"):
+                    qs = jnp.asarray(qvs)
                 with phase("device"):
                     dists, ids = sharded_knn(
                         self.mesh, self.device_vecs, qs, self.device_valid,
@@ -274,81 +293,59 @@ class VecStore:
                 with phase("d2h"):
                     ids = np.asarray(ids)
             return self._pairs(dists, ids)
-        if self.rank_mode == "int8":
-            from surrealdb_tpu.ops.topk import knn_rank_int8
+        # looked up on the module at every call: the benchmark's fault
+        # hook plants its `knn_rank_rescore` there
+        from surrealdb_tpu.ops import topk
 
+        if self.rank_mode == "int8":
             kc = min(n, max(cfg["int8_oversample"] * k, k + 16))
-            b_total = qs.shape[0]
             # halve the score budget: the int8 kernel holds int32 dots
             # AND the f32 score matrix at [chunk, N] concurrently
-            bucket, chunk, r = _pow2_chunks(
+            bucket, chunk, _ = _pow2_chunks(
                 b_total, n, cfg["query_chunk"], cfg["score_budget"] // 2
             )
             note_shape("knn_rank_int8",
                        (self.vecs.shape, chunk, kc, self.metric))
+            qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
-                if bucket != b_total:
-                    qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
-                cand = knn_rank_int8(
+                cand = np.asarray(topk.knn_rank_int8(
                     self.device_rank, self.device_arow, self.device_x2,
-                    self.device_valid, qs.reshape(r, chunk, -1), kc,
-                    self.metric,
-                )
-                cand = np.asarray(cand).reshape(bucket, kc)[:b_total]
+                    self.device_valid, qs_r, kc, self.metric,
+                ))
             return (
                 {"mode": "cand", "rank_mode": self.rank_mode, "kc": kc},
-                [np.ascontiguousarray(cand, np.int32)],
+                [np.ascontiguousarray(
+                    cand.reshape(bucket, kc)[:b_total], np.int32)],
             )
         if self.device_rank is not None:
-            from surrealdb_tpu.ops.topk import knn_rank_rescore
-
             # oversampling absorbs bf16/approx-top-k ranking error AND
             # tombstoned rows ranked into the candidate set
             kc = min(n, max(2 * k, k + 16))
-            b_total = qs.shape[0]
-            bucket, chunk, r = _pow2_chunks(
+            bucket, chunk, _ = _pow2_chunks(
                 b_total, n, cfg["query_chunk"], cfg["score_budget"]
             )
             note_shape("knn_rank_rescore",
                        (self.vecs.shape, chunk, min(k, kc), kc,
                         self.metric))
+            qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
-                if bucket != b_total:
-                    qs = jnp.pad(qs, ((0, bucket - b_total), (0, 0)))
-                dists, ids = knn_rank_rescore(
-                    self.device_rank, self.device_full,
-                    qs.reshape(r, chunk, -1), min(k, kc), kc, self.metric,
-                    self.device_x2, self.device_norms, self.device_valid,
-                )
-                dists = np.asarray(dists).reshape(bucket, -1)[:b_total]
-            with phase("d2h"):
-                ids = np.asarray(ids).reshape(bucket, -1)[:b_total]
-            return self._pairs(dists, ids)
-        if n > cfg["block_rows"]:
-            from surrealdb_tpu.ops.topk import knn_search_blocked
-
-            note_shape("knn_search_blocked",
-                       (self.vecs.shape, qs.shape[0], k, self.metric))
-            with phase("device"):
-                dists, ids = knn_search_blocked(
-                    self.device_vecs, qs, k, self.metric, self.mink_p,
-                    self.device_valid,
-                )
-                dists = np.asarray(dists)
+                packed = np.asarray(topk.knn_rank_rescore(
+                    self.device_rank, self.device_full, qs_r,
+                    min(k, kc), kc, self.metric, self.device_x2,
+                    self.device_norms, self.device_valid,
+                ))
+            packed = packed.reshape(bucket, -1)
         else:
-            from surrealdb_tpu.ops.topk import knn_search
-
-            note_shape("knn_search",
-                       (self.vecs.shape, qs.shape[0], k, self.metric))
+            search = topk.knn_search_blocked if n > cfg["block_rows"] \
+                else topk.knn_search
+            note_shape(search.__name__,
+                       (self.vecs.shape, b_total, k, self.metric))
             with phase("device"):
-                dists, ids = knn_search(
-                    self.device_vecs, qs, k, self.metric, self.mink_p,
-                    self.device_valid,
-                )
-                dists = np.asarray(dists)
-        with phase("d2h"):
-            ids = np.asarray(ids)
-        return self._pairs(dists, ids)
+                packed = np.asarray(search(
+                    self.device_vecs, qvs, k, self.metric, self.mink_p,
+                    self.device_valid, packed=True,
+                ))
+        return self._pairs(*topk.unpack_pairs(packed[:b_total]))
 
     def _pairs(self, dists, ids):
         return (

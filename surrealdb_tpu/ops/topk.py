@@ -7,10 +7,32 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # f32 contractions that promise exact results (the [B, kc, D] rescore):
 # full f32 on the MXU instead of the TPU's default single bf16 pass
 _EXACT = jax.lax.Precision.HIGHEST
+
+
+def pack_pairs(dists, ids):
+    """(f32 dists, i32 ids), both [..., k], as ONE int32 array [..., 2k]
+    built inside the jitted program: the distances' bits beside the ids,
+    so that a dispatch copies one array back to the host and not two
+    (each copy of a small ready array is a round trip to the chip).
+    int32 and not f32 is the carrier: integer lanes are never
+    canonicalised, where an id's bits read as f32 could be a NaN.
+    `unpack_pairs` is the host's side."""
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(dists, jnp.int32),
+         ids.astype(jnp.int32)], axis=-1,
+    )
+
+
+def unpack_pairs(packed):
+    """A numpy `pack_pairs` array as (dists f32, ids i32): two views,
+    bit for bit what the program computed."""
+    k = packed.shape[-1] // 2
+    return packed[..., :k].view(np.float32), packed[..., k:]
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -20,17 +42,20 @@ def top_k_smallest(dists, k: int):
     return -neg, idx
 
 
-@partial(jax.jit, static_argnames=("k", "metric"))
+@partial(jax.jit, static_argnames=("k", "metric", "packed"))
 def knn_search(xs, qs, k: int, metric: str = "euclidean", p: float = 3.0,
-               valid=None):
+               valid=None, packed: bool = False):
     """Fused distance + top-k. `valid`: optional [N] bool mask (tombstones /
-    predicate pushdown); invalid rows get +inf distance."""
+    predicate pushdown); invalid rows get +inf distance. Returns
+    (dists [B, k], ids [B, k]), or with `packed` (the served path,
+    device/vecstore.py) the one `pack_pairs` array [B, 2k]."""
     from surrealdb_tpu.ops.distance import distance_matrix
 
     d = distance_matrix(xs, qs, metric, p)
     if valid is not None:
         d = jnp.where(valid[None, :], d, jnp.inf)
-    return top_k_smallest(d, k)
+    out = top_k_smallest(d, k)
+    return pack_pairs(*out) if packed else out
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "recall_target"))
@@ -88,8 +113,10 @@ def knn_rank_rescore(xs_rank, xs_full, qs_r, k: int, kc: int,
     distances, exact `lax.top_k` over kc) in place of a host-side numpy
     rescore.
 
-    `qs_r` is [R, B, D] f32 query chunks; returns (dists [R,B,k] f32,
-    ids [R,B,k] i32). `x2`: f32 row norms² (euclidean ranking);
+    `qs_r` is [R, B, D] f32 query chunks; returns ONE int32 array
+    [R, B, 2k], the f32 distances' bits beside the int32 ids
+    (`pack_pairs`; `unpack_pairs` splits it on the host), so a dispatch
+    is one program and one copy back. `x2`: f32 row norms² (euclidean ranking);
     `norms`: f32 row norms (cosine rescore). Precision note: the
     stage-1 ranking matmul runs at the MXU's bf16 rate on purpose; the
     stage-2 contractions over the kc candidates carry
@@ -143,7 +170,7 @@ def knn_rank_rescore(xs_rank, xs_full, qs_r, k: int, kc: int,
         d = jnp.where(valid[cand], d, jnp.inf)
         nd, sel = jax.lax.top_k(-d, k)
         ids = jnp.take_along_axis(cand, sel, axis=1)
-        return -nd, ids
+        return pack_pairs(-nd, ids)
 
     return jax.lax.map(one, qs_r)
 
@@ -186,12 +213,13 @@ def knn_rank_int8(xs_q, arow, x2, valid, qs_r, kc: int,
     return jax.lax.map(one, qs_r)
 
 
-@partial(jax.jit, static_argnames=("k", "metric", "block"))
+@partial(jax.jit, static_argnames=("k", "metric", "block", "packed"))
 def knn_search_blocked(xs, qs, k: int, metric: str = "euclidean",
-                       p: float = 3.0, valid=None, block: int = 65536):
+                       p: float = 3.0, valid=None, block: int = 65536,
+                       packed: bool = False):
     """Blockwise scan for stores too large to materialize [B, N] at once:
     lax.scan over row blocks keeping a running top-k (HBM-bandwidth bound,
-    peak memory [B, block])."""
+    peak memory [B, block]). Returns as `knn_search` does."""
     from surrealdb_tpu.ops.distance import distance_matrix
 
     n, dim = xs.shape
@@ -226,4 +254,4 @@ def knn_search_blocked(xs, qs, k: int, metric: str = "euclidean",
 
     bases = jnp.arange(nblocks, dtype=jnp.int32) * block
     (fd, fi), _ = jax.lax.scan(step, init, (xs_b, valid_b, bases))
-    return fd, fi
+    return pack_pairs(fd, fi) if packed else (fd, fi)
