@@ -307,6 +307,8 @@ class DeviceHost:
             "ann": dict(kernelstats.ANN),
             # the bag hops' riders, paths and overflows (kernelstats.CSR)
             "csr": dict(kernelstats.CSR),
+            # the exact stores' scans (kernelstats.SCAN)
+            "scan": dict(kernelstats.SCAN),
         }, []
 
     def op_profile(self, meta, bufs):
@@ -435,6 +437,14 @@ class DeviceHost:
         self.vec.move_to_end(meta["key"])
         out_meta, out_bufs = ent[1].knn(bufs[0], int(meta["k"]))
         out_meta.setdefault("mesh_ndev", _store_ndev(ent[1]))
+        if out_meta.get("rank_mode") is None:
+            # an exact store scored every row for every rider
+            from surrealdb_tpu.device.kernelstats import SCAN
+
+            riders = bufs[0].shape[0]
+            SCAN["riders"] += riders
+            SCAN["dispatches"] += 1
+            SCAN["rows_scored"] += riders * ent[1].vecs.shape[0]
         return "ok", out_meta, out_bufs
 
     def _prewarm_shapes(self, cache, meta, field, warm_one):

@@ -67,6 +67,12 @@ ANN = {"searches": 0, "rows_scored": 0}
 # lint: mem-account(fixed-key int counters, not derived state)
 CSR = {"bag_riders": 0, "paths_out": 0, "edges_gathered": 0,
        "overflows": 0}
+# `vec_knn` on exact stores (device/handlers.py; on one device the
+# program `exact_scan`): every one scores every row of its store, so
+# `rows_scored` is unpadded riders x the store's rows; `op_status`
+# reports it as `scan`
+# lint: mem-account(fixed-key int counters, not derived state)
+SCAN = {"riders": 0, "dispatches": 0, "rows_scored": 0}
 
 
 @contextmanager

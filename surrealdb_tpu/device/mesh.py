@@ -46,10 +46,9 @@ import os
 import numpy as np
 
 from surrealdb_tpu import cnf
+from surrealdb_tpu.device.vecstore import exact_store
 
 MESH_AXIS = "mesh"
-
-MXU_METRICS = ("euclidean", "cosine", "dot")
 
 # one jitted shard_map per (kernel, mesh, shapes, statics) — the same
 # bounded compiled-ladder discipline as csrstore._jit_cache
@@ -290,7 +289,8 @@ class MeshVecStore:
             else even_splits(n, self.mesh_ndev)
         )
         _check_offsets(self.offsets, n, self.mesh_ndev)
-        if metric in MXU_METRICS and (6 * n * dim) // self.mesh_ndev \
+        if not exact_store(metric, self.cfg) \
+                and (6 * n * dim) // self.mesh_ndev \
                 > self.cfg.get("hbm_budget", 1 << 62):
             self.rank_mode = "int8"
         else:
@@ -312,7 +312,7 @@ class MeshVecStore:
         n = max(int(n), 0)
         dim = max(int(dim), 1)
         nloc = -(-n // ndev) if n else 1
-        if metric in MXU_METRICS and (6 * n * dim) // ndev \
+        if not exact_store(metric, cfg) and (6 * n * dim) // ndev \
                 > cfg.get("hbm_budget", 1 << 62):
             # int8 ranking: rows (1 B/elem) + arow/x2 f32 + valid + base
             return ndev * nloc * (dim + 9) + 4 * ndev

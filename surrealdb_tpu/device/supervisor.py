@@ -126,6 +126,7 @@ class DeviceSupervisor:
             "device_dispatch_timeouts": 0, "device_dispatch_errors": 0,
             "device_fallbacks": 0, "device_host_routed": 0,
             "device_oom_refusals": 0,
+            "device_col_ships": 0, "device_col_ship_bytes": 0,
         }
         # wall seconds spent shipping block caches to the runner
         self.ship_s = 0.0
@@ -206,6 +207,12 @@ class DeviceSupervisor:
         """A caller with a serving device answered from the host by
         rule, not by trouble (counted once per query so answered)."""
         self.counters["device_host_routed"] += 1
+
+    def note_col_ship(self, nbytes: int):
+        """A table's column block (col.py) is about to be shipped:
+        once a table version a metric, never with a query."""
+        self.counters["device_col_ships"] += 1
+        self.counters["device_col_ship_bytes"] += int(nbytes)
 
     def ensure_started(self):
         """Kick the async first spawn (idempotent, never blocks)."""
@@ -487,6 +494,8 @@ class DeviceSupervisor:
             "fallbacks": self.counters["device_fallbacks"],
             "host_routed": self.counters.get("device_host_routed", 0),
             "oom_refusals": self.counters.get("device_oom_refusals", 0),
+            "col_ships": self.counters["device_col_ships"],
+            "col_ship_bytes": self.counters["device_col_ship_bytes"],
             "last_error": self.last_error,
             "vec_blocks": sum(1 for k in loaded if k.startswith("vec/")),
             "csr_blocks": sum(1 for k in loaded if k.startswith("csr/")),
@@ -1136,7 +1145,8 @@ def attach_telemetry(telemetry):
     )
     for name in ("device_restarts", "device_dispatch_timeouts",
                  "device_fallbacks", "device_host_routed",
-                 "device_oom_refusals"):
+                 "device_oom_refusals", "device_col_ships",
+                 "device_col_ship_bytes"):
         telemetry.register_gauge(
             name, lambda n=name: get_supervisor().counters.get(n, 0)
         )

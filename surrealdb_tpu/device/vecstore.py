@@ -11,12 +11,24 @@ The kernel selection mirrors the pre-supervisor design exactly
 (bf16 rank + f32 rescore single-chip, sharded rank/rescore on a mesh,
 int8 ranking store above the HBM budget, exact kernels for non-MXU
 metrics); budgets arrive in `cfg` per dispatch so the serving process's
-configuration governs.
+configuration governs. A store whose `cfg` says `exact` (a table's
+column block under a no-index scan, col.py) takes the exact kernels
+whatever its metric: f32 rows only, every row scored, no candidate set.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+
+MXU_METRICS = ("euclidean", "cosine", "dot")
+
+
+def exact_store(metric: str, cfg: dict) -> bool:
+    """Whether a store keeps its f32 rows alone and answers from the
+    exact kernels: always for the non-MXU metrics, and for any metric
+    when the shipper's `cfg` says `exact`."""
+    return metric not in MXU_METRICS or bool(cfg.get("exact"))
 
 
 def _device_count() -> int:
@@ -100,7 +112,7 @@ class VecStore:
             ndev = _device_count()
         n = max(int(n), 0)
         dim = max(int(dim), 1)
-        if metric not in ("euclidean", "cosine", "dot"):
+        if exact_store(metric, cfg):
             # exact store: the raw rows + the validity mask
             return (n * dim * itemsize) // max(ndev, 1) + n
         if (6 * n * dim) // max(ndev, 1) > cfg.get("hbm_budget",
@@ -127,8 +139,9 @@ class VecStore:
 
         valid = self.valid.copy()
         multi = jax.device_count() > 1
-        if self.metric not in ("euclidean", "cosine", "dot"):
-            # non-MXU metrics: exact distance kernel over the raw store
+        if exact_store(self.metric, self.cfg):
+            # non-MXU metrics and stores told to be exact: the exact
+            # distance kernel over the raw store, and no ranking copy
             if multi:
                 from surrealdb_tpu.parallel.mesh import (
                     default_mesh, shard_rows, shard_vec,
@@ -336,15 +349,22 @@ class VecStore:
                 ))
             packed = packed.reshape(bucket, -1)
         else:
-            search = topk.knn_search_blocked if n > cfg["block_rows"] \
-                else topk.knn_search
-            note_shape(search.__name__,
-                       (self.vecs.shape, b_total, k, self.metric))
+            # the exact store: the batch padded to its bucket like the
+            # ranking branches' (a program a bucket, not a rider count)
+            blocked = n > cfg["block_rows"]
+            bucket, chunk, rounds = _pow2_chunks(
+                b_total, min(n, topk.SCAN_BLOCK) if blocked else n,
+                cfg["query_chunk"], cfg["score_budget"]
+            )
+            note_shape("exact_scan",
+                       (self.vecs.shape, chunk, k, self.metric))
+            qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
-                packed = np.asarray(search(
-                    self.device_vecs, qvs, k, self.metric, self.mink_p,
-                    self.device_valid, packed=True,
-                ))
+                parts = [np.asarray(topk.exact_scan(
+                    self.device_vecs, qs_r[r], k, self.metric,
+                    self.mink_p, self.device_valid, cfg["block_rows"],
+                )) for r in range(rounds)]
+            packed = parts[0] if rounds == 1 else np.concatenate(parts)
         return self._pairs(*topk.unpack_pairs(packed[:b_total]))
 
     def _pairs(self, dists, ids):

@@ -331,10 +331,16 @@ class VecTopKScanOp(Operator):
     """Columnar brute-force vector top-k: ORDER BY a recognized vector
     expression with LIMIT over a full table scan rides the persistent
     column store (col.py + the native C++ extraction kernel) — score the
-    whole table in one numpy call, then materialize ONLY the winning
-    rows. The winners' projected scores recompute per-row in f64 from
-    the fetched documents, so output values are bit-identical to the
-    row-at-a-time engine; only the ranking runs on the f32 column.
+    whole table in one pass, then materialize ONLY the winning rows.
+    With a serving device runner the pass is a `vec_knn` on the
+    column's resident exact f32 block, shared with whatever other scans
+    of the table are waiting (`_scan_batcher`); otherwise, and for the
+    orders the device does not serve, one numpy call on the host
+    (`scan_host`). The winners' projected scores recompute per-row in
+    f64 from the fetched documents, so output values are bit-identical
+    to the row-at-a-time engine; only the ranking runs on the f32 column.
+    Stages: `vec_scan` (the device leg, submit until the row numbers are
+    back) and `scan_fetch` (the winners fetched and yielded).
     Reference role: exec/operators/knn_topk.rs (KnnTopK scan operator)."""
 
     def __init__(self, tb, spec, keep, skip, desc, label):
@@ -351,6 +357,7 @@ class VecTopKScanOp(Operator):
         from surrealdb_tpu.col import get_vector_column
         from surrealdb_tpu.exec.eval import fetch_record
         from surrealdb_tpu.exec.statements import Source
+        from surrealdb_tpu.telemetry import stage_record
         from surrealdb_tpu.val import RecordId
 
         ns, db = ctx.need_ns_db()
@@ -362,30 +369,20 @@ class VecTopKScanOp(Operator):
             # dirty overlay or non-conforming rows: the planner guards
             # against engaging here, but races resolve to the safe path
             raise _FallbackToLegacy()
-        m = col.mat
         qf = qv.astype(np.float32)
-        if kind == "cos_sim":
-            dots = m @ qf
-            denom = col.norms() * np.linalg.norm(qf)
-            with np.errstate(divide="ignore", invalid="ignore"):
-                scores = dots / denom
-        elif kind == "eucl":
-            scores = np.linalg.norm(m - qf[None, :], axis=1)
-        elif kind == "manh":
-            scores = np.abs(m - qf[None, :]).sum(axis=1)
-        else:  # dot
-            scores = m @ qf
-        n_rows = scores.shape[0]
-        k = min(self.keep, n_rows)
-        key = -scores if self.desc else scores
-        if k < n_rows:
-            part = np.argpartition(key, k - 1)[:k]
-            order = part[np.argsort(key[part], kind="stable")]
-        else:
-            order = np.argsort(key, kind="stable")
-        order = order[self.skip:]
+        k = min(self.keep, col.mat.shape[0])
+        metric = _SCAN_METRIC.get((kind, self.desc))
+        order = None
+        if k and scan_on_device(col, metric):
+            t0 = time.monotonic_ns()
+            order = _scan_batcher().submit(
+                _ScanRider(col, metric, qf.tobytes(), k))
+            stage_record("vec_scan", time.monotonic_ns() - t0)
+        if order is None:
+            order = scan_host(col, kind, qf, k, self.desc)
+        t0 = time.monotonic_ns()
         batch = []
-        for i in order:
+        for i in order[self.skip:]:
             ctx.check_deadline()
             rid = RecordId(self.tb, col.ids[int(i)])
             doc = fetch_record(ctx, rid)
@@ -395,8 +392,128 @@ class VecTopKScanOp(Operator):
             if len(batch) >= BATCH_SIZE:
                 yield batch
                 batch = []
+        stage_record("scan_fetch", time.monotonic_ns() - t0)
         if batch:
             yield batch
+
+
+def scan_host(col, kind: str, qf, k: int, desc: bool):
+    """The scan on the host: every row of the column scored by one
+    numpy call, the row numbers of the first `k` in order."""
+    m = col.mat
+    if kind == "cos_sim":
+        dots = m @ qf
+        denom = col.norms() * np.linalg.norm(qf)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            scores = dots / denom
+    elif kind == "eucl":
+        scores = np.linalg.norm(m - qf[None, :], axis=1)
+    elif kind == "manh":
+        scores = np.abs(m - qf[None, :]).sum(axis=1)
+    else:  # dot
+        scores = m @ qf
+    key = -scores if desc else scores
+    if k < scores.shape[0]:
+        part = np.argpartition(key, k - 1)[:k]
+        return part[np.argsort(key[part], kind="stable")]
+    return np.argsort(key, kind="stable")
+
+
+# (kind, descending) -> the metric whose ascending distance IS that
+# order: what the runner's exact store serves. Every other order
+# (`manh`, similarity ascending, ...) is the host's.
+_SCAN_METRIC = {("cos_sim", True): "cosine", ("eucl", False): "euclidean",
+                ("dot", True): "dot"}
+
+
+def scan_on_device(col, metric) -> bool:
+    """Whether a whole-column scan under `metric` goes to the column's
+    device block. A scan the host answers by rule while a runner is
+    serving (an order or metric the device does not serve, a table
+    under `cnf.KNN_DEVICE_MIN_ROWS` rows, a zero row under cosine, a
+    superseded column) counts as host-routed; a table that small never
+    starts a runner."""
+    from surrealdb_tpu.idx.vector import device_routed
+
+    if not col.servable(metric):
+        note_scan_on_host()
+        return False
+    return device_routed()
+
+
+def note_scan_on_host():
+    """A vector top-k scan that the host answers by rule while a runner
+    is serving: counted once, as the index engine's are."""
+    from surrealdb_tpu.device import get_supervisor
+
+    sup = get_supervisor()
+    if sup.state == "ready":
+        sup.note_host_routed()
+
+
+class _ScanRider:
+    """One scan waiting for its dispatch: the column whose block it
+    reads, the device metric, the query's f32 bytes and how many rows
+    it wants."""
+
+    __slots__ = ("col", "metric", "q", "keep")
+
+    def __init__(self, col, metric, q, keep):
+        self.col = col
+        self.metric = metric
+        self.q = q
+        self.keep = keep
+
+
+_SCAN_BATCHER = None
+
+
+def _scan_batcher():
+    """The scans' cross-query batcher (device/batcher.py), one a
+    process like `exec/vops.py _get_fused_batcher`: it records the same
+    `batch_wait` / `batch_ride` / `batch_dispatch` stages and
+    `BATCH_STATS` as the index engine's."""
+    global _SCAN_BATCHER
+    if _SCAN_BATCHER is None:
+        from surrealdb_tpu.device import DeviceOpError, DeviceUnavailable
+        from surrealdb_tpu.device.batcher import DeviceBatcher
+
+        _SCAN_BATCHER = DeviceBatcher(
+            dispatch=_scan_dispatch, fallback=_scan_fallback,
+            retryable=(DeviceUnavailable, DeviceOpError),
+        )
+    return _SCAN_BATCHER
+
+
+def _scan_dispatch(riders: list) -> list:
+    """One batch of the batcher: riders of one column block and metric
+    share ONE `vec_knn` with k = the largest `keep` among them; each
+    gets the row numbers of its first `keep`."""
+    from surrealdb_tpu.col import device_topk, query_batch
+
+    groups: dict = {}
+    for i, r in enumerate(riders):
+        groups.setdefault((id(r.col), r.metric), []).append(i)
+    out = [None] * len(riders)
+    for idxs in groups.values():
+        r0 = riders[idxs[0]]
+        _dists, rows = device_topk(
+            r0.col, r0.metric, query_batch([riders[i].q for i in idxs]),
+            max(riders[i].keep for i in idxs))
+        n = r0.col.mat.shape[0]
+        for row, i in zip(rows, idxs):
+            row = row[:riders[i].keep]
+            out[i] = row[(row >= 0) & (row < n)]
+    return out
+
+
+def _scan_fallback(rider):
+    """Per-rider degrade after device trouble: None tells the operator
+    to score on the host (counted once as a fallback)."""
+    from surrealdb_tpu.device import get_supervisor
+
+    get_supervisor().note_fallback()
+    return None
 
 
 class _FallbackToLegacy(Exception):
@@ -953,6 +1070,10 @@ def build_select_plan(n, ctx):
                     f"expr: {spec[0]}, limit: {lim + off}]",
                 )
                 order = []
+            else:
+                # a dirty transaction or a ragged row: the row-at-a-time
+                # sort below answers
+                note_scan_on_host()
 
     if node is None:
         scan_label = (

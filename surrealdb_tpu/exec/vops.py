@@ -1201,10 +1201,11 @@ def _get_fused_batcher():
 
 def _fused_host_single(p):
     """Exact host scoring for one rider (the same `_host_distances`
-    ladder the legacy brute path uses)."""
+    ladder the legacy brute path uses). `cand` None: the whole column."""
     from surrealdb_tpu.idx.vector import TpuVectorIndex
 
-    xs = p["mat"][p["cand"]]
+    cand = p["cand"]
+    xs = p["col"].mat if cand is None else p["col"].mat[cand]
     tmp = TpuVectorIndex.__new__(TpuVectorIndex)
     tmp.vecs = xs
     tmp.metric = p["metric"]
@@ -1214,14 +1215,20 @@ def _fused_host_single(p):
     idx = np.argpartition(d, k - 1)[:k] if k < xs.shape[0] else \
         np.arange(xs.shape[0])
     idx = idx[np.argsort(d[idx], kind="stable")]
-    return [(int(p["cand"][int(i)]), float(d[i])) for i in idx]
+    return [(int(i if cand is None else cand[int(i)]), float(d[i]))
+            for i in idx]
 
 
 def _fused_dispatch(payloads):
     """One coalesced dispatch: group riders by (matrix, candidate-mask)
     and run ONE batched scoring kernel per group — device when healthy
-    and the candidate set is big enough, exact host ladder otherwise."""
+    and the candidate set is big enough, exact host ladder otherwise.
+    A group over the whole column (no residual predicate) searches the
+    column's resident device block (`col.device_topk`, what a no-index
+    `ORDER BY vector::...` scan reads too); a masked group ships its
+    surviving rows with the call (`brute_knn`)."""
     from surrealdb_tpu import cnf
+    from surrealdb_tpu.col import device_topk, query_batch
     from surrealdb_tpu.device import get_supervisor
 
     groups = {}
@@ -1231,28 +1238,31 @@ def _fused_dispatch(payloads):
     sup = get_supervisor()
     for token, idxs in groups.items():
         p0 = payloads[idxs[0]]
-        cand = p0["cand"]
-        n = int(cand.shape[0])
+        col, cand = p0["col"], p0["cand"]
+        n = col.mat.shape[0] if cand is None else int(cand.shape[0])
         if n == 0:
             for i in idxs:
                 results[i] = []
             continue
         use_device = n >= cnf.KNN_DEVICE_MIN_ROWS and sup.fast_path() \
-            and len(idxs) > 0
+            and (cand is not None or col.servable(p0["metric"]))
         if use_device:
-            xs = p0["mat"][cand]
-            qs = np.stack([payloads[i]["q"] for i in idxs])
+            qs = query_batch([payloads[i]["q"].tobytes() for i in idxs])
             kmax = min(max(payloads[i]["k"] for i in idxs), n)
-            _t, _m, bufs = sup.call(
-                "brute_knn",
-                {"k": kmax, "metric": p0["metric"], "p": p0["p"]},
-                [xs, qs.astype(np.float32)],
-            )
-            d, ind = bufs[0], bufs[1]
+            if cand is None:
+                d, ind = device_topk(col, p0["metric"], qs, kmax,
+                                     p0["p"])
+            else:
+                _t, _m, bufs = sup.call(
+                    "brute_knn",
+                    {"k": kmax, "metric": p0["metric"], "p": p0["p"]},
+                    [col.mat[cand], qs],
+                )
+                d, ind = bufs[0], bufs[1]
             for row, i in enumerate(idxs):
                 k = min(payloads[i]["k"], n)
                 results[i] = [
-                    (int(cand[int(ii)]), float(dd))
+                    (int(ii if cand is None else cand[int(ii)]), float(dd))
                     for dd, ii in zip(d[row][:k], ind[row][:k])
                     if ii >= 0
                 ]
@@ -1308,8 +1318,8 @@ def fused_brute_knn(tb, knn, qv, rest, ctx):
             return None
         cand = np.flatnonzero(mask[pos])
     else:
-        cand = np.arange(len(col.ids), dtype=np.int64)
-    if len(cand) == 0:
+        cand = None  # the whole column
+    if not (len(col.ids) if cand is None else len(cand)):
         return []
     from surrealdb_tpu.ops.metrics import normalize_metric
 
@@ -1318,9 +1328,10 @@ def fused_brute_knn(tb, knn, qv, rest, ctx):
     # exact mask bytes in the token — a hash collision between two
     # different candidate sets would score a rider against the wrong
     # rows, silently
-    token = (id(col.mat), cand.tobytes(), metric, float(p))
+    token = (id(col.mat), None if cand is None else cand.tobytes(),
+             metric, float(p))
     payload = {
-        "mat": col.mat, "cand": cand, "q": q, "k": int(knn.k),
+        "col": col, "cand": cand, "q": q, "k": int(knn.k),
         "metric": metric, "p": float(p), "token": token,
     }
     _count(ctx.ds, "fused_knn_queries")

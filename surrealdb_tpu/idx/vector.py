@@ -223,6 +223,48 @@ class _Coalescer(DeviceBatcher):
             return self.index._host_knn_single(q, k)
 
 
+def device_routed() -> bool:
+    """Routing policy for the scoring engine (SURREAL_KNN_HOST_BATCH),
+    shared by the index engine and the no-index column scan (col.py):
+    dispatch to the device runner on real accelerators; when the
+    "device" IS this host's CPU, the batched BLAS host path wins —
+    offloading numpy-speed kernels through jax only adds dispatch
+    overhead. `device` forces the old always-dispatch behavior,
+    `host` forces host scoring."""
+    from surrealdb_tpu.device import get_supervisor
+
+    mode = cnf.KNN_HOST_BATCH
+    if mode == "host":
+        return False
+    sup = get_supervisor()
+    if not sup.fast_path():
+        if sup.mode != "off":
+            # device wanted but cold/degraded/disabled: host serves
+            sup.note_fallback()
+        return False
+    if mode == "device":
+        return True
+    if sup.platform == "cpu":
+        # the "accelerator" is this host's own CPU (inline debug
+        # mode or a CPU-platform runner): one BLAS pass here beats
+        # shipping numpy-speed work through jax/IPC
+        sup.note_host_routed()
+        return False
+    return True
+
+
+def device_cfg() -> dict:
+    """Kernel budgets shipped per dispatch (read at call time so the
+    serving process's configuration governs the runner)."""
+    return {
+        "hbm_budget": cnf.KNN_HBM_BUDGET_BYTES,
+        "score_budget": cnf.KNN_SCORE_BUDGET_ELEMS,
+        "query_chunk": cnf.KNN_QUERY_CHUNK,
+        "int8_oversample": cnf.KNN_INT8_OVERSAMPLE,
+        "block_rows": BLOCK_ROWS,
+    }
+
+
 class TpuVectorIndex:
     """Per-(ns,db,tb,ix) device block cache + search engine."""
 
@@ -1254,32 +1296,8 @@ class TpuVectorIndex:
         return self.coalescer.search(qv, k)
 
     def _use_device(self) -> bool:
-        """Routing policy for the scoring engine (SURREAL_KNN_HOST_BATCH):
-        dispatch to the device runner on real accelerators; when the
-        "device" IS this host's CPU, the batched BLAS host path wins —
-        offloading numpy-speed kernels through jax only adds dispatch
-        overhead. `device` forces the old always-dispatch behavior,
-        `host` forces host scoring."""
-        from surrealdb_tpu.device import get_supervisor
-
-        mode = cnf.KNN_HOST_BATCH
-        if mode == "host":
-            return False
-        sup = get_supervisor()
-        if not sup.fast_path():
-            if sup.mode != "off":
-                # device wanted but cold/degraded/disabled: host serves
-                sup.note_fallback()
-            return False
-        if mode == "device":
-            return True
-        if sup.platform == "cpu":
-            # the "accelerator" is this host's own CPU (inline debug
-            # mode or a CPU-platform runner): one BLAS pass here beats
-            # shipping numpy-speed work through jax/IPC
-            sup.note_host_routed()
-            return False
-        return True
+        """This index's searches go to the device (`device_routed`)."""
+        return device_routed()
 
     def knn_batch(self, qvs: np.ndarray, k: int):
         """The raw batched engine entry: [B, D] queries -> per-query
@@ -1426,17 +1444,6 @@ class TpuVectorIndex:
                 ])
         return out
 
-    def _device_cfg(self) -> dict:
-        """Kernel budgets shipped per dispatch (read at call time so the
-        serving process's configuration governs the runner)."""
-        return {
-            "hbm_budget": cnf.KNN_HBM_BUDGET_BYTES,
-            "score_budget": cnf.KNN_SCORE_BUDGET_ELEMS,
-            "query_chunk": cnf.KNN_QUERY_CHUNK,
-            "int8_oversample": cnf.KNN_INT8_OVERSAMPLE,
-            "block_rows": BLOCK_ROWS,
-        }
-
     def _device_knn_batch(self, qvs: np.ndarray, k: int):
         """Batched search through the device supervisor: [B, D] queries
         -> per-query (rid, dist) lists. The runner ranks (bf16/int8/
@@ -1454,7 +1461,7 @@ class TpuVectorIndex:
             return "vec_load", {
                 "metric": self.metric,
                 "mink_p": self.mink_p,
-                "cfg": self._device_cfg(),
+                "cfg": device_cfg(),
             }, [
                 np.ascontiguousarray(self.vecs),
                 np.ascontiguousarray(self.valid.astype(np.uint8)),

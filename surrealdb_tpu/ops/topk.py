@@ -12,6 +12,9 @@ import numpy as np
 # f32 contractions that promise exact results (the [B, kc, D] rescore):
 # full f32 on the MXU instead of the TPU's default single bf16 pass
 _EXACT = jax.lax.Precision.HIGHEST
+# rows a step of `knn_search_blocked` scores: [B, SCAN_BLOCK] f32 is
+# what the exact store's batches are sized against (device/vecstore.py)
+SCAN_BLOCK = 65536
 
 
 def pack_pairs(dists, ids):
@@ -215,43 +218,58 @@ def knn_rank_int8(xs_q, arow, x2, valid, qs_r, kc: int,
 
 @partial(jax.jit, static_argnames=("k", "metric", "block", "packed"))
 def knn_search_blocked(xs, qs, k: int, metric: str = "euclidean",
-                       p: float = 3.0, valid=None, block: int = 65536,
-                       packed: bool = False):
+                       p: float = 3.0, valid=None,
+                       block: int = SCAN_BLOCK, packed: bool = False):
     """Blockwise scan for stores too large to materialize [B, N] at once:
-    lax.scan over row blocks keeping a running top-k (HBM-bandwidth bound,
-    peak memory [B, block]). Returns as `knn_search` does."""
+    a loop over row blocks keeping a running top-k (HBM-bandwidth bound,
+    peak memory [B, block]). Every block is a `dynamic_slice` of the
+    store as it lies: nothing is padded or copied, so a dispatch reads
+    the rows once. The last block is moved back to end at the last row
+    and the rows it shares with the one before are masked out. Returns
+    as `knn_search` does."""
     from surrealdb_tpu.ops.distance import distance_matrix
 
-    n, dim = xs.shape
+    n = xs.shape[0]
     b = qs.shape[0]
+    block = max(min(block, n), 1)
     nblocks = max((n + block - 1) // block, 1)
-    pad = nblocks * block - n
-    xs_p = jnp.pad(xs, ((0, pad), (0, 0)))
     if valid is None:
         valid = jnp.ones((n,), dtype=bool)
-    valid_p = jnp.pad(valid, (0, pad))
-    xs_b = xs_p.reshape(nblocks, block, dim)
-    valid_b = valid_p.reshape(nblocks, block)
+    offs = jnp.arange(block, dtype=jnp.int32)
+    kb = min(k, block)
+
+    def step(i, carry):
+        best_d, best_i = carry
+        base = i * block
+        start = jnp.minimum(base, n - block)
+        blk = jax.lax.dynamic_slice_in_dim(xs, start, block, 0)
+        rows = start + offs
+        vmask = jax.lax.dynamic_slice_in_dim(valid, start, block, 0) \
+            & (rows >= base)
+        d = distance_matrix(blk, qs, metric, p)
+        d = jnp.where(vmask[None, :], d, jnp.inf)
+        cand_d, cand_i = jax.lax.top_k(-d, kb)
+        merged_d = jnp.concatenate([best_d, -cand_d], axis=1)
+        merged_i = jnp.concatenate([best_i, cand_i + start], axis=1)
+        nd, sel = jax.lax.top_k(-merged_d, k)
+        return -nd, jnp.take_along_axis(merged_i, sel, axis=1)
 
     init = (
         jnp.full((b, k), jnp.inf, dtype=jnp.float32),
         jnp.full((b, k), -1, dtype=jnp.int32),
     )
-
-    def step(carry, inp):
-        best_d, best_i = carry
-        blk, vmask, base = inp
-        d = distance_matrix(blk, qs, metric, p)
-        d = jnp.where(vmask[None, :], d, jnp.inf)
-        cand_d, cand_i = jax.lax.top_k(-d, min(k, block))
-        cand_d = -cand_d
-        cand_i = cand_i + base
-        merged_d = jnp.concatenate([best_d, cand_d], axis=1)
-        merged_i = jnp.concatenate([best_i, cand_i], axis=1)
-        nd, sel = jax.lax.top_k(-merged_d, k)
-        ni = jnp.take_along_axis(merged_i, sel, axis=1)
-        return (-nd, ni), None
-
-    bases = jnp.arange(nblocks, dtype=jnp.int32) * block
-    (fd, fi), _ = jax.lax.scan(step, init, (xs_b, valid_b, bases))
+    fd, fi = jax.lax.fori_loop(0, nblocks, step, init)
     return pack_pairs(fd, fi) if packed else (fd, fi)
+
+
+@partial(jax.jit, static_argnames=("k", "metric", "block_rows"))
+def exact_scan(xs, qs, k: int, metric: str, p, valid, block_rows: int):
+    """The exact store's program (device/vecstore.py), under one name
+    whatever the store's size: every row scored in f32
+    (`distance_matrix`, `Precision.HIGHEST`), exact `lax.top_k`, no
+    candidate set. `knn_search` where the [B, N] scores fit
+    (`block_rows`), `knn_search_blocked` above. [B, D] f32 queries in,
+    the one `pack_pairs` array [B, 2k] out."""
+    if xs.shape[0] > block_rows:
+        return knn_search_blocked(xs, qs, k, metric, p, valid, packed=True)
+    return knn_search(xs, qs, k, metric, p, valid, packed=True)

@@ -197,6 +197,10 @@ def encode(v) -> bytes:
 # ---------------------------------------------------------------------------
 
 
+# arrays of at least this many items are tried as one run of f64s
+_F64_RUN_MIN = 8
+
+
 class _Dec:
     def __init__(self, data: bytes):
         self.b = data
@@ -240,6 +244,16 @@ class _Dec:
             return self.take(self.arg(info)).decode("utf-8")
         if major == 4:
             n = self.arg(info)
+            end = self.i + 9 * n
+            if n >= _F64_RUN_MIN and end <= len(self.b) \
+                    and self.b[self.i:end:9] == b"\xfb" * n:
+                # a run of f64s (a stored vector): one C call over the
+                # run, skipping each item's marker byte, instead of n
+                # trips through `value`; the same `>d` either way
+                out = list(struct.unpack_from(">" + "xd" * n, self.b,
+                                              self.i))
+                self.i = end
+                return out
             return [self.value() for _ in range(n)]
         if major == 5:
             n = self.arg(info)

@@ -191,18 +191,26 @@ def test_a_bucket_compiles_one_program_and_a_rider_count_none(
 
 
 def test_f32_search_compiles_nothing_beside_its_kernel(one_device):
-    """`knn_search` takes [B, D] as it comes (a program a rider count,
-    as before); what went is every program beside it."""
+    """The exact store's program is `exact_scan` whatever its size, and
+    since PR 31 its batch is padded to a power-of-two bucket like the
+    ranking branches': a program a bucket, none a rider count, and no
+    program beside it."""
     from surrealdb_tpu.device import kernelstats
 
     kernelstats.install_jax_listeners()
     by_fn = kernelstats.COMPILE["backend_compile_s"]
     st = make_store("f32", rows=831)
     before = dict(by_fn)
-    for b in (1, 3, 5):
+    compiled_at = []
+    for b in range(1, 10):
+        seen = dict(by_fn)
         st.knn(st.vecs[:b], K)
+        if by_fn != seen:
+            compiled_at.append(b)
+    # `query_chunk` is 8 here: 9 riders are two rounds of the 8-program
+    assert compiled_at == [1, 2, 3, 5]
     assert {fn for fn in by_fn if by_fn[fn] != before.get(fn)} \
-        == {"jit(knn_search)"}
+        == {"jit(exact_scan)"}
 
 
 def test_the_fault_hook_still_plants_its_kc(one_device, monkeypatch):

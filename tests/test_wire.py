@@ -3,6 +3,8 @@ core/src/rpc/format/cbor tag dialect; VERDICT round-2 item 8)."""
 
 from decimal import Decimal
 
+import pytest
+
 from surrealdb_tpu import wire
 from surrealdb_tpu.val import (
     NONE, Datetime, Duration, File, Geometry, Range, RecordId, SSet,
@@ -93,3 +95,52 @@ def test_http_rpc_cbor():
         assert out["result"][0]["result"] == 42
     finally:
         srv.shutdown()
+
+
+# -- a stored vector decodes as one run of f64s (PR 31) ----------------------
+
+
+def _slow_decode(data: bytes):
+    """The item-by-item decoder, with the run's fast path switched off."""
+    from surrealdb_tpu import wire
+
+    old = wire._F64_RUN_MIN
+    wire._F64_RUN_MIN = 1 << 62
+    try:
+        return wire.decode(data)
+    finally:
+        wire._F64_RUN_MIN = old
+
+
+@pytest.mark.parametrize("value", [
+    [0.5] * 7,                                   # under the run's floor
+    [0.5] * 8,
+    [float(i) / 3 for i in range(768)],          # an embedding
+    [1.5, float("inf"), float("-inf"), -0.0, 5e-324, 1.7976931348623157e308,
+     0.1, 0.2, 0.3],
+    [0.5] * 8 + [2],                             # an int ends the run: no run
+    [0.5] * 8 + [None],
+    list(range(12)),
+    [[0.25] * 9, [0.75] * 9, "x"],               # runs inside an array
+    {"id": 7, "emb": [0.125] * 16, "tags": ["a", "b"], "n": {"v": [2.5] * 8}},
+])
+def test_f64_run_decodes_as_item_by_item(value):
+    from surrealdb_tpu import wire
+
+    data = wire.encode(value)
+    fast, slow = wire.decode(data), _slow_decode(data)
+    assert repr(fast) == repr(slow) == repr(value)   # repr: -0.0 and types
+
+
+def test_f64_run_keeps_nan_and_refuses_truncation():
+    import math
+
+    from surrealdb_tpu import wire
+    from surrealdb_tpu.err import SdbError
+
+    data = wire.encode([float("nan")] * 4 + [1.0] * 6)
+    out = wire.decode(data)
+    assert all(math.isnan(x) for x in out[:4]) and out[4:] == [1.0] * 6
+    for cut in (3, 20, len(data) - 1):
+        with pytest.raises(SdbError, match="truncated"):
+            wire.decode(data[:cut])
