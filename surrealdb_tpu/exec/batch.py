@@ -373,6 +373,7 @@ COUNTER_KEYS = (
     "batches_vectorized",
     "rows_vectorized",
     "rows_fallback",
+    "scan_rows_owned",
     "agg_groups",
     "agg_columnar",
     "agg_streamed",

@@ -128,6 +128,33 @@ def fetch_record(ctx: Ctx, rid: RecordId):
     return doc
 
 
+def fetch_record_owned(ctx: Ctx, rid: RecordId):
+    """`fetch_record` for a reader that owns the rows it yields (the
+    top-k scan's winners): the stored bytes are decoded fresh, outside
+    the content-keyed decode cache and its deep copy
+    (`kvs/api.py deserialize_fresh`). Everything else is `fetch_record`'s:
+    a history read and a suppressed link fetch go through it unchanged,
+    and the statement's `record_cache` and computed fields work alike."""
+    if ctx._no_link_fetch or ctx.version is not None:
+        return fetch_record(ctx, rid)
+    ck = (rid.tb, K.enc_value(rid.id))
+    hit = ctx.record_cache.get(ck)
+    if hit is not None:
+        return hit
+    ns, db = ctx.need_ns_db()
+    raw = ctx.txn.get(K.record(ns, db, rid.tb, rid.id))
+    if raw is None:
+        doc = NONE
+    else:
+        from surrealdb_tpu.kvs.api import deserialize_fresh
+
+        doc = deserialize_fresh(raw)
+        ctx.record_cache[ck] = doc  # pre-cache raw: breaks compute cycles
+        doc = apply_computed_fields(rid.tb, doc, rid, ctx)
+    ctx.record_cache[ck] = doc
+    return doc
+
+
 def computed_fields_of(tb: str, ctx: Ctx):
     """Computed field definitions for a table (cached per statement)."""
     ck = ("__computed__", tb)

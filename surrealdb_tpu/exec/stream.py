@@ -339,6 +339,9 @@ class VecTopKScanOp(Operator):
     (`scan_host`). The winners' projected scores recompute per-row in
     f64 from the fetched documents, so output values are bit-identical
     to the row-at-a-time engine; only the ranking runs on the f32 column.
+    The operator owns the rows it yields: each winner is decoded fresh
+    from its stored bytes (`exec/eval.py fetch_record_owned`), outside
+    the decode cache, and counted in `scan_rows_owned`.
     Stages: `vec_scan` (the device leg, submit until the row numbers are
     back) and `scan_fetch` (the winners fetched and yielded).
     Reference role: exec/operators/knn_topk.rs (KnnTopK scan operator)."""
@@ -355,7 +358,8 @@ class VecTopKScanOp(Operator):
     def _execute(self, ctx):
         from surrealdb_tpu import key as K
         from surrealdb_tpu.col import get_vector_column
-        from surrealdb_tpu.exec.eval import fetch_record
+        from surrealdb_tpu.exec.batch import _count
+        from surrealdb_tpu.exec.eval import fetch_record_owned
         from surrealdb_tpu.exec.statements import Source
         from surrealdb_tpu.telemetry import stage_record
         from surrealdb_tpu.val import RecordId
@@ -385,15 +389,17 @@ class VecTopKScanOp(Operator):
         for i in order[self.skip:]:
             ctx.check_deadline()
             rid = RecordId(self.tb, col.ids[int(i)])
-            doc = fetch_record(ctx, rid)
+            doc = fetch_record_owned(ctx, rid)
             if doc is NONE:
                 continue
             batch.append(Source(rid=rid, doc=doc))
             if len(batch) >= BATCH_SIZE:
+                _count(ctx.ds, "scan_rows_owned", len(batch))
                 yield batch
                 batch = []
         stage_record("scan_fetch", time.monotonic_ns() - t0)
         if batch:
+            _count(ctx.ds, "scan_rows_owned", len(batch))
             yield batch
 
 

@@ -190,6 +190,20 @@ def deserialize_shared(b: bytes):
     return deserialize(b)
 
 
+def deserialize_fresh(b: bytes):
+    """Decode OUTSIDE the decode cache: no look-up, no insert, no lock,
+    no copy. The value is new every call and the caller's to mutate.
+    For a reader that hands its rows to one consumer and whose keys
+    rarely repeat (`exec/stream.py VecTopKScanOp`: ten winners of a
+    million rows, other ones every query), where a cache miss costs
+    several fresh decodes and a hit still hashes and deep-copies."""
+    if b[:1] == b"\x01":
+        from surrealdb_tpu import wire
+
+        return wire.decode(b[1:])
+    return deserialize(b)  # the pickle framings never enter the cache
+
+
 class _RestrictedUnpickler(pickle.Unpickler):
     """The pickle fallback codec only ever stores this package's own
     types (AST-bearing catalog structs) plus stdlib value types. In

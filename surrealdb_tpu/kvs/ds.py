@@ -262,8 +262,8 @@ class Datastore:
         from surrealdb_tpu.exec.batch import counters as _col_counters
 
         self._columnar_counters = _col_counters(self)
-        for _ck in ("rows_vectorized", "rows_fallback", "colstore_hits",
-                    "colstore_builds", "fused_knn_queries",
+        for _ck in ("rows_vectorized", "rows_fallback", "scan_rows_owned",
+                    "colstore_hits", "colstore_builds", "fused_knn_queries",
                     "pushdown_rows_pruned"):
             self.telemetry.register_counter(
                 f"columnar_{_ck}",

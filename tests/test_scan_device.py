@@ -17,6 +17,7 @@ from surrealdb_tpu import col as colmod
 from surrealdb_tpu import key as K
 from surrealdb_tpu.device import get_supervisor, kernelstats, set_supervisor
 from surrealdb_tpu.device.supervisor import DeviceSupervisor
+from surrealdb_tpu.exec.batch import counters
 from surrealdb_tpu.kvs.api import serialize
 from surrealdb_tpu.val import RecordId
 
@@ -117,11 +118,14 @@ def test_device_scan_equals_host_scan_and_f64(monkeypatch, kind, direction,
                                  limit, start)
     assert got_ids == want_ids
     assert np.allclose(got_s, want_s, rtol=0, atol=1e-12)
+    # the winners came by the owned fetch, on either route
+    assert counters(ds)["scan_rows_owned"] == len(got_ids)
     with monkeypatch.context() as m:
         on_host(m)
         host_ids, host_s = ask(ds, text, q)
     assert scan_counts() == after          # the host answered that one
     assert host_ids == got_ids and host_s == got_s
+    assert counters(ds)["scan_rows_owned"] == 2 * len(got_ids)
 
 
 def test_riders_of_different_limit_share_one_dispatch(monkeypatch):

@@ -144,3 +144,72 @@ def test_f64_run_keeps_nan_and_refuses_truncation():
     for cut in (3, 20, len(data) - 1):
         with pytest.raises(SdbError, match="truncated"):
             wire.decode(data[:cut])
+
+
+# -- a decode outside the decode cache (PR 32) -------------------------------
+
+
+def _framed(framing: str) -> bytes:
+    import pickle
+
+    from surrealdb_tpu.catalog import TableDef
+    from surrealdb_tpu.kvs.api import serialize
+
+    if framing == "wire":
+        raw = serialize({"id": RecordId("t", 32), "emb": [0.25] * 16,
+                         "tags": ["a", {"n": NONE}]})
+        assert raw[:1] == b"\x01"
+    elif framing == "pickle":
+        raw = serialize(TableDef(name="t32"))     # carries no wire encoding
+        assert raw[:1] == b"\x00"
+    else:
+        raw = pickle.dumps({"k": [1, 2.5, "x"]})  # legacy: no header byte
+        assert raw[:1] == b"\x80"
+    return raw
+
+
+@pytest.mark.parametrize("framing", ["wire", "pickle", "headerless"])
+def test_deserialize_fresh_equals_deserialize_and_shares_nothing(framing):
+    from surrealdb_tpu.kvs import api
+
+    raw = _framed(framing)
+    api.deserialize(raw)            # a wire-framed value is in the cache now
+    assert (raw in api._dec_cache) == (framing == "wire")
+    cached, charged = dict(api._dec_cache), api._dec_cache_bytes
+    a, b = api.deserialize_fresh(raw), api.deserialize_fresh(raw)
+    assert a == b == api.deserialize(raw)
+    assert repr(a) == repr(api.deserialize(raw))
+    assert a is not b
+    if framing == "wire":
+        pristine = api._dec_cache[raw]
+        assert a is not pristine and a["emb"] is not pristine["emb"]
+        assert a["tags"][1] is not pristine["tags"][1]
+        a["emb"].append("mine")     # the caller's to mutate
+        a["tags"][1]["n"] = 1
+        assert api.deserialize(raw) == b == api.deserialize_fresh(raw)
+    assert api._dec_cache_bytes == charged
+    assert api._dec_cache.keys() == cached.keys()
+    assert all(api._dec_cache[k] is v for k, v in cached.items())
+
+
+def test_deserialize_fresh_never_fills_the_cache():
+    from surrealdb_tpu.kvs import api
+    from surrealdb_tpu.kvs.api import serialize
+
+    raw = serialize({"never": "seen", "emb": [0.5] * 32})
+    cached, charged = len(api._dec_cache), api._dec_cache_bytes
+    assert api.deserialize_fresh(raw) == {"never": "seen", "emb": [0.5] * 32}
+    assert raw not in api._dec_cache
+    assert (len(api._dec_cache), api._dec_cache_bytes) == (cached, charged)
+
+
+@pytest.mark.parametrize("header", [b"\x00", b""])
+def test_deserialize_fresh_refuses_a_disallowed_global(header):
+    import pickle
+
+    from surrealdb_tpu.kvs import api
+
+    raw = header + pickle.dumps(pickle.PickleBuffer)   # a global by name
+    for decode in (api.deserialize, api.deserialize_fresh):
+        with pytest.raises(pickle.UnpicklingError, match="disallowed type"):
+            decode(raw)
