@@ -309,6 +309,11 @@ class DeviceHost:
             "csr": dict(kernelstats.CSR),
             # the exact stores' scans (kernelstats.SCAN)
             "scan": dict(kernelstats.SCAN),
+            # the growing stores: rows held and rows allocated for, a
+            # store, and what `vec_append` wrote (kernelstats.APPEND)
+            "vec": {key: {"rows": s.n, "capacity": s.capacity}
+                    for key, (_t, s) in self.vec.items() if s.growable},
+            "append": dict(kernelstats.APPEND),
         }, []
 
     def op_profile(self, meta, bufs):
@@ -437,6 +442,10 @@ class DeviceHost:
         self.vec.move_to_end(meta["key"])
         out_meta, out_bufs = ent[1].knn(bufs[0], int(meta["k"]))
         out_meta.setdefault("mesh_ndev", _store_ndev(ent[1]))
+        if ent[1].growable:
+            # tells the serving side that a delta will do next time,
+            # and up to which row
+            out_meta["capacity"] = ent[1].capacity
         if out_meta.get("rank_mode") is None:
             # an exact store scored every row for every rider
             from surrealdb_tpu.device.kernelstats import SCAN
@@ -444,8 +453,34 @@ class DeviceHost:
             riders = bufs[0].shape[0]
             SCAN["riders"] += riders
             SCAN["dispatches"] += 1
-            SCAN["rows_scored"] += riders * ent[1].vecs.shape[0]
+            SCAN["rows_scored"] += riders * ent[1].shape[0]
         return "ok", out_meta, out_bufs
+
+    def op_vec_append(self, meta, bufs):
+        """A delta for a resident block that grows in place
+        (device/vecstore.py `append`): rows [m, D], their row numbers
+        and mask bits, from tag `tag_from` to `tag`. `stale` where the
+        runner does not hold `tag_from` (evicted, restarted, another
+        delta went first), `full` where the store does not grow in
+        place or the rows pass its capacity: either way the caller
+        ships the whole block, and that is the only time a write costs
+        a re-ship and new programs."""
+        key = meta["key"]
+        ent = self.vec.get(key)
+        if ent is None or ent[0] != list(meta["tag_from"]):
+            return "stale", {}, []
+        rows, row_numbers, flags = bufs
+        st = ent[1]
+        if not (st.growable and st.append(rows, row_numbers, flags)):
+            return "full", {}, []
+        self.vec[key] = (list(meta["tag"]), st)
+        self.vec.move_to_end(key)
+        from surrealdb_tpu.device.kernelstats import APPEND
+
+        APPEND["appends"] += 1
+        APPEND["rows"] += int(len(row_numbers))
+        APPEND["bytes"] += sum(int(b.nbytes) for b in bufs)
+        return "ok", {"rows": st.n, "capacity": st.capacity}, []
 
     def _prewarm_shapes(self, cache, meta, field, warm_one):
         """Shared prewarm skeleton: compile one kernel shape per listed
@@ -475,7 +510,14 @@ class DeviceHost:
         k = int(meta.get("k", 10))
 
         def warm(st, b):
-            st.knn(np.zeros((b, st.vecs.shape[1]), np.float32), k)
+            st.knn(np.zeros((b, st.shape[1]), np.float32), k)
+            # a store that grows in place: the append programs of every
+            # ladder step up to `b` rows (warming is idempotent: a step
+            # met before is a counted hit)
+            step = 1
+            while step <= b and st.growable:
+                st.warm_append(step)
+                step *= 2
 
         return self._prewarm_shapes(self.vec, meta, "buckets", warm)
 

@@ -73,6 +73,11 @@ CSR = {"bag_riders": 0, "paths_out": 0, "edges_gathered": 0,
 # reports it as `scan`
 # lint: mem-account(fixed-key int counters, not derived state)
 SCAN = {"riders": 0, "dispatches": 0, "rows_scored": 0}
+# `vec_append` on the stores that grow in place (device/handlers.py;
+# the program `vec_append`): deltas written, their rows (unpadded) and
+# the bytes of their buffers; `op_status` reports it as `append`
+# lint: mem-account(fixed-key int counters, not derived state)
+APPEND = {"appends": 0, "rows": 0, "bytes": 0}
 
 
 @contextmanager

@@ -278,6 +278,8 @@ class MeshVecStore:
                  offsets=None):
         self.key = key
         self.vecs = vecs
+        self.shape = tuple(int(v) for v in vecs.shape)  # as VecStore's
+        self.growable = False  # a mesh store takes whole loads only
         self.valid = valid.astype(bool)
         self.metric = metric
         self.mink_p = float(mink_p)

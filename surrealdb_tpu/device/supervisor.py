@@ -127,6 +127,10 @@ class DeviceSupervisor:
             "device_fallbacks": 0, "device_host_routed": 0,
             "device_oom_refusals": 0,
             "device_col_ships": 0, "device_col_ship_bytes": 0,
+            # writes to resident vector blocks: deltas sent as
+            # `vec_append` (their rows and bytes), and whole `vec_load`s
+            "device_vec_appends": 0, "device_vec_append_rows": 0,
+            "device_vec_append_bytes": 0, "device_vec_full_ships": 0,
         }
         # wall seconds spent shipping block caches to the runner
         self.ship_s = 0.0
@@ -247,12 +251,16 @@ class DeviceSupervisor:
                 return False
 
     def call(self, op: str, meta: dict, bufs=(),
-             timeout_s: Optional[float] = None):
+             timeout_s: Optional[float] = None, sent=None):
         """One dispatch -> (tag, meta, bufs). Raises DeviceUnavailable
         (degrade to host), DeviceOpError (this op failed), or SdbError
         (mode=require and the device can't serve). Wall time lands in
         the `device_rpc` stage stat, and a live runner's reply cuts it
-        into six more (`_record_rpc_parts`)."""
+        into six more (`_record_rpc_parts`). `sent()`, if given, runs
+        once the request is in the runner's queue, which the runner
+        serves in order: whatever is sent after it finds this op done.
+        An inline host runs the op in the caller's thread and never
+        calls it."""
         from surrealdb_tpu.telemetry import stage_record
 
         if self.mode == "off" or self._stop.is_set():
@@ -287,7 +295,7 @@ class DeviceSupervisor:
         try:
             t0 = time.perf_counter_ns()
             try:
-                return self._call_live(op, meta, bufs, base)
+                return self._call_live(op, meta, bufs, base, sent=sent)
             finally:
                 stage_record("device_rpc",
                              time.perf_counter_ns() - t0)
@@ -312,14 +320,26 @@ class DeviceSupervisor:
     # frame (and no transient copy) has to hold the whole store
     LOAD_PART_BYTES = 256 << 20
 
-    def ensure_loaded(self, key: str, tag, loader):
+    def ensure_loaded(self, key: str, tag, loader, delta=None):
         """Ship a block cache unless (key, tag) is already resident on
         the CURRENT runner. `loader() -> (op, meta, bufs)` materializes
-        the payload only when a ship is actually needed."""
+        the payload only when a ship is actually needed. `delta`, for a
+        vector block that grows in place, is `(tag_from, make)`: where
+        the runner holds `tag_from`, `make() -> [rows, row numbers,
+        mask bits]` goes as ONE `vec_append` and the block moves to
+        `tag` where it lies; where it does not (`stale`: a restart, an
+        eviction) or the rows pass its capacity (`full`), the loader's
+        whole ship follows, as ever."""
         tag = list(tag)
         with self._lock:
             if self._loaded.get(key) == tag:
                 return
+            held = self._loaded.get(key)
+        if delta is not None and held is not None \
+                and delta[0] is not None and held == list(delta[0]) \
+                and self._append(key, held, tag, delta[1]):
+            return
+        with self._lock:
             if self._oom_keys.get(key) == tag:
                 # the runner already refused this exact store under its
                 # byte budget: fail fast instead of re-shipping it just
@@ -343,6 +363,8 @@ class DeviceSupervisor:
         # before it would reach a handler here, and the recording must
         # survive that
         t0 = time.monotonic()
+        if op == "vec_load":
+            self.counters["device_vec_full_ships"] += 1
         if (op == "vec_load"
                 and bufs[0].nbytes > self.LOAD_PART_BYTES):
             self._multipart_vec_load(key, tag, meta, bufs[0], bufs[1])
@@ -360,6 +382,32 @@ class DeviceSupervisor:
                     "csr_load": "csr"}.get(op)
             if kind is not None:
                 self._prewarm_async(key, tag, kind)
+
+    def _append(self, key: str, tag_from, tag, make) -> bool:
+        """One `vec_append` from `tag_from` to `tag`; stage
+        `vec_append`, call to reply (inside it the call's `device_rpc`
+        and its parts, as any call's). False, and the key forgotten,
+        where the runner said `stale` or `full`."""
+        from surrealdb_tpu.telemetry import stage_record
+
+        bufs = make()
+        t0 = time.perf_counter_ns()
+        t, _m, _b = self.call(
+            "vec_append", {"key": key, "tag_from": tag_from, "tag": tag},
+            bufs, timeout_s=self.load_timeout_s,
+        )
+        stage_record("vec_append", time.perf_counter_ns() - t0)
+        if t != "ok":
+            self.forget(key)
+            return False
+        with self._lock:
+            self.ship_s += (time.perf_counter_ns() - t0) / 1e9
+            self._loaded[key] = tag
+            self.counters["device_vec_appends"] += 1
+            self.counters["device_vec_append_rows"] += len(bufs[1])
+            self.counters["device_vec_append_bytes"] += sum(
+                int(b.nbytes) for b in bufs)
+        return True
 
     def _multipart_vec_load(self, key, tag, meta, vecs, valid):
         begin = dict(meta)
@@ -496,6 +544,10 @@ class DeviceSupervisor:
             "oom_refusals": self.counters.get("device_oom_refusals", 0),
             "col_ships": self.counters["device_col_ships"],
             "col_ship_bytes": self.counters["device_col_ship_bytes"],
+            "vec_appends": self.counters["device_vec_appends"],
+            "vec_append_rows": self.counters["device_vec_append_rows"],
+            "vec_append_bytes": self.counters["device_vec_append_bytes"],
+            "vec_full_ships": self.counters["device_vec_full_ships"],
             "last_error": self.last_error,
             "vec_blocks": sum(1 for k in loaded if k.startswith("vec/")),
             "csr_blocks": sum(1 for k in loaded if k.startswith("csr/")),
@@ -878,7 +930,7 @@ class DeviceSupervisor:
     # -- live dispatch -------------------------------------------------------
 
     def _call_live(self, op, meta, bufs, base_timeout,
-                   health_check=False):
+                   health_check=False, sent=None):
         t_call = time.monotonic_ns()
         budget = None if health_check else _query_remaining()
         eff = base_timeout if budget is None \
@@ -900,6 +952,8 @@ class DeviceSupervisor:
         meta = dict(meta)
         meta["seq"] = seq
         sq.put((op, meta, bufs))
+        if sent is not None:
+            sent()
         start = time.monotonic()
         end = start + eff
         cancelled = False
@@ -1146,7 +1200,9 @@ def attach_telemetry(telemetry):
     for name in ("device_restarts", "device_dispatch_timeouts",
                  "device_fallbacks", "device_host_routed",
                  "device_oom_refusals", "device_col_ships",
-                 "device_col_ship_bytes"):
+                 "device_col_ship_bytes", "device_vec_appends",
+                 "device_vec_append_rows", "device_vec_append_bytes",
+                 "device_vec_full_ships"):
         telemetry.register_gauge(
             name, lambda n=name: get_supervisor().counters.get(n, 0)
         )

@@ -14,6 +14,15 @@ metrics); budgets arrive in `cfg` per dispatch so the serving process's
 configuration governs. A store whose `cfg` says `exact` (a table's
 column block under a no-index scan, col.py) takes the exact kernels
 whatever its metric: f32 rows only, every row scored, no candidate set.
+
+The single-device bf16 ranking store GROWS IN PLACE: its arrays are
+allocated at `capacity_for(n)` rows, the rows past `n` are invalid by
+the mask the kernels already honour, every program is keyed by the
+capacity and never by `n`, and `append` writes new, overwritten and
+tombstoned rows at their row numbers with one donating program
+(`vec_append`). A whole re-ship, and new programs, come once a
+capacity step of rows. The other branches (exact, int8, a mesh) keep
+the whole-load path.
 """
 
 from __future__ import annotations
@@ -29,6 +38,79 @@ def exact_store(metric: str, cfg: dict) -> bool:
     exact kernels: always for the non-MXU metrics, and for any metric
     when the shipper's `cfg` says `exact`."""
     return metric not in MXU_METRICS or bool(cfg.get("exact"))
+
+
+def capacity_for(n: int) -> int:
+    """Rows a growing store of `n` rows is allocated for: a function of
+    `n` alone. The step is a thirty-second of the next power of two
+    (256 at least: 4,096 at 100,000 rows), and the headroom is one to
+    two steps, 3-9 % of the rows from 8,192 up: (n // step + 2) * step."""
+    n = max(int(n), 1)
+    step = max(256, (1 << (n - 1).bit_length()) // 32)
+    return (n // step + 2) * step
+
+
+def _append_bucket(m: int) -> int:
+    """The ladder of delta sizes `append` pads to: 1, 2, 4, ..."""
+    return 1 << max(int(m) - 1, 0).bit_length()
+
+
+# lint: mem-account(one jitted function a metric: three at most)
+_APPEND_PROGRAMS: dict = {}
+
+
+def _append_program(metric: str):
+    """The jitted, donating program behind `VecStore.append`, one a
+    metric (the program's name in a device trace is `jit_vec_append`
+    whatever the metric: `benchmark/layers/vec_append_roofline.py`
+    finds it there). `packed` is ONE int32 array [m, D + 3]: the new
+    rows' f32 bits, each row's stat's f32 bits (x2 under euclidean, the
+    norm under cosine), its row number and its mask bit. A row number
+    outside the store (the padding of a delta to its ladder step) is
+    dropped by the scatter."""
+    fn = _APPEND_PROGRAMS.get(metric)
+    if fn is not None:
+        return fn
+    import jax
+    import jax.numpy as jnp
+
+    def vec_append(full, rank, stat, valid, packed):
+        with jax.named_scope("vec_append"):
+            dim = full.shape[1]
+            rows = jax.lax.bitcast_convert_type(
+                packed[:, :dim], jnp.float32)
+            rstat = jax.lax.bitcast_convert_type(
+                packed[:, dim], jnp.float32)
+            idx = packed[:, dim + 1]
+            flags = packed[:, dim + 2] != 0
+            # as `ensure` derives the ranking copy from the f32 rows
+            if metric == "cosine":
+                rrows = (rows / rstat[:, None]).astype(jnp.bfloat16)
+            else:
+                rrows = rows.astype(jnp.bfloat16)
+            return (full.at[idx].set(rows, mode="drop"),
+                    rank.at[idx].set(rrows, mode="drop"),
+                    stat.at[idx].set(rstat, mode="drop"),
+                    valid.at[idx].set(flags, mode="drop"))
+
+    fn = jax.jit(vec_append, donate_argnums=(0, 1, 2, 3))
+    _APPEND_PROGRAMS[metric] = fn
+    return fn
+
+
+def row_stat(rows: np.ndarray, metric: str):
+    """The per-row stat of the ranking stores, f64 on the host: squared
+    norms for euclidean ranking, norms for the cosine rescore, None for
+    dot. `ensure` computes it for a whole block and `append` for a
+    delta: a row's stat does not depend on its neighbours, so an
+    appended row is bit-identical to the same row loaded fresh."""
+    if metric == "euclidean":
+        return (rows.astype(np.float64) ** 2).sum(axis=1).astype(np.float32)
+    if metric == "cosine":
+        return np.maximum(
+            np.linalg.norm(rows.astype(np.float64), axis=1), 1e-30
+        ).astype(np.float32)
+    return None
 
 
 def _device_count() -> int:
@@ -79,8 +161,17 @@ class VecStore:
     def __init__(self, key: str, vecs: np.ndarray, valid: np.ndarray,
                  metric: str, mink_p: float, cfg: dict):
         self.key = key
+        # the shipped rows; the growing store lets go of them once
+        # they are on the device (`ensure`): nothing reads them there,
+        # and an append would leave them behind
         self.vecs = vecs
         self.valid = valid.astype(bool)
+        self.n, self.dim = (int(v) for v in vecs.shape)
+        self.itemsize = int(vecs.dtype.itemsize)
+        # rows the device arrays are allocated for: `n`, or, where the
+        # store grows in place, `capacity_for(n)` as loaded
+        self.capacity = self.n
+        self.growable = False
         self.metric = metric
         self.mink_p = float(mink_p)
         self.cfg = dict(cfg)
@@ -94,8 +185,13 @@ class VecStore:
         self.rank_mode = None  # "bf16" | "int8" | None (exact store)
         self.mesh = None
 
+    @property
+    def shape(self) -> tuple:
+        """(rows, dim) as of the last load or append."""
+        return (self.n, self.dim)
+
     def nbytes(self) -> int:
-        return int(self.vecs.nbytes)
+        return self.n * self.dim * self.itemsize
 
     @staticmethod
     def estimate_device_bytes(n: int, dim: int, itemsize: int,
@@ -115,20 +211,25 @@ class VecStore:
         if exact_store(metric, cfg):
             # exact store: the raw rows + the validity mask
             return (n * dim * itemsize) // max(ndev, 1) + n
-        if (6 * n * dim) // max(ndev, 1) > cfg.get("hbm_budget",
-                                                   1 << 62):
+        # one device: the bf16 store is allocated, and budgeted, at
+        # its capacity (`ensure` tests the same product)
+        rows = capacity_for(n) if ndev == 1 else n
+        if (6 * rows * dim) // max(ndev, 1) > cfg.get("hbm_budget",
+                                                      1 << 62):
             # int8 ranking store: rows (1 B/elem) + arow/x2 + valid
             return n * dim + 9 * n
         # bf16 rank + f32 full (6 B/elem) + per-row stats + valid
-        return (6 * n * dim) // max(ndev, 1) + 9 * n
+        return (6 * rows * dim) // max(ndev, 1) + 9 * rows
 
     def device_nbytes(self) -> int:
         """Estimated device-resident bytes for the budget ledger (the
         host mirror in `self.vecs` is serving-process memory, already
         accounted there)."""
-        n, dim = self.vecs.shape
+        if self.growable:
+            # what is allocated, whatever `n` has grown to since
+            return 6 * self.capacity * self.dim + 9 * self.capacity
         return self.estimate_device_bytes(
-            n, dim, self.vecs.dtype.itemsize, self.metric, self.cfg
+            self.n, self.dim, self.itemsize, self.metric, self.cfg
         )
 
     def ensure(self):
@@ -164,14 +265,13 @@ class VecStore:
         self.device_x2 = None
         x2 = norms = None
         if self.metric == "euclidean":
-            x2 = (xs.astype(np.float64) ** 2).sum(axis=1).astype(np.float32)
+            x2 = row_stat(xs, self.metric)
         elif self.metric == "cosine":
-            norms = np.maximum(
-                np.linalg.norm(xs.astype(np.float64), axis=1), 1e-30
-            ).astype(np.float32)
+            norms = row_stat(xs, self.metric)
         n, dim = xs.shape
         ndev = jax.device_count()
-        if (6 * n * dim) // max(ndev, 1) > self.cfg["hbm_budget"]:
+        cap = n if multi else capacity_for(n)
+        if (6 * cap * dim) // max(ndev, 1) > self.cfg["hbm_budget"]:
             # bf16 rank + f32 full (6 B/elem, per-chip share under a
             # mesh) won't fit HBM: int8 ranking store (1 B/elem); the
             # EXACT rescore of the oversampled candidates happens on the
@@ -219,12 +319,28 @@ class VecStore:
             )
             self.device_valid = shard_vec(self.mesh, valid, pad)
         else:
-            self.device_full = jnp.asarray(xs, dtype=jnp.float32)
-            if x2 is not None:
-                self.device_x2 = jnp.asarray(x2)
+            # the store that grows in place: every array at `cap` rows,
+            # padded HERE on the host (one transfer each, and no eager
+            # program specialised on `n`); the rows past `n` are
+            # invalid. One stat array whatever the metric, so that
+            # `append` is one program: x2 (zeros under dot), or norms
+            # (ones past `n`: the ranking copy divides by them).
+            def padded(a, fill=0):
+                out = np.full((cap,) + a.shape[1:], fill, a.dtype)
+                out[:n] = a
+                return out
+
+            self.device_full = jnp.asarray(
+                padded(np.asarray(xs, np.float32)))
             if norms is not None:
-                self.device_norms = jnp.asarray(norms)
-            self.device_valid = jnp.asarray(valid)
+                self.device_norms = jnp.asarray(padded(norms, 1.0))
+            else:
+                self.device_x2 = jnp.asarray(padded(
+                    x2 if x2 is not None else np.zeros(n, np.float32)))
+            self.device_valid = jnp.asarray(padded(valid))
+            self.capacity = cap
+            self.growable = True
+            self.vecs = self.valid = None
         if self.metric == "cosine":
             self.device_rank = (
                 self.device_full / self.device_norms[:, None]
@@ -257,7 +373,7 @@ class VecStore:
         # a query on a v5e). The mesh branches keep `h2d` / `d2h` where
         # they still transfer or copy apart.
         cfg = self.cfg
-        n = self.vecs.shape[0]
+        n = self.n
         qvs = np.ascontiguousarray(qvs, dtype=np.float32)
         b_total = qvs.shape[0]
         if self.mesh is not None:
@@ -270,7 +386,7 @@ class VecStore:
                     b_total, nloc, cfg["query_chunk"], cfg["score_budget"]
                 )
                 note_shape("sharded_rank_rescore",
-                           (self.vecs.shape, chunk, k, kc, self.metric))
+                           (self.shape, chunk, k, kc, self.metric))
                 d_parts = []
                 i_parts = []
                 for s in range(0, b_total, chunk):
@@ -294,7 +410,7 @@ class VecStore:
                 from surrealdb_tpu.parallel.mesh import sharded_knn
 
                 note_shape("sharded_knn",
-                           (self.vecs.shape, b_total, k, self.metric))
+                           (self.shape, b_total, k, self.metric))
                 with phase("h2d"):
                     qs = jnp.asarray(qvs)
                 with phase("device"):
@@ -318,7 +434,7 @@ class VecStore:
                 b_total, n, cfg["query_chunk"], cfg["score_budget"] // 2
             )
             note_shape("knn_rank_int8",
-                       (self.vecs.shape, chunk, kc, self.metric))
+                       (self.shape, chunk, kc, self.metric))
             qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
                 cand = np.asarray(topk.knn_rank_int8(
@@ -332,13 +448,18 @@ class VecStore:
             )
         if self.device_rank is not None:
             # oversampling absorbs bf16/approx-top-k ranking error AND
-            # tombstoned rows ranked into the candidate set
-            kc = min(n, max(2 * k, k + 16))
+            # tombstoned rows ranked into the candidate set. The
+            # program is keyed by the CAPACITY the arrays have, never
+            # by `n`: a store that grows keeps its programs (a slot
+            # the mask rules out comes back as inf and is dropped by
+            # the serving side, as a tombstone's is)
+            cap = self.capacity
+            kc = min(cap, max(2 * k, k + 16))
             bucket, chunk, _ = _pow2_chunks(
-                b_total, n, cfg["query_chunk"], cfg["score_budget"]
+                b_total, cap, cfg["query_chunk"], cfg["score_budget"]
             )
             note_shape("knn_rank_rescore",
-                       (self.vecs.shape, chunk, min(k, kc), kc,
+                       ((cap, self.dim), chunk, min(k, kc), kc,
                         self.metric))
             qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
@@ -357,7 +478,7 @@ class VecStore:
                 cfg["query_chunk"], cfg["score_budget"]
             )
             note_shape("exact_scan",
-                       (self.vecs.shape, chunk, k, self.metric))
+                       (self.shape, chunk, k, self.metric))
             qs_r = _chunked(qvs, bucket, chunk)
             with phase("device"):
                 parts = [np.asarray(topk.exact_scan(
@@ -366,6 +487,69 @@ class VecStore:
                 )) for r in range(rounds)]
             packed = parts[0] if rounds == 1 else np.concatenate(parts)
         return self._pairs(*topk.unpack_pairs(packed[:b_total]))
+
+    def append(self, rows: np.ndarray, row_numbers: np.ndarray,
+               flags: np.ndarray) -> bool:
+        """Writes a delta into the resident block, in place: `rows`
+        [m, D] at `row_numbers` [m] (distinct; new rows past `n`,
+        overwritten and tombstoned rows below it) with mask bits
+        `flags` [m]. One transfer in (the delta packed into one int32
+        array, padded to its ladder step), one donating program, and
+        nothing copied back: the next search's program waits for it on
+        the device. False, and nothing written, where the store does
+        not grow in place or a row number lies outside its capacity:
+        the caller ships the whole block again."""
+        if not self.growable:
+            return False
+        m = int(len(row_numbers))
+        top = int(row_numbers.max()) + 1 if m else 0
+        if top > self.capacity or (m and int(row_numbers.min()) < 0):
+            return False
+        # the stat from the rows as shipped (an f64 index's too), as
+        # `ensure` takes it; then the f32 rows the device keeps
+        rows = np.asarray(rows).reshape(m, self.dim)
+        stat = row_stat(rows, self.metric)
+        rows = np.ascontiguousarray(rows, np.float32)
+        packed = self._padding(m)
+        packed[:m, :self.dim] = rows.view(np.int32)
+        if stat is not None:
+            packed[:m, self.dim] = stat.view(np.int32)
+        packed[:m, self.dim + 1] = row_numbers
+        packed[:m, self.dim + 2] = np.asarray(flags, bool)
+        self._run_append(packed)
+        self.n = max(self.n, top)
+        return True
+
+    def warm_append(self, rows: int):
+        """Compiles (or loads) the append program of the ladder step
+        that holds `rows` rows, by a delta that is all padding."""
+        if self.growable:
+            self._run_append(self._padding(rows))
+
+    def _padding(self, m: int) -> np.ndarray:
+        """The packed delta of the ladder step that holds `m` rows,
+        every row number outside the store: the scatter drops them."""
+        packed = np.zeros((_append_bucket(m), self.dim + 3), np.int32)
+        packed[:, self.dim + 1] = self.capacity
+        return packed
+
+    def _run_append(self, packed: np.ndarray):
+        from surrealdb_tpu.device.kernelstats import note_shape, phase
+
+        cosine = self.metric == "cosine"
+        note_shape("vec_append", ((self.capacity, self.dim),
+                                  packed.shape[0], self.metric))
+        with phase("device"):
+            out = _append_program(self.metric)(
+                self.device_full, self.device_rank,
+                self.device_norms if cosine else self.device_x2,
+                self.device_valid, packed)
+        self.device_full, self.device_rank, stat_dev, \
+            self.device_valid = out
+        if cosine:
+            self.device_norms = stat_dev
+        else:
+            self.device_x2 = stat_dev
 
     def _pairs(self, dists, ids):
         return (

@@ -13,6 +13,12 @@ class RetryableKvError(SdbError):
     level, exactly like the reference's retryable TiKV errors."""
 
 
+class TxConflict(SdbError):
+    """A local store refused a commit: a key the transaction wrote was
+    committed by another after its snapshot (kvs/mem.py `CONFLICT_MSG`
+    is the message). Nothing of the transaction was applied or seen."""
+
+
 class QueryTimeout(SdbError):
     """The query ran past its deadline (statement TIMEOUT, the edge
     X-Surreal-Timeout budget, or the server default). The message keeps

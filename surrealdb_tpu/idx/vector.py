@@ -42,6 +42,9 @@ from surrealdb_tpu import cnf
 DEVICE_MIN_ROWS = cnf.KNN_DEVICE_MIN_ROWS
 # blockwise scan threshold (rows) to bound [B, N] materialization
 BLOCK_ROWS = cnf.KNN_BLOCK_ROWS
+# rows one delta to a resident block may carry (`vec_append`); a sync
+# gap that touched more ships the whole block, as every write did once
+DELTA_MAX_ROWS = 4096
 
 
 def _vec_dtype(params) -> type:
@@ -89,10 +92,11 @@ def vector_index_update(idef, rid: RecordId, before, after, ctx):
     if new_vec is None and old_vec is None:
         return
     # version allocation is process-atomic (ds.lock): concurrent writers
-    # can't collide on a log slot; a cancelled txn burns a version, which
-    # sync() detects as a log gap and resolves with a rebuild. The KV
-    # read happens BEFORE the lock — on a sharded store it is a remote
-    # round trip, and ds.lock must never be held across one.
+    # can't collide on a log slot; a cancelled txn burns a version. The
+    # datastore remembers which (`_note_version`): sync() steps over a
+    # gap it knows to be burnt, and resolves any other with a rebuild.
+    # The KV read happens BEFORE the lock — on a sharded store it is a
+    # remote round trip, and ds.lock must never be held across one.
     stored = ctx.txn.get_val(vkey) or 0
     with ctx.ds.lock:
         counters = getattr(ctx.ds, "_ix_versions", None)
@@ -102,6 +106,7 @@ def vector_index_update(idef, rid: RecordId, before, after, ctx):
         ckey = (ns, db, rid.tb, idef.name)
         ver = max(counters.get(ckey, 0), stored) + 1
         counters[ckey] = ver
+        _note_version(ctx.ds, ctx.txn, ckey, ver)
     log_key = K.ix_state(ns, db, rid.tb, idef.name, b"hl", K.enc_u64(ver))
     if new_vec is not None:
         ctx.txn.set_val(key, new_vec.tobytes())
@@ -110,6 +115,45 @@ def vector_index_update(idef, rid: RecordId, before, after, ctx):
         ctx.txn.delete(key)
         ctx.txn.set_val(log_key, ("del", rid.id, None))
     ctx.txn.set_val(vkey, ver)
+
+
+# burnt versions remembered an index, at most (a store that nobody
+# searches never reads them back; past this they are forgotten, and the
+# gap they leave is resolved by a rebuild, as every gap once was)
+BURNT_MAX = 4096
+
+
+def _note_version(ds, txn, ckey, ver: int):
+    """Book-keeping for the op log's gaps (caller holds `ds.lock`).
+    Writers of one index conflict on its version key, so of the
+    transactions that overlap one commits and the rest are cancelled:
+    a version that is allocated and not committed when a higher one is
+    (`open`), or whose transaction was cancelled (`burnt`), will never
+    be in the log. `_read_log` steps over exactly those; a gap of any
+    other origin (a log trimmed by another node's rebuild, a statement
+    rolled back inside a transaction that then committed) still forces
+    the rebuild."""
+    book = ds.__dict__.setdefault("_ix_gaps", {}).setdefault(
+        ckey, {"open": set(), "burnt": set()})
+    book["open"].add(ver)
+    mine = txn.__dict__.get("_ix_allocs")
+    if mine is not None:
+        mine.append((ckey, ver))
+        return
+    mine = txn._ix_allocs = [(ckey, ver)]
+
+    def settle(burnt: bool):
+        with ds.lock:
+            for key, v in mine:
+                b = ds._ix_gaps[key]
+                b["open"].discard(v)
+                if burnt:
+                    if len(b["burnt"]) >= BURNT_MAX:
+                        b["burnt"].clear()
+                    b["burnt"].add(v)
+
+    txn.on_commit(lambda: settle(False))
+    txn.on_cancel(lambda: settle(True))
 
 
 def _exact_mxu_distances(metric: str, xs, q):
@@ -304,6 +348,23 @@ class TpuVectorIndex:
         # bump re-ships them from the host arrays (KV truth)
         self._dev_key = f"vec/{uuid.uuid4().hex[:16]}"
         self._dev_epoch = 0
+        # the host arrays grow amortised: `vecs` / `valid` are views of
+        # the first rows of these buffers (None: the arrays are whole,
+        # as a rebuild or a caller's assignment leaves them)
+        self._vec_buf = None
+        self._valid_buf = None
+        # a resident block that grows in place (device/vecstore.py) is
+        # written by deltas: the tag this engine last brought the
+        # runner to, the rows it held then and the capacity it said it
+        # has (known only while the tag is), and the rows below that
+        # count overwritten or flipped since. `_drop_device` forgets
+        # them: then the whole block goes
+        self._dev_tag = None
+        self._dev_rows = 0
+        self._dev_capacity = None
+        # lint: mem-account(at most DELTA_MAX_ROWS row numbers: past that the whole block ships and the set is dropped)
+        self._dev_dirty: set = set()
+        self._dev_ship_lock = threading.Lock()
         self.rank_mode = None  # last runner-reported ranking mode
         # widest mesh the runner reported serving this engine's blocks
         # on (device/mesh.py; 1 or 0 = legacy single-device stores)
@@ -349,6 +410,10 @@ class TpuVectorIndex:
         # would silently change its answer, the one degradation the
         # governance layer must never produce
         self._pins = 0
+        # the pin count has a lock of its own: a search pins and unpins
+        # once each, and must not queue for that behind a sync that
+        # holds `self.lock` while it waits for the write lock
+        self._pin_lock = threading.Lock()
         # resource governance: every byte this engine derives from KV
         # truth is a tracked, evictable account — the host rows
         # (rebuild = one range scan on the next sync), the CAGRA
@@ -409,6 +474,9 @@ class TpuVectorIndex:
     # -- resource accounting ------------------------------------------------
 
     def _vec_mem_bytes(self) -> int:
+        buf = self._vec_buf
+        if buf is not None and self.vecs.base is buf:
+            return int(buf.nbytes) + int(self._valid_buf.nbytes)
         return int(self.vecs.nbytes) + int(self.valid.nbytes)
 
     def _ann_mem_bytes(self) -> int:
@@ -448,26 +516,31 @@ class TpuVectorIndex:
         # never observe the arrays vanish — eviction degrades speed,
         # NEVER answers. Called only from checkpoint sites that hold
         # none of this engine's locks.
-        with self.lock:
-            if self._pins > 0:
-                return  # actively serving: not evictable right now
-            with self.rw.write():
+        if self._pins > 0:
+            return  # actively serving: not evictable right now
+        with self.lock, self.rw.write():
+            with self._pin_lock:
+                if self._pins > 0:
+                    return  # pinned while this waited for the locks
+                # under the pin lock: a search that pins from here on
+                # finds the version gone and syncs behind this eviction
                 self.version = -1
-                self.rids = []
-                self.row_index = {}
-                self.vecs = np.zeros((0, self.dim), dtype=self.dtype)
-                self.valid = np.zeros(0, dtype=bool)
-                self._drop_device()
-                with self._ann_lock:
-                    self._ann = None
-                    self._ann_dirty = {}
-                    self._ann_dead = 0
-                    self._ann_dead_base = 0
-                    self._ann_gen += 1
-                    if self._ann_state == "ready":
-                        self._ann_state = "idle"
-                if self._segs is not None:
-                    self._segs.reset()
+            self.rids = []
+            self.row_index = {}
+            self.vecs = np.zeros((0, self.dim), dtype=self.dtype)
+            self.valid = np.zeros(0, dtype=bool)
+            self._vec_buf = self._valid_buf = None
+            self._drop_device()
+            with self._ann_lock:
+                self._ann = None
+                self._ann_dirty = {}
+                self._ann_dead = 0
+                self._ann_dead_base = 0
+                self._ann_gen += 1
+                if self._ann_state == "ready":
+                    self._ann_state = "idle"
+            if self._segs is not None:
+                self._segs.reset()
 
     # -- cache sync ---------------------------------------------------------
     def sync(self, ctx):
@@ -483,10 +556,15 @@ class TpuVectorIndex:
         self._mem_vec.touch()
         resource.checkpoint()
         ver0 = self.version
+        t0 = time.perf_counter_ns()
         try:
             self._sync_impl(ctx)
         finally:
             if self.version != ver0:
+                # stage `index_sync`: this searcher found the version
+                # moved and read the log, applied it or waited for the
+                # thread that did (inside its `index_knn`)
+                stage_record("index_sync", time.perf_counter_ns() - t0)
                 # the sync grew state (log apply / rebuild): settle
                 # with a fresh poll, same step-jump rationale as the
                 # ANN install
@@ -497,15 +575,41 @@ class TpuVectorIndex:
         ns, db, tb, ix = self.key
         vkey = K.ix_state(ns, db, tb, ix, b"vn")
         ver = ctx.txn.get_val(vkey) or 0
-        if ver == self.version:
+        have = self.version
+        if ver == have:
             return
-        with self.lock, self.rw.write():
+        behind = 0 <= ver < have
+        if behind and self._committed_version(ctx, vkey) >= have:
+            # this transaction's snapshot is older than what the cache
+            # holds, and what it holds is committed: serve that.
+            # Rebuilding back to the snapshot would take the rows away
+            # from the searches that already synced to them and wait to
+            # ride, acknowledged writes among them. Decided without the
+            # engine's locks (the caller is pinned: the arrays stay),
+            # so a search that began before a write does not queue
+            # behind the syncs of those that began after it
+            return
+        # `self.lock` makes the syncs take turns; the write lock, which
+        # has to wait for the dispatches in flight, is taken only to
+        # change the arrays: the searches that queued behind this sync
+        # find the version theirs and leave without it, and the log is
+        # read before it
+        with self.lock:
             if ver == self.version:
                 return
+            if not behind and 0 <= ver < self.version:
+                # another search's sync took the cache past this
+                # snapshot while this one waited its turn: as above
+                # (no store is asked under the lock)
+                return
             gap = ver - self.version
-            n = len(self.rids)
-            if self.version >= 0 and 0 < gap <= max(4096, n // 4):
-                if self._apply_log(ctx, self.version, ver):
+            entries = None
+            if self.version >= 0 and 0 < gap <= max(4096,
+                                                    len(self.rids) // 4):
+                entries = self._read_log(ctx, self.version, ver)
+            with self.rw.write():
+                if entries is not None:
+                    self._apply_entries(entries)
                     self.version = ver
                     frag = (
                         1.0 - self.live / len(self.valid)
@@ -514,18 +618,49 @@ class TpuVectorIndex:
                     )
                     if frag <= 0.25:
                         return
-            self._rebuild(ctx)
-            self.version = ver
+                self._rebuild(ctx)
+                self.version = ver
 
-    def _apply_log(self, ctx, from_ver, to_ver) -> bool:
+    @staticmethod
+    def _committed_version(ctx, vkey) -> int:
+        """The index version as committed now, by a read transaction
+        of its own (the caller's may hold an older snapshot)."""
+        txn = ctx.ds.transaction(write=False)
+        try:
+            return txn.get_val(vkey) or 0
+        finally:
+            txn.cancel()
+
+    def _read_log(self, ctx, from_ver, to_ver):
+        """The op-log entries of (from_ver, to_ver] for `_apply_entries`,
+        or None where the log is incomplete (e.g. trimmed): rebuild
+        instead."""
         ns, db, tb, ix = self.key
         beg = K.ix_state(ns, db, tb, ix, b"hl", K.enc_u64(from_ver + 1))
         end = K.ix_state(ns, db, tb, ix, b"hl", K.enc_u64(to_ver)) + b"\x00"
         entries = list(ctx.txn.scan_vals(beg, end))
-        if len(entries) != to_ver - from_ver:
-            return False  # log incomplete (e.g. trimmed) — rebuild instead
-        self._apply_entries([e for _k, e in entries])
-        return True
+        if len(entries) != to_ver - from_ver \
+                and not self._gaps_are_burnt(ctx, entries, from_ver, to_ver):
+            return None
+        return [e for _k, e in entries]
+
+    def _gaps_are_burnt(self, ctx, entries, from_ver, to_ver) -> bool:
+        """Whether every version in (from_ver, to_ver] that the log
+        lacks belongs to a transaction of this process that was
+        cancelled, or is still open below a committed version and so
+        will be (`_note_version`). The burnt ones passed are forgotten."""
+        ds = ctx.ds
+        book = getattr(ds, "_ix_gaps", {}).get(self.key)
+        if book is None:
+            return False
+        have = {int.from_bytes(k[-8:], "big") for k, _e in entries}
+        with ds.lock:
+            gone = book["burnt"] | book["open"]
+            ok = all(v in have or v in gone
+                     for v in range(from_ver + 1, to_ver + 1))
+            if ok:
+                book["burnt"] = {v for v in book["burnt"] if v > to_ver}
+        return ok
 
     def _apply_entries(self, entries):
         """Apply pre-fetched op-log entries [(op, idv, raw), ...] to the
@@ -536,6 +671,7 @@ class TpuVectorIndex:
         add_rows = []
         add_rids = []
         add_valid = []
+        touched = []  # rows below the old count, overwritten or flipped
         for op, idv, raw in entries:
             h = K.enc_value(idv)
             row = self.row_index.get(h)
@@ -546,6 +682,7 @@ class TpuVectorIndex:
                     if self.valid[row]:
                         self._ann_dead += 1
                         self._flip(row, False)
+                        touched.append(row)
                 else:
                     # the row was appended EARLIER IN THIS BATCH and is
                     # still in the pending buffers — dropping the
@@ -557,6 +694,7 @@ class TpuVectorIndex:
             vec = np.frombuffer(raw, dtype=self.dtype)
             if row is not None and row < len(self.vecs):
                 self.vecs[row] = vec
+                touched.append(row)
                 if not self.valid[row]:
                     self._flip(row, True)
                 # the ANN graph/int8 snapshot no longer matches this
@@ -576,16 +714,45 @@ class TpuVectorIndex:
                 add_rows.append(vec)
                 add_valid.append(True)
         if add_rows:
-            self.vecs = (
-                np.vstack([self.vecs, np.stack(add_rows)])
-                if len(self.vecs)
-                else np.stack(add_rows)
-            )
-            self.valid = np.concatenate(
-                [self.valid, np.asarray(add_valid, bool)]
-            )
+            self._grow_host(add_rows, add_valid)
             self.rids.extend(add_rids)
-        self._drop_device()
+        if self._dev_capacity is None:
+            self._drop_device()
+            return
+        # the runner holds a block that grows in place: keep what
+        # changed since the tag it holds, for the next dispatch to send
+        # as one delta (folded across syncs until then)
+        self._host_stats = None
+        self._dev_dirty.update(touched)
+        n = len(self.rids)
+        if n > self._dev_capacity or len(self._dev_dirty) \
+                + n - self._dev_rows > DELTA_MAX_ROWS:
+            self._drop_device()
+
+    def _grow_host(self, add_rows, add_valid):
+        """Appends rows to the host arrays without copying what is
+        there: `vecs` / `valid` are views of buffers with room to
+        spare, reallocated (a quarter larger) only when they are full
+        or when the arrays were assigned from outside. A view taken
+        before keeps its length (`_build_ann` relies on that)."""
+        n, m = len(self.vecs), len(add_rows)
+        need = n + m
+        buf, vbuf = self._vec_buf, self._valid_buf
+        if buf is None or self.vecs.base is not buf \
+                or self._valid.base is not vbuf or need > len(buf):
+            room = need + max(need // 4, 64)
+            buf = np.empty((room, self.dim), self.dtype)
+            buf[:n] = self.vecs
+            vbuf = np.zeros(room, bool)
+            vbuf[:n] = self._valid
+            self._vec_buf, self._valid_buf = buf, vbuf
+        for j, vec in enumerate(add_rows, n):
+            buf[j] = vec
+        vbuf[n:need] = add_valid
+        self.vecs = buf[:need]
+        # not through the setter: it would count the whole mask again
+        self._valid = vbuf[:need]
+        self.live += sum(add_valid)
 
     def _drop_device(self):
         """Invalidate the device-resident cache (host arrays are truth):
@@ -595,6 +762,39 @@ class TpuVectorIndex:
         self._dev_epoch += 1
         self.rank_mode = None
         self._host_stats = None
+        self._dev_tag = None
+        self._dev_capacity = None
+        self._dev_dirty = set()
+
+    def _ensure_device(self, sup, tag, loader):
+        """The runner holds this engine's block at `tag` when this
+        returns: already, by one delta (`vec_append`) where it holds
+        the tag the kept changes start from, else by the whole ship.
+        Callers hold the read lock, so the arrays and the changes stand
+        still; dispatches that arrive together send one delta."""
+        if self._dev_tag == tag:
+            sup.ensure_loaded(self._dev_key, tag, loader)
+            return
+        with self._dev_ship_lock:
+            delta = None
+            if self._dev_capacity is not None and self._dev_tag is not None \
+                    and self._dev_tag != tag:
+                delta = (self._dev_tag, self._delta_bufs)
+            # lint: lock-held(the ship lock serialises exactly this call, so that dispatches arriving together send one delta; bounded by the supervisor's load timeout and degrade circuit)
+            sup.ensure_loaded(self._dev_key, tag, loader, delta=delta)
+            self._dev_tag = list(tag)
+            self._dev_rows = len(self.rids)
+            self._dev_dirty = set()
+
+    def _delta_bufs(self):
+        """[rows, row numbers, mask bits] of what changed since
+        `_dev_tag`: the rows overwritten or flipped, then the rows
+        appended, as they are now."""
+        idx = np.asarray(
+            sorted(r for r in self._dev_dirty if r < self._dev_rows)
+            + list(range(self._dev_rows, len(self.rids))), np.int32)
+        return [np.ascontiguousarray(self.vecs[idx]), idx,
+                self.valid[idx].astype(np.uint8)]
 
     def _he_range(self) -> tuple[bytes, bytes, bytes]:
         """(prefix, begin, end) of this engine's element keyspace —
@@ -639,6 +839,7 @@ class TpuVectorIndex:
             np.stack(rows) if rows else np.zeros((0, self.dim), self.dtype)
         )
         self.valid = np.ones(len(rids), dtype=bool)
+        self._vec_buf = self._valid_buf = None
         self._drop_device()
         # a repack remaps row ids: the ANN snapshot (graph ids, dirty
         # rows, any build in flight) is void — discard and re-trigger;
@@ -713,7 +914,7 @@ class TpuVectorIndex:
         host-routed parts call the batched engine entry directly —
         paying the coalescer's condition dance per part per query
         measurably loses to one BLAS pass on CPU-routed stores."""
-        with self.lock:
+        with self._pin_lock:
             self._pins += 1  # pin: eviction must not race this search
         try:
             n = self.live
@@ -731,7 +932,7 @@ class TpuVectorIndex:
             with self.rw.read():
                 return self.knn_batch(np.asarray(qv)[None, :], k)[0]
         finally:
-            with self.lock:
+            with self._pin_lock:
                 self._pins -= 1
 
     def residency(self) -> dict:
@@ -1232,13 +1433,13 @@ class TpuVectorIndex:
         handled by oversample + host truthiness check + refill
         (SURVEY.md hard-parts: cond-filtered KNN)."""
         t0 = time.perf_counter_ns()
-        with self.lock:
+        with self._pin_lock:
             self._pins += 1  # pin: eviction must not race this query
         try:
             return self._knn(q, k, ctx, ef=ef, cond=cond,
                              cond_ctx=cond_ctx)
         finally:
-            with self.lock:
+            with self._pin_lock:
                 self._pins -= 1
             # wall time inside the index: the cache sync check, then
             # the batcher's `batch_wait` + `batch_ride` of this rider;
@@ -1454,8 +1655,6 @@ class TpuVectorIndex:
         from surrealdb_tpu.device import DeviceUnavailable, get_supervisor
 
         sup = get_supervisor()
-        n = len(self.rids)
-        tag = [int(self.version), int(self._dev_epoch)]
 
         def loader():
             return "vec_load", {
@@ -1470,12 +1669,32 @@ class TpuVectorIndex:
         qs32 = np.ascontiguousarray(qvs, dtype=np.float32)
         meta = bufs = None
         for _attempt in (0, 1):
-            sup.ensure_loaded(self._dev_key, tag, loader)
-            t, meta, bufs = sup.call(
-                "vec_knn",
-                {"key": self._dev_key, "tag": tag, "k": int(k)},
-                [qs32],
-            )
+            # what the answer is mapped by: the rows as the runner will
+            # have them when it serves this search
+            rids = self.rids
+            n = len(rids)
+            tag = [int(self.version), int(self._dev_epoch)]
+            self._ensure_device(sup, tag, loader)
+            # Once the search is in the runner's queue nothing below
+            # reads the host arrays of a block that grows in place
+            # (ids come back final, and are mapped by `rids` as
+            # captured: rows are only ever appended to that list): the
+            # read lock is lent until the reply is here, so that a
+            # write's sync does not wait out the round trip with every
+            # new search queued behind it. What a later dispatch sends
+            # is served after this search.
+            lent = []
+            try:
+                t, meta, bufs = sup.call(
+                    "vec_knn",
+                    {"key": self._dev_key, "tag": tag, "k": int(k)},
+                    [qs32],
+                    sent=(lambda: lent.append(self.rw.lend_read()))
+                    if self._dev_capacity is not None else None,
+                )
+            finally:
+                if lent and lent[0]:
+                    self.rw.reclaim_read()
             if t == "stale":
                 # runner evicted/restarted between load and query
                 sup.forget(self._dev_key)
@@ -1488,7 +1707,15 @@ class TpuVectorIndex:
         # stage `knn_post`: the host's share of this dispatch after the
         # RPC — the exact rescore of int8 candidates, ids -> record ids
         t_post = time.perf_counter_ns()
-        self.rank_mode = meta.get("rank_mode")
+        if tag[1] == self._dev_epoch:
+            # what the reply says of the block holds only while the
+            # runner has that block: a search whose lock was lent may
+            # come back after a sync dropped the device copy (a
+            # capacity step, a rebuild), and must not bring the old
+            # block's capacity back beside a tag that is gone
+            self.rank_mode = meta.get("rank_mode")
+            # a block that grows in place says so: deltas from here on
+            self._dev_capacity = meta.get("capacity")
         nd = int(meta.get("mesh_ndev", 1) or 1)
         if nd > self._dev_mesh:
             self._dev_mesh = nd
@@ -1519,7 +1746,7 @@ class TpuVectorIndex:
             dists, ids = bufs
             out = [
                 [
-                    (self.rids[int(i)], float(d))
+                    (rids[int(i)], float(d))
                     for d, i in zip(drow, irow)
                     if 0 <= i < n and np.isfinite(d)
                 ]

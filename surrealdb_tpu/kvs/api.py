@@ -491,9 +491,22 @@ class Transaction:
                 except Exception:
                     pass
 
+    def on_cancel(self, fn):
+        """Run `fn()` once this transaction is cancelled (a failed
+        commit ends in a cancel too): what it reserved outside the
+        keyspace is given back there."""
+        if not hasattr(self, "_cancel_hooks"):
+            self._cancel_hooks = []
+        self._cancel_hooks.append(fn)
+
     def cancel(self):
         if not self.closed:
             self.btx.cancel()
             self.closed = True
             if hasattr(self, "_commit_hooks"):
                 self._commit_hooks = []
+            for fn in self.__dict__.pop("_cancel_hooks", ()):
+                try:
+                    fn()
+                except Exception:
+                    pass

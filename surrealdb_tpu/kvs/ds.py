@@ -162,6 +162,10 @@ class Datastore:
         # cross-transaction caches / engines
         self.lock = threading.RLock()
         self.vector_indexes: dict = {}  # (ns,db,tb,ix) -> TpuVectorIndex
+        # held, for a bounded time, by an auto-commit statement that lost
+        # its commit to another writer while it runs again
+        # (exec/executor.py CONFLICT_RETRIES)
+        self.retry_floor = threading.Lock()
         self.index_builds: dict = {}  # (ns,db,tb,ix) -> building status
         self.ft_indexes: dict = {}  # (ns,db,tb,ix) -> FullTextIndex
         # live subscriptions, indexed by (ns,db,tb) — the write path

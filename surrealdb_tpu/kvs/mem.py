@@ -22,7 +22,7 @@ try:
 except ImportError:  # container lacks the dep — pure-Python fallback
     from surrealdb_tpu.utils.sortedcompat import SortedDict, SortedList
 
-from surrealdb_tpu.err import SdbError
+from surrealdb_tpu.err import SdbError, TxConflict
 from surrealdb_tpu.kvs.api import Backend, BackendTx
 
 CONFLICT_MSG = (
@@ -126,7 +126,7 @@ class VersionedStore:
                release: bool = True) -> int:
         """Validate + apply a writeset. Returns the new version.
 
-        Raises SdbError(CONFLICT_MSG) when any written key was committed by
+        Raises TxConflict(CONFLICT_MSG) when any written key was committed by
         another transaction after `snap`. `pre_apply` (e.g. a WAL append)
         runs under the store lock after validation passes, so durability and
         visibility stay atomic. With `release`, the committer's own snapshot
@@ -141,7 +141,7 @@ class VersionedStore:
                 if chain is not None and chain[-1][0] > snap:
                     if release:
                         self._release_locked(snap)
-                    raise SdbError(CONFLICT_MSG)
+                    raise TxConflict(CONFLICT_MSG)
             if release:
                 self._release_locked(snap)
             if pre_apply is not None:

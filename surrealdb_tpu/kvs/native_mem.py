@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from surrealdb_tpu.err import SdbError
+from surrealdb_tpu.err import SdbError, TxConflict
 from surrealdb_tpu.kvs.api import Backend, BackendTx
 from surrealdb_tpu.kvs.mem import CONFLICT_MSG
 from surrealdb_tpu.native import NativeMemtable
@@ -104,7 +104,7 @@ class NativeMemTx(BackendTx):
         # one mutex hold on the C++ side (see sdb_commit_batch)
         ver = self.store.table.commit_batch(snap, self.writes.items())
         if not ver:
-            raise SdbError(CONFLICT_MSG)
+            raise TxConflict(CONFLICT_MSG)
 
     def cancel(self):
         self.done = True

@@ -286,9 +286,10 @@ def test_an_exact_store_keeps_f32_rows_alone(one_device, metric):
     assert st.rank_mode is None and st.device_vecs.dtype == np.float32
     assert st.device_nbytes() == VecStore.estimate_device_bytes(
         300, 24, 4, metric, cfg, 1) == 300 * 24 * 4 + 300
-    # without the flag the same rows are a bf16 rank + f32 rescore store
+    # without the flag the same rows are a bf16 rank + f32 rescore store,
+    # allocated for the capacity it grows in (768 rows at 300)
     assert VecStore.estimate_device_bytes(300, 24, 4, metric, STORE_CFG, 1) \
-        == 6 * 300 * 24 + 9 * 300
+        == 6 * 768 * 24 + 9 * 768
     meta, (dists, ids) = st.knn(xs[:3], 5)
     assert meta == {"mode": "pairs", "rank_mode": None}
     assert ids[:, 0].tolist() == [0, 1, 2] or metric == "dot"

@@ -30,6 +30,22 @@ CORPUS = [0, 1, 2, 3, 5, 7, 11, 13, 17, 19, 22, 23, 29, 31, 37, 41,
           55, 77, 101, 137]
 
 
+@pytest.fixture(autouse=True)
+def collect_between_simulations():
+    """A transaction that a finished simulation left open cancels
+    itself when it is collected (`kvs/shard.py ShardTx.__del__`), over
+    the simulated network. Collected in the middle of the NEXT
+    simulation's scheduling step, that finalizer waits for the kernel's
+    mutex in the thread that holds it, and the seed ends at its 300 s
+    wall-clock watchdog: the flake of `test_seed_corpus[19]` in whole
+    runs (PR 33 caught its stack). What one simulation leaves behind
+    is collected here, before the next begins."""
+    import gc
+
+    gc.collect()
+    yield
+
+
 def _small():
     return SimConfig(groups=2, members=3, spare_groups=0, clients=4,
                      ops_per_client=10, splits=0)

@@ -258,13 +258,11 @@ def test_mutation_lock_cycle_turns_gate_red(tree_copy_base):
 def test_mutation_blocking_under_lock_turns_gate_red(tree_copy_base):
     root = _mutate(
         tree_copy_base, "m_block", "surrealdb_tpu/idx/vector.py",
-        "        with self.lock:\n"
-        "            if self._pins > 0:\n"
-        "                return  # actively serving: not evictable right now\n",
-        "        with self.lock:\n"
+        "        with self.lock, self.rw.write():\n"
+        "            with self._pin_lock:\n",
+        "        with self.lock, self.rw.write():\n"
         "            _time.sleep(0.01)\n"
-        "            if self._pins > 0:\n"
-        "                return  # actively serving: not evictable right now\n",
+        "            with self._pin_lock:\n",
     )
     rep = staticlint.run(root)
     hits = [f for f in rep.findings if f.rule == "lock-held"]

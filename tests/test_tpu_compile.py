@@ -62,3 +62,59 @@ def test_exact_scan_reads_a_million_rows_in_place(one_chip, no_compile_cache,
     assert mem.argument_size_in_bytes >= n * dim * 4
     assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
     assert mem.output_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("metric,rows", [("euclidean", 1), ("euclidean", 1024),
+                                         ("cosine", 4)])
+def test_vec_append_writes_in_place_at_the_cells_capacity(
+        one_chip, no_compile_cache, metric, rows):
+    """`exact128rw`'s write at the capacity 100,000 rows are allocated
+    (106,496 x 128): the donating scatter compiles for one v5e chip under
+    the name the benchmark's roofline reader looks up, every output
+    aliases its argument (no second copy of the 82 MB block), and it needs
+    next to nothing beside them."""
+    import jax
+    import jax.numpy as jnp
+
+    from surrealdb_tpu.device.vecstore import _append_program, capacity_for
+
+    cap, dim = capacity_for(100_000), 128
+    assert cap == 106_496
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = _append_program(metric).lower(
+        arr((cap, dim), jnp.float32), arr((cap, dim), jnp.bfloat16),
+        arr((cap,), jnp.float32), arr((cap,), jnp.bool_),
+        arr((rows, dim + 3), jnp.int32))
+    assert "module @jit_vec_append" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    block = cap * dim * 6 + cap * 5
+    assert mem.argument_size_in_bytes >= block
+    assert mem.alias_size_in_bytes >= block
+    assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
+
+
+def test_knn_rank_rescore_compiles_at_the_cells_capacity(one_chip,
+                                                         no_compile_cache):
+    """The search program over the allocated rows (106,496, a multiple
+    of 8,192) at the 32-rider bucket: compiles for one v5e chip, its
+    score block [32, 106,496] f32 and little else beside the arguments."""
+    import jax
+    import jax.numpy as jnp
+
+    from surrealdb_tpu.ops import topk
+
+    cap, dim, k, kc = 106_496, 128, 10, 26
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = topk.knn_rank_rescore.lower(
+        arr((cap, dim), jnp.bfloat16), arr((cap, dim), jnp.float32),
+        arr((1, 32, dim), jnp.float32), k, kc, "euclidean",
+        arr((cap,), jnp.float32), None, arr((cap,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes < 1 << 16  # [1, 32, 2k] int32, tiled

@@ -42,6 +42,11 @@ def make_store(branch, rows, live=None):
     st = VecStore(f"t/{branch}/{rows}", xs, valid, metric, 3.0, cfg)
     st.ensure()
     assert st.rank_mode == {"bf16": "bf16", "int8": "int8"}.get(branch)
+    # the bf16 store grows in place: it is allocated for more rows than
+    # it holds, and lets go of the rows it was shipped
+    assert st.growable == (branch == "bf16") and st.shape == (rows, DIM)
+    assert (st.vecs is None) == st.growable and st.capacity >= rows
+    st.xs = xs
     return st
 
 
@@ -53,7 +58,8 @@ def by_hand(st, qvs, k):
     from surrealdb_tpu.device.vecstore import _pow2_chunks
     from surrealdb_tpu.ops import topk
 
-    n, b = st.vecs.shape[0], qvs.shape[0]
+    # the programs see the rows the arrays are allocated for
+    n, b = st.capacity, qvs.shape[0]
     if st.rank_mode is None:
         dists, ids = topk.knn_search(
             st.device_vecs, jnp.asarray(qvs), k, st.metric, st.mink_p,
@@ -92,7 +98,7 @@ def test_knn_is_the_kernel_on_the_host_padded_batch(one_device, branch,
     # 6 live rows under k = 10: every answer also holds masked-out rows
     st = make_store(branch, rows=300, live=6)
     rng = np.random.default_rng(riders)
-    qvs = st.vecs[rng.integers(0, 6, riders)] \
+    qvs = st.xs[rng.integers(0, 6, riders)] \
         + rng.normal(size=(riders, DIM)).astype(np.float32) * 0.01
     meta, bufs = st.knn(qvs, K)
     want_meta, want = by_hand(st, qvs, K)
@@ -178,7 +184,7 @@ def test_a_bucket_compiles_one_program_and_a_rider_count_none(
     compiled_at = []
     for b in range(1, 33):
         seen = dict(by_fn)
-        st.knn(st.vecs[:b], K)
+        st.knn(st.xs[:b], K)
         if by_fn != seen:
             compiled_at.append(b)
     assert compiled_at == [1, 2, 3, 5, 9, 17]
@@ -186,7 +192,7 @@ def test_a_bucket_compiles_one_program_and_a_rider_count_none(
     assert new == {kernel}, new
     after = dict(by_fn)
     for b in range(1, 33):
-        st.knn(st.vecs[:b], K)
+        st.knn(st.xs[:b], K)
     assert by_fn == after
 
 
@@ -204,7 +210,7 @@ def test_f32_search_compiles_nothing_beside_its_kernel(one_device):
     compiled_at = []
     for b in range(1, 10):
         seen = dict(by_fn)
-        st.knn(st.vecs[:b], K)
+        st.knn(st.xs[:b], K)
         if by_fn != seen:
             compiled_at.append(b)
     # `query_chunk` is 8 here: 9 riders are two rounds of the 8-program
@@ -222,7 +228,7 @@ def test_the_fault_hook_still_plants_its_kc(one_device, monkeypatch):
     real = topk.knn_rank_rescore
     assert real.__name__ == "knn_rank_rescore"
     st = make_store("bf16", rows=300)
-    qvs = st.vecs[:3]
+    qvs = st.xs[:3]
     _meta, (dists, ids) = st.knn(qvs, K)  # kc = 26, unplanted
     assert dists.shape == ids.shape == (3, K)
     calls = []
