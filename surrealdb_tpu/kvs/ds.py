@@ -276,6 +276,11 @@ class Datastore:
         self.telemetry.register_counter(
             "ft_cache_evictions", lambda: self._ft_cache.evictions
         )
+        # record-id texts built, not served from the object's slot
+        # (val.RecordId.render): process-wide, like the stage table
+        from surrealdb_tpu.val import rid_renders as _rid_renders
+
+        self.telemetry.register_counter("rid_renders", _rid_renders)
         # index-serving shard count across all sharded vector indexes
         # (0 on unsharded stores; pairs with the knn_shard_fanout /
         # knn_partial_results / knn_hedged_dispatches counters)

@@ -57,6 +57,23 @@ def parse_timeout(raw) -> float:
     return v
 
 
+def _encode_reply(encode, payload) -> bytes:
+    """The reply's bytes, `encode(payload)`, under stage `reply_encode`:
+    once a reply that holds the program's values, inside `request`.
+    Wall time only: its CPU time is in `request`'s, and a read of the
+    thread's CPU clock is a system call (7 us on the chip's host, where
+    two more a request cost the KNN cells 3 % of their `qps`: PERF.md
+    §6, PR 34)."""
+    t0 = time.perf_counter_ns()
+    body = encode(payload)
+    stage_record("reply_encode", time.perf_counter_ns() - t0)
+    return body
+
+
+def _json_bytes(payload) -> bytes:
+    return json.dumps(to_json(payload)).encode()
+
+
 class _AuthFailed(Exception):
     """Bearer token rejected — maps to HTTP 401."""
 
@@ -103,6 +120,11 @@ class SurrealHandler(BaseHTTPRequestHandler):
     def _json(self, code: int, payload):
         self._send(code, json.dumps(payload).encode(), "application/json")
 
+    def _json_values(self, code: int, payload):
+        """`_json` for a payload that holds the program's values."""
+        self._send(code, _encode_reply(_json_bytes, payload),
+                   "application/json")
+
     def _text(self, code: int, text: str, ctype="text/plain"):
         self._send(code, text.encode(), ctype)
 
@@ -143,12 +165,13 @@ class SurrealHandler(BaseHTTPRequestHandler):
         return s
 
     def _run_sql(self, sql: str, sess: Session, vars=None):
+        """The statements' results as values: for `_json_values`."""
         res = self.ds.execute(sql, session=sess, vars=vars or {})
         out = []
         for r in res:
             row = {
                 "status": "OK" if r.ok else "ERR",
-                "result": to_json(r.result) if r.ok else r.error,
+                "result": r.result if r.ok else r.error,
                 "time": f"{r.time_ns / 1e6:.3f}ms",
             }
             if getattr(r, "partial", None):
@@ -409,7 +432,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             sess = self._session()
             sql = self._body().decode()
             try:
-                self._json(200, self._run_sql(sql, sess))
+                self._json_values(200, self._run_sql(sql, sess))
             except SdbError as e:
                 self._json(400, {"error": str(e)})
             return
@@ -434,7 +457,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
         if path == "/import":
             sess = self._session()
             sql = self._body().decode()
-            self._json(200, self._run_sql(sql, sess))
+            self._json_values(200, self._run_sql(sql, sess))
             return
         if path == "/signin":
             from surrealdb_tpu.iam import signin
@@ -467,19 +490,20 @@ class SurrealHandler(BaseHTTPRequestHandler):
             fmt_out = "cbor" if "cbor" in accept else (
                 "fb" if "flatbuffers" in accept else "json"
             )
-            rich_out = fmt_out != "json"
 
             def respond(payload):
                 if fmt_out == "cbor":
                     from surrealdb_tpu import wire
 
-                    self._send(200, wire.encode(payload), "application/cbor")
+                    self._send(200, _encode_reply(wire.encode, payload),
+                               "application/cbor")
                 elif fmt_out == "fb":
                     from surrealdb_tpu import fb
 
-                    self._send(200, fb.encode(payload), fb.MIME)
+                    self._send(200, _encode_reply(fb.encode, payload),
+                               fb.MIME)
                 else:
-                    self._json(200, payload)
+                    self._json_values(200, payload)
 
             req = {}
             try:
@@ -501,10 +525,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
                 rs = RpcSession(self.ds, anon_level=self.anon_level)
                 rs.session = self._session()
                 out = rs.handle(req.get("method", ""), req.get("params") or [])
-                respond({
-                    "id": req.get("id"),
-                    "result": out if rich_out else to_json(out),
-                })
+                respond({"id": req.get("id"), "result": out})
             except RpcError as e:
                 respond({"id": req.get("id"),
                          "error": {"code": e.code, "message": str(e)}})
@@ -525,7 +546,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     self.ds, sess, req.get("query", ""),
                     req.get("variables") or {},
                 )
-                self._json(200, to_json(out))
+                self._json_values(200, out)
             except SdbError as e:
                 self._json(200, {"errors": [{"message": str(e)}]})
             return
@@ -598,7 +619,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             sql = f"UPDATE {target} MERGE $data"
         else:
             sql = f"DELETE {target} RETURN BEFORE"
-        self._json(200, self._run_sql(sql, sess, vars))
+        self._json_values(200, self._run_sql(sql, sess, vars))
 
     # -- websocket ----------------------------------------------------------
     def _ws_upgrade(self):
@@ -697,6 +718,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             pack = json.dumps
             unpack = lambda data: json.loads(data.decode())
             jsonify = to_json
+        encode = lambda payload: pack(jsonify(payload))  # a request's reply
 
         # live-query notification push: the session actor is read/write
         # split (reference rpc/websocket.rs:47) — THIS thread only reads
@@ -801,8 +823,8 @@ class SurrealHandler(BaseHTTPRequestHandler):
                         self.ds.inflight.close(handle)
                         if ticket is not None:
                             ticket.release()
-                    self._ws_send(pack(
-                        {"id": rid, "result": jsonify(out)}
+                    self._ws_send(_encode_reply(
+                        encode, {"id": rid, "result": out}
                     ))
                     stage_record("request", time.perf_counter_ns() - t0,
                                  time.thread_time_ns() - cpu0)

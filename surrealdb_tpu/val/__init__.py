@@ -331,14 +331,42 @@ class Table:
         return f"Table({self.name})"
 
 
-class RecordId:
-    """A record pointer `table:id`. id may be int, str, Uuid, list or dict."""
+# texts built by `RecordId.render` (not served from the slot): against the
+# ids a server sends, how often the kept text engages. Counted on the slow
+# path alone, lock-free as `telemetry.StageStat` (a race loses one count);
+# the datastore registers it as counter `rid_renders` (kvs/ds.py).
+_rid_renders = 0
 
-    __slots__ = ("tb", "id")
+
+def rid_renders() -> int:
+    return _rid_renders
+
+
+class RecordId:
+    """A record pointer `table:id`. id may be int, str, Uuid, list or dict.
+
+    `_text` is the rendered `table:id`, kept by the first `render()` where
+    the id is exactly an `int` or a `str`: those cannot change under it,
+    and `tb` / `id` are assigned nowhere after `__init__`. Any other id
+    (a list id can be mutated in place) is rendered on every call. The
+    same objects are sent again and again (`graph/csr.py _rid_cache`: one
+    a node for the life of the graph), so a reply's ids cost one slot
+    read each. Equality, hash and the key encoders never read it."""
+
+    __slots__ = ("tb", "id", "_text")
 
     def __init__(self, tb: str, id):
         self.tb = tb
         self.id = id
+        self._text = None
+
+    def __setstate__(self, state):
+        # `copy` and `pickle` make the object without `__init__`, and a
+        # stored pickle from before the slot existed has no text to give
+        slots = state[1]
+        self.tb = slots["tb"]
+        self.id = slots["id"]
+        self._text = None
 
     def __eq__(self, other):
         return (
@@ -354,7 +382,14 @@ class RecordId:
         return f"RecordId({self.render()})"
 
     def render(self) -> str:
-        return f"{escape_rid_table(self.tb)}:{render_record_id_key(self.id)}"
+        global _rid_renders
+        text = self._text
+        if text is None:
+            _rid_renders += 1
+            text = f"{escape_rid_table(self.tb)}:{render_record_id_key(self.id)}"
+            if type(self.id) in (int, str):
+                self._text = text
+        return text
 
 
 class Range:
@@ -992,6 +1027,22 @@ def render(v, pretty: bool = False, _depth: int = 0) -> str:
 
 
 def to_json(v):
+    # the exact types that make up nearly all of a reply, before the
+    # ladder: a 1,000-id answer walked fourteen `isinstance` rungs and
+    # built a text per id. Subclasses (`bool` is an `int`, an `IntEnum`,
+    # a `dict` or `list` subclass), NONE and every rare type fall through
+    # to the rung they always reached, so the result is the ladder's.
+    t = type(v)
+    if t is RecordId:
+        return v.render()
+    if t is list:
+        # a record id gives its kept text without a call
+        return [(x._text or x.render()) if type(x) is RecordId
+                else to_json(x) for x in v]
+    if t is dict:
+        return {k: to_json(x) for k, x in v.items()}
+    if t is str or t is float or t is int or t is bool:
+        return v
     if v is NONE:
         return None
     if v is None:

@@ -407,6 +407,10 @@ def test_request_carries_cpu_time_and_no_other_stage_does(ds):
         assert 0 < inner <= ns
         cpu_ms = snap["request"]["cpu_ms"] - cpu0
         assert 0 < cpu_ms <= ns / 1e6 + 3 * 0.01  # clock resolution
+        # ... and the reply's encoding, once a reply (wall time only: a
+        # read of the thread's CPU clock is a system call)
+        enc_count, enc_ns = added(t0, "reply_encode")
+        assert enc_count == 3 and 0 < enc_ns <= ns
         assert [k for k, v in snap.items() if "cpu_ms" in v] == ["request"]
         assert all("last_us" not in v for v in snap.values())
         # /metrics and INFO FOR SYSTEM read the same table

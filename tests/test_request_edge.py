@@ -367,13 +367,16 @@ def test_ending_a_span_drops_the_children_left_open():
 
 def test_server_close_stops_the_watcher_once():
     def watchers():
-        return sum(t.name == "surreal-hangup-watch"
-                   for t in threading.enumerate())
+        return {t for t in threading.enumerate()
+                if t.name == "surreal-hangup-watch"}
 
+    # by identity, not by count: the watcher of the test before may
+    # still be on its way out while this server starts its own
     before = watchers()
     srv = make_server(Datastore("memory"), "127.0.0.1", 0,
                       unauthenticated=True)
-    assert watchers() == before + 1
+    mine = watchers() - before
+    assert len(mine) == 1
     srv.server_close()
     srv.server_close()  # the stop descriptor is written once
-    assert _until(lambda: watchers() == before)
+    assert _until(lambda: not mine & watchers())

@@ -6,15 +6,18 @@ import base64
 import hashlib
 import json
 import os
+import re
 import socket
 import struct
 import threading
+import time
 import urllib.request
 
 import pytest
 
 from surrealdb_tpu import Datastore
 from surrealdb_tpu.server import make_server
+from surrealdb_tpu.telemetry import stage_snapshot
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +90,72 @@ def test_http_rpc(server):
                 {"surreal-ns": "t", "surreal-db": "t"})
     out = json.loads(b)
     assert out["result"][0]["result"] == 42
+
+
+def _stage(name):
+    st = stage_snapshot().get(name) or {}
+    return st.get("count", 0), st.get("total_ms", 0.0)
+
+
+def _stages_after(before, requests):
+    """What `requests` replies added to `request` and `reply_encode`
+    (`request` closes a moment after its client has the reply)."""
+    end = time.monotonic() + 5
+    while (_stage("request")[0] < before["request"][0] + requests
+           and time.monotonic() < end):
+        time.sleep(0.005)
+    return {k: tuple(a - b for a, b in zip(_stage(k), v))
+            for k, v in before.items()}
+
+
+_IDS_SQL = ("SELECT VALUE ->follows->account->follows->account "
+            "FROM type::record('account', $i)")
+_IDS_BODY = ('[{"status": "OK", "result": '
+             '[["account:3", "account:`a b`", "account:3"]], "time": "T"}]')
+
+
+@pytest.mark.parametrize("route", ["rpc", "sql", "ws"])
+def test_reply_of_record_ids_is_encoded_once_inside_request(server, route):
+    ds, base, port = server
+    ns = f"enc_{route}"
+    hdrs = {"surreal-ns": ns, "surreal-db": ns}
+    ds.execute(
+        "DEFINE TABLE follows TYPE RELATION; "
+        "CREATE account:1, account:2, account:3, account:`a b`; "
+        "RELATE account:1->follows:1->account:2; "
+        "RELATE account:1->follows:2->account:`a b`; "
+        "RELATE account:2->follows:3->account:3; "
+        "RELATE account:2->follows:4->account:`a b`; "
+        "RELATE account:`a b`->follows:5->account:3",
+        ns=ns, db=ns)
+    before = {k: _stage(k) for k in ("request", "reply_encode")}
+    requests = 1
+    if route == "rpc":
+        body = json.dumps({"id": 7, "method": "query",
+                           "params": [_IDS_SQL, {"i": 1}]}).encode()
+        _s, raw = _req(base + "/rpc", "POST", body, hdrs)
+        want = '{"id": 7, "result": ' + _IDS_BODY + '}'
+    elif route == "sql":
+        _s, raw = _req(base + "/sql", "POST",
+                       _IDS_SQL.replace("$i", "1").encode(), hdrs)
+        want = _IDS_BODY
+    else:
+        ws = WsClient(port)
+        try:
+            ws.call("use", [ns, ns])               # a request of its own
+            raw = json.dumps(
+                ws.call("query", [_IDS_SQL, {"i": 1}])).encode()
+        finally:
+            ws.close()
+        requests = 2
+        want = '{"id": 2, "result": ' + _IDS_BODY + '}'
+    # the reply's bytes are what they were before the ids kept their text
+    assert re.sub(r'"time": "[0-9.]+ms"', '"time": "T"', raw.decode()) == want
+    added = _stages_after(before, requests)
+    count, wall_ms = added["reply_encode"]
+    assert count == requests and wall_ms > 0
+    req_count, req_wall_ms = added["request"]
+    assert req_count == requests and wall_ms <= req_wall_ms
 
 
 class WsClient:
