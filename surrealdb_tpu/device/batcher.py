@@ -230,7 +230,8 @@ class DeviceBatcher:
                 with self.cond:
                     while not slot[2]:
                         self.cond.wait(0.05)
-        stage_record("batch_wait", slot[3] - t_enq)
+        # written down after the ride: the wait ended at the grab
+        stage_record("batch_wait", slot[3] - t_enq, end_ns=slot[3])
         stage_record("batch_ride", time.monotonic_ns() - slot[3])
         if slot[1] is not None:
             raise slot[1]

@@ -25,7 +25,10 @@ when the request was read and decoded, `ready` just before the reply
 is sent, and the nanoseconds spent in the phases the op timed (`h2d`,
 `device`, `d2h`; kernelstats.phase). The same spans go into the
 profiler's trace as `runner:<op>`, `runner:<phase>` and `runner:idle`
-(blocked in recv).
+(blocked in recv); `runner:<op>` carries `seq` and `t_recv`, that
+`recv` stamp, so whoever reads the trace can lay the profiler's clock
+over CLOCK_MONOTONIC and the device plane beside the serving process's
+`host_stages.json` (DeviceSupervisor.profile).
 
 The loop is deliberately single-threaded and crash-only: any internal
 corruption is allowed to kill the process — the supervisor restarts it
@@ -117,7 +120,7 @@ def serve(sock) -> None:
             return
         seq = current["seq"] = meta.get("seq")
         try:
-            with TraceAnnotation("runner:" + op, seq=seq):
+            with TraceAnnotation("runner:" + op, seq=seq, t_recv=t_recv):
                 tag, out_meta, out_bufs = host.handle(op, meta, bufs)
             out_meta = dict(out_meta)
             out_meta["seq"] = seq

@@ -157,7 +157,10 @@ class DeviceSupervisor:
         self._proc: Optional[subprocess.Popen] = None
         self._sock: Optional[socket.socket] = None
         self._send_q: Optional[queue.Queue] = None
-        self._pending: dict = {}  # seq -> [Event, reply|None]
+        # seq -> [Event, reply|None, t_got, t_sent, t_in]: the three
+        # stamps of the threads that hand the call on (`_send_loop`,
+        # `_recv_loop`; time.monotonic_ns, None until stamped)
+        self._pending: dict = {}
         self._seq = 0
         self._loaded: dict = {}  # cache key -> tag (current runner gen)
         self._probe_thread: Optional[threading.Thread] = None
@@ -256,7 +259,8 @@ class DeviceSupervisor:
         (degrade to host), DeviceOpError (this op failed), or SdbError
         (mode=require and the device can't serve). Wall time lands in
         the `device_rpc` stage stat, and a live runner's reply cuts it
-        into six more (`_record_rpc_parts`). `sent()`, if given, runs
+        into six more and the two hand-offs among them into five
+        (`_record_rpc_parts`). `sent()`, if given, runs
         once the request is in the runner's queue, which the runner
         serves in order: whatever is sent after it finds this op done.
         An inline host runs the op in the caller's thread and never
@@ -576,6 +580,12 @@ class DeviceSupervisor:
             return kernelstats.snapshot()
         return dict(self.compile_counts)
 
+    def pending_calls(self) -> int:
+        """Dispatches sent (or queued to send) and not yet answered.
+        Takes no lock, so the stall watch can ask while some thread
+        holds `_lock` for good."""
+        return len(self._pending)
+
     def runner_pid(self) -> Optional[int]:
         p = self._proc
         return p.pid if p is not None else None
@@ -601,24 +611,55 @@ class DeviceSupervisor:
         Blocks the caller for the window plus the time `stop_trace`
         takes to write it; dispatches queued behind the start or the
         stop wait for it (they are not timed out as a wedge) and see
-        its seconds in their `rpc_out`. Returns {dir, window_s,
-        stop_s}."""
-        started = self._profile_call({"action": "start", "dir": dir})
-        stopped = None
+        its seconds in their `rpc_out`.
+
+        The same window of THIS process: while it is open every stage
+        record keeps its interval (telemetry.timeline_arm), written as
+        `dir`/host_stages.json (`{clock, window_ns, stages: [[stage,
+        thread id, start_ns, end_ns], ...]}`) on CLOCK_MONOTONIC, the
+        clock of each `runner:<op>` span's `t_recv` in the trace.
+        Returns {dir, window_s, stop_s, host_stages, runner_busy_s,
+        runner_idle_s, runner_idle_by}: the seconds of the window in
+        which the runner held no op, by what this process was doing
+        then (device/idle.py)."""
+        import json
+
+        from surrealdb_tpu import telemetry
+        from surrealdb_tpu.device.idle import runner_idle_by
+
+        # armed before the start and disarmed after the stop: a call
+        # records when its waiter runs again, which may be after the
+        # window's edge
+        telemetry.timeline_arm()
+        started = stopped = None
         try:
+            started = self._profile_call({"action": "start", "dir": dir})
             time.sleep(max(float(seconds), 0.0))
             stopped = self._profile_call({"action": "stop"})
         finally:
-            if stopped is None:
+            timeline = telemetry.timeline_disarm()
+            if started is not None and stopped is None:
                 # interrupted, or the stop itself failed: a trace left
                 # open would make every later `start_trace` raise
                 try:
                     self._profile_call({"action": "stop"})
                 except Exception:
                     pass  # runner gone or restarting: no trace left
+        w0 = int(started["started"] * 1e9)
+        w1 = int(stopped["stopping"] * 1e9)
+        idle = runner_idle_by(timeline, w0, w1)
+        path = os.path.join(dir, "host_stages.json")
+        os.makedirs(dir, exist_ok=True)
+        with open(path, "w") as f:
+            json.dump({"clock": "CLOCK_MONOTONIC", "window_ns": [w0, w1],
+                       "stages": timeline}, f)
         return {"dir": dir,
                 "window_s": stopped["stopping"] - started["started"],
-                "stop_s": stopped["stopped"] - stopped["stopping"]}
+                "stop_s": stopped["stopped"] - stopped["stopping"],
+                "host_stages": path,
+                "runner_busy_s": idle["busy_s"],
+                "runner_idle_s": idle["idle_s"],
+                "runner_idle_by": idle["by"]}
 
     def _profile_call(self, meta: dict) -> dict:
         hold = self.PROFILE_TIMEOUT_S
@@ -947,11 +988,11 @@ class DeviceSupervisor:
             self._seq += 1
             seq = self._seq
             ev = threading.Event()
-            slot = [ev, None]
+            slot = [ev, None, None, None, None]
             self._pending[seq] = slot
         meta = dict(meta)
         meta["seq"] = seq
-        sq.put((op, meta, bufs))
+        sq.put((op, meta, bufs, slot))
         if sent is not None:
             sent()
         start = time.monotonic()
@@ -987,7 +1028,8 @@ class DeviceSupervisor:
         t_wake = time.monotonic_ns()
         tag, rmeta, rbufs = slot[1]
         if not health_check:
-            _record_rpc_parts(rmeta.get("t"), t_call, t_wake)
+            _record_rpc_parts(rmeta.get("t"), t_call, slot[2], slot[3],
+                              slot[4], t_wake)
         if tag == "err":
             if rmeta.get("_unavail"):
                 raise DeviceUnavailable(rmeta.get("error", "runner died"))
@@ -1022,15 +1064,17 @@ class DeviceSupervisor:
             if sq is None:
                 return
             try:
-                item = sq.get(timeout=0.25)
+                op, meta, bufs, slot = sq.get(timeout=0.25)
             except queue.Empty:
                 continue
+            slot[2] = time.monotonic_ns()
             try:
-                proto.send_msg(sock, *item)
+                proto.send_msg(sock, op, meta, bufs)
             except (OSError, ValueError) as e:
                 if self._is_current(gen):
                     self._mark_degraded(f"runner link lost (send): {e}")
                 return
+            slot[3] = time.monotonic_ns()
 
     def _recv_loop(self, sock, gen):
         from surrealdb_tpu.device import proto
@@ -1042,6 +1086,7 @@ class DeviceSupervisor:
                 if self._is_current(gen):
                     self._mark_degraded(f"runner died: {e}")
                 return
+            t_in = time.monotonic_ns()
             if tag == "compiling":
                 with self._lock:
                     self._compiling_seq = meta.get("seq")
@@ -1062,6 +1107,7 @@ class DeviceSupervisor:
                         time.monotonic() + self.dispatch_timeout_s
             if slot is not None:
                 slot[1] = (tag, meta, bufs)
+                slot[4] = t_in
                 slot[0].set()
 
     def _is_current(self, gen) -> bool:
@@ -1070,36 +1116,68 @@ class DeviceSupervisor:
                 and self.state in ("ready", "degraded", "probing")
 
 
-def _record_rpc_parts(t, t_call: int, t_wake: int):
+def _record_rpc_parts(t, t_call: int, t_got, t_sent, t_in,
+                      t_wake: int):
     """One RPC cut in six stages on one clock (time.monotonic_ns is
     CLOCK_MONOTONIC in both processes), from the reply's `t`
     (proto.REPLY_T): `rpc_out` from `_call_live`'s entry until the
-    runner had read and decoded the request (the queue to the send
-    thread, encode, socket, decode), `runner_h2d`, `runner_device`,
-    `runner_d2h` as the op timed them (kernelstats.phase; 0 for an op
-    that times none), `runner_other` the rest between the runner's
-    `recv` and `ready` stamps, `rpc_back` from `ready` until the waiter
-    ran again (encode, socket, recv-loop thread, event, the interpreter
-    lock). They partition the call, so they sum to its `device_rpc`. A
-    reply without `t` (an inline host, an error, an older runner) or a
-    negative difference records nothing."""
+    runner had read and decoded the request, `runner_h2d`,
+    `runner_device`, `runner_d2h` as the op timed them
+    (kernelstats.phase; 0 for an op that times none), `runner_other`
+    the rest between the runner's `recv` and `ready` stamps, `rpc_back`
+    from `ready` until the waiter ran again. They partition the call,
+    so they sum to its `device_rpc`.
+
+    The two hand-offs are cut again at the stamps of the threads that
+    carry the call (`t_got`, `t_sent`: `_send_loop` holds the item,
+    `send_msg` has returned; `t_in`: `_recv_loop` holds the decoded
+    reply), to the nanosecond: `rpc_out` = `rpc_send_wake` (entry to
+    `t_got`: `_lock`, the queue, the send thread's wake) + `rpc_send`
+    (to min(`t_sent`, `recv`): encode and `sendall`; the min, because
+    the send thread may lose the interpreter between `sendall` and its
+    stamp, or not have stamped yet) + `rpc_wire_out` (to `recv`: the
+    socket, the runner finishing the op before, its read and decode);
+    `rpc_back` = `rpc_recv` (`ready` to `t_in`: the runner's encode and
+    send, the socket, the recv thread's wake, read and decode) +
+    `rpc_wake` (to the waiter running: the pending lookup under
+    `_lock`, `Event.set`, the waiter's wake).
+
+    All eleven or none, so their counts agree: a reply without `t` (an
+    inline host, an error, an older runner), a stamp missing or a
+    negative difference records nothing. Each is recorded with its own
+    end stamp, for an open window's timeline (telemetry.stage_record);
+    the runner's four have sums and no stamps, and are laid end to end
+    from `recv` to `ready` there (`runner_other` first)."""
     from surrealdb_tpu.device.proto import REPLY_T
     from surrealdb_tpu.telemetry import stage_record
 
-    if not isinstance(t, bytes) or len(t) != REPLY_T.size:
+    if not isinstance(t, bytes) or len(t) != REPLY_T.size \
+            or t_got is None or t_in is None:
         return
     recv, ready, h2d, dev, d2h = REPLY_T.unpack(t)
+    sent = recv if t_sent is None else min(t_sent, recv)
     out = recv - t_call
+    send_wake = t_got - t_call
+    send = sent - t_got
     other = ready - recv - h2d - dev - d2h
     back = t_wake - ready
-    if min(out, h2d, dev, d2h, other, back) < 0:
+    back_recv = t_in - ready
+    wake = t_wake - t_in
+    if min(out, send_wake, send, h2d, dev, d2h, other, back, back_recv,
+           wake) < 0:
         return
-    stage_record("rpc_out", out)
-    stage_record("runner_h2d", h2d)
-    stage_record("runner_device", dev)
-    stage_record("runner_d2h", d2h)
-    stage_record("runner_other", other)
-    stage_record("rpc_back", back)
+    stage_record("rpc_out", out, end_ns=recv)
+    stage_record("rpc_send_wake", send_wake, end_ns=t_got)
+    stage_record("rpc_send", send, end_ns=sent)
+    stage_record("rpc_wire_out", recv - sent, end_ns=recv)
+    end = recv
+    for name, ns in (("runner_other", other), ("runner_h2d", h2d),
+                     ("runner_device", dev), ("runner_d2h", d2h)):
+        end += ns
+        stage_record(name, ns, end_ns=end)
+    stage_record("rpc_back", back, end_ns=t_wake)
+    stage_record("rpc_recv", back_recv, end_ns=t_in)
+    stage_record("rpc_wake", wake, end_ns=t_wake)
 
 
 def require_refusal(mode: str, platform, jax_platforms: str):

@@ -219,6 +219,10 @@ class InflightRegistry:
         self._id_prefix = f"q{uuid.uuid4().hex[:12]}-"
         self._id_seq = 0
         self._pool: list[QueryHandle] = []
+        # queries closed so far: the stall watch (server/stallwatch.py)
+        # reads it beside `count()` to tell open queries that finish
+        # from open queries that do not
+        self.finished = 0
         if telemetry is not None:
             telemetry.register_gauge("inflight_queries", self.count)
 
@@ -244,7 +248,8 @@ class InflightRegistry:
 
     def close(self, handle: QueryHandle):
         with self.lock:
-            self.queries.pop(handle.id, None)
+            if self.queries.pop(handle.id, None) is not None:
+                self.finished += 1
             # recycle only a handle nobody can still legitimately
             # cancel: kill()/cancel_all() flip the flag UNDER this
             # lock, so a clean flag here means no set can race the

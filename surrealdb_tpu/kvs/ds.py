@@ -14,6 +14,7 @@ from typing import Any, Optional
 from surrealdb_tpu import cnf
 from surrealdb_tpu.err import SdbError
 from surrealdb_tpu.kvs.api import Transaction
+from surrealdb_tpu.telemetry import stage_record
 
 
 class Session:
@@ -405,10 +406,19 @@ class Datastore:
                 write,
             )
         if self._local_catalog_cache:
-            with self.lock:
+            # stage `txn_lock_ds`: the wait for this process-wide
+            # mutex alone (the store's own is `txn_lock_store`, taken
+            # inside it); recorded once it is given back
+            t0 = time.monotonic_ns()
+            self.lock.acquire()
+            t1 = time.monotonic_ns()
+            try:
                 t = Transaction(self.backend.transaction(write), write)
                 t._ds = self
                 t._shared_cat = self._catalog_shared
+            finally:
+                self.lock.release()
+            stage_record("txn_lock_ds", t1 - t0, end_ns=t1)
             return t
         return Transaction(self.backend.transaction(write), write)
 
@@ -457,7 +467,6 @@ class Datastore:
             sess.db = db
         stmts = self._ast_cache.get(sql)
         if stmts is None:
-            from surrealdb_tpu.telemetry import stage_record
             t_parse = time.perf_counter_ns()
             try:
                 stmts = parse(sql, capabilities=self.capabilities)

@@ -15,6 +15,7 @@ rollback like the reference's api.rs savepoint API.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 try:
@@ -24,6 +25,7 @@ except ImportError:  # container lacks the dep — pure-Python fallback
 
 from surrealdb_tpu.err import SdbError, TxConflict
 from surrealdb_tpu.kvs.api import Backend, BackendTx
+from surrealdb_tpu.telemetry import stage_record
 
 CONFLICT_MSG = (
     "Failed to commit transaction due to a read or write conflict. "
@@ -43,9 +45,18 @@ class VersionedStore:
 
     # -- snapshots ---------------------------------------------------------
     def snapshot(self) -> int:
-        with self.lock:
+        # stage `txn_lock_store`: the wait for the store's mutex where
+        # a transaction opens, and only there
+        t0 = time.monotonic_ns()
+        self.lock.acquire()
+        t1 = time.monotonic_ns()
+        try:
             self.active.add(self.version)
-            return self.version
+            snap = self.version
+        finally:
+            self.lock.release()
+        stage_record("txn_lock_store", t1 - t0, end_ns=t1)
+        return snap
 
     def release(self, snap: int) -> None:
         with self.lock:

@@ -7,19 +7,27 @@ same optimistic model as kvs/mem.MemTx."""
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from surrealdb_tpu.err import SdbError, TxConflict
 from surrealdb_tpu.kvs.api import Backend, BackendTx
 from surrealdb_tpu.kvs.mem import CONFLICT_MSG
 from surrealdb_tpu.native import NativeMemtable
+from surrealdb_tpu.telemetry import stage_record
 
 
 class NativeMemTx(BackendTx):
     def __init__(self, store: "NativeMemBackend", write: bool):
         self.store = store
         self.write = write
+        # stage `txn_lock_store`, as kvs/mem.py `snapshot` records it:
+        # this store's mutex lies inside the library, so the reading is
+        # the whole `sdb_snapshot` call, which hands the interpreter
+        # away and has to get it back
+        t0 = time.monotonic_ns()
         self.snap = store.table.snapshot()
+        stage_record("txn_lock_store", time.monotonic_ns() - t0)
         self.writes: dict[bytes, Optional[bytes]] = {}
         self.savepoints: list[dict] = []
         self.done = False

@@ -29,7 +29,7 @@ _WS_MAGIC = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
 # routes that must stay responsive under overload: liveness probes and
 # the observability surface bypass admission control entirely
 _UNGATED_PATHS = ("/status", "/health", "/version", "/metrics",
-                  "/telemetry/traces")
+                  "/telemetry/traces", "/telemetry/stalls")
 
 
 def parse_timeout(raw) -> float:
@@ -368,6 +368,14 @@ class SurrealHandler(BaseHTTPRequestHandler):
                 self._json(401, {"error": "Not authenticated"})
                 return
             self._json(200, self.ds.telemetry.recent_traces())
+            return
+        if path == "/telemetry/stalls":
+            # the stall watch's last dumps (server/stallwatch.py):
+            # thread names and frames, gated like the traces
+            if self._session().auth_level == "none":
+                self._json(401, {"error": "Not authenticated"})
+                return
+            self._json(200, self.ds.telemetry.recent_stalls())
             return
         if path == "/kv/topology":
             # shard topology (ranges, epochs, primaries) of a sharded
@@ -863,6 +871,7 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
     from surrealdb_tpu import cnf
     from surrealdb_tpu.server.admission import AdmissionController
     from surrealdb_tpu.server.hangup import HangupWatch
+    from surrealdb_tpu.server.stallwatch import StallWatch
 
     if max_inflight is None:
         max_inflight = cnf.HTTP_MAX_INFLIGHT
@@ -893,12 +902,15 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
             # the disconnect watch of the gated routes
             self.hangups = (HangupWatch(ds.telemetry)
                             if admission is not None else None)
+            # what a wake costs, and the moments nothing finishes
+            self.stalls = StallWatch(ds)
             super().__init__(*args)  # a failed bind calls server_close
 
         def server_close(self):
             super().server_close()
             if self.hangups is not None:
                 self.hangups.close()
+            self.stalls.close()
 
     if not tls_cert:
         return _HttpServer((host, port), handler)

@@ -8,6 +8,7 @@ the same data is surfaced as pull endpoints instead of OTLP push:
 - `/metrics` (server): Prometheus text format — datastore counters,
   query-duration histogram, HTTP/WS/RPC counters.
 - `/telemetry/traces` (server): recent per-query span trees as JSON.
+- `/telemetry/stalls` (server): the stall watch's last dumps.
 
 Spans are thread-local and cheap: `span(name)` context managers nest;
 each query's root span lands in a bounded ring buffer.
@@ -75,16 +76,53 @@ class StageStat:
 _STAGES: dict[str, StageStat] = {}
 
 
-def stage_record(name: str, ns: int, cpu_ns=None):
+# While a `DeviceSupervisor.profile` window is open, every record also
+# lands here as `(stage, thread id, start_ns, end_ns)` on
+# CLOCK_MONOTONIC (time.monotonic_ns; perf_counter_ns is the same clock
+# on Linux, and the runner stamps with it too), so the window's
+# `host_stages.json` says where each interval lay and not only what it
+# summed to. None while no window is open: a record then costs one
+# global read more. Bounded: a window left open cannot grow it past
+# TIMELINE_MAX entries (~50 MB; half a minute of 32 busy callers).
+TIMELINE_MAX = 1 << 18
+_TIMELINE = None
+
+
+def stage_record(name: str, ns: int, cpu_ns=None, end_ns=None):
     """Record `ns` nanoseconds of wall time (and, where the caller
     measured it, `cpu_ns` of its thread's CPU time) spent in query
-    stage `name`."""
+    stage `name`. `end_ns` is the stage's end on time.monotonic_ns
+    where the caller records after the fact (a wait written down once
+    the ride is over, a part of an RPC that ended at another thread's
+    stamp); left out, the stage ended now. It places the interval in
+    an open window's timeline and changes no sum."""
     st = _STAGES.get(name)
     if st is None:
         # dict set is atomic under the GIL; a racing first-record for
         # the same stage leaves one winner and loses one sample
         st = _STAGES.setdefault(name, StageStat())
     st.add(ns, cpu_ns)
+    tl = _TIMELINE
+    if tl is not None and len(tl) < TIMELINE_MAX:
+        if end_ns is None:
+            end_ns = time.monotonic_ns()
+        tl.append((name, threading.get_ident(), end_ns - ns, end_ns))
+
+
+def timeline_arm():
+    """Start keeping the stages' intervals (`DeviceSupervisor.profile`
+    opens its window with this). A window already open keeps its
+    list."""
+    global _TIMELINE
+    if _TIMELINE is None:
+        _TIMELINE = []
+
+
+def timeline_disarm() -> list:
+    """Stop keeping them; the intervals kept since `timeline_arm`."""
+    global _TIMELINE
+    tl, _TIMELINE = _TIMELINE, None
+    return tl or []
 
 
 def stage_snapshot() -> dict:
@@ -138,6 +176,9 @@ class Telemetry:
         self.lock = threading.Lock()
         self.ring_size = ring_size
         self.traces: list[Span] = []  # rendered lazily by recent_traces
+        # the stall watch's last dumps (server/stallwatch.py), newest
+        # last; served at /telemetry/stalls
+        self.stalls: list[dict] = []
         self.counters: dict[str, int] = {}
         # query duration histogram (cumulative bucket counts, Prometheus
         # `le` semantics) + sum/count
@@ -257,6 +298,17 @@ class Telemetry:
         with self.lock:
             spans = list(self.traces[-limit:])
         return [s.to_dict() for s in spans]
+
+    STALL_RING = 4
+
+    def add_stall(self, dump: dict):
+        with self.lock:
+            self.stalls.append(dump)
+            del self.stalls[:-self.STALL_RING]
+
+    def recent_stalls(self) -> list[dict]:
+        with self.lock:
+            return list(self.stalls)
 
     # -- prometheus ---------------------------------------------------------
     def prometheus(self, ds=None) -> str:

@@ -1,9 +1,12 @@
 """One dispatch's timeline from inside the program: the stages the server
 edge (`request`), the batcher (`batch_wait`, `batch_ride`,
-`batch_dispatch`), the index engine (`knn_post`) and the supervisor
-(`rpc_out`, `runner_*`, `rpc_back`) record, the runner's `loop` and `ann`
-counters, and the profiler window the program owns. CPU only: the
-batcher tests run no device, the RPC tests a CPU runner subprocess."""
+`batch_dispatch`), the index engine (`knn_post`), the supervisor
+(`rpc_out`, `runner_*`, `rpc_back` and the five hand-offs inside the two
+ways) and the KV store (`txn_lock_*`) record, the runner's `loop` and
+`ann` counters, and the window the program owns in both processes: the
+profiler's trace, `host_stages.json` and `runner_idle_by`
+(device/idle.py). CPU only: the batcher tests run no device, the RPC
+tests a CPU runner subprocess."""
 
 from __future__ import annotations
 
@@ -11,6 +14,7 @@ import glob
 import json
 import threading
 import time
+import types
 import urllib.request
 
 import numpy as np
@@ -22,6 +26,9 @@ from surrealdb_tpu.device.batcher import BatchStats, DeviceBatcher
 
 RPC_PARTS = ("rpc_out", "runner_h2d", "runner_device", "runner_d2h",
              "runner_other", "rpc_back")
+# `rpc_out` = the first three, `rpc_back` = the last two
+HANDOFFS = ("rpc_send_wake", "rpc_send", "rpc_wire_out", "rpc_recv",
+            "rpc_wake")
 
 
 def totals() -> dict:
@@ -184,7 +191,7 @@ def test_knn_post_is_recorded_once_a_dispatch(monkeypatch):
     assert added(t0, "knn_post")[0] == 1
     assert added(t0, "device_rpc")[0] >= 1
     # the inline host's reply has no `t`: no part of an RPC is recorded
-    assert all(added(t0, p)[0] == 0 for p in RPC_PARTS)
+    assert all(added(t0, p)[0] == 0 for p in RPC_PARTS + HANDOFFS)
 
 
 # -- one RPC cut in six, over a live CPU runner ---------------------------------
@@ -245,21 +252,56 @@ def test_one_vec_knn_is_cut_in_six_parts_inside_its_device_rpc(live):
     assert total <= rpc_ns and rpc_ns - total < 2e6
 
 
+def test_the_hand_offs_partition_the_way_out_and_the_way_back(live):
+    _sup, knn = live
+    t0 = totals()
+    for _ in range(3):
+        assert knn()[0] == "ok"
+    got = {p: added(t0, p) for p in RPC_PARTS + HANDOFFS}
+    assert all(c == 3 and ns >= 0 for c, ns in got.values()), got
+    # to the nanosecond: the stamps between are shared
+    assert got["rpc_out"][1] == sum(
+        got[p][1] for p in ("rpc_send_wake", "rpc_send", "rpc_wire_out"))
+    assert got["rpc_back"][1] == got["rpc_recv"][1] + got["rpc_wake"][1]
+    # a thread woke on either way: neither hand-off is free
+    assert got["rpc_send_wake"][1] > 0 and got["rpc_wake"][1] > 0
+
+
 def test_a_reply_without_t_or_with_a_negative_part_records_nothing():
     from surrealdb_tpu.device.supervisor import _record_rpc_parts
 
     pack = proto.REPLY_T.pack  # recv, ready, h2d, device, d2h
+    every = RPC_PARTS + HANDOFFS
+    # (t, t_call, t_got, t_sent, t_in, t_wake)
     t0 = totals()
-    _record_rpc_parts(None, 100, 900)
-    _record_rpc_parts(pack(200, 300, 0, 60, 0)[:16], 100, 900)
+    _record_rpc_parts(None, 100, 120, 150, 700, 900)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0)[:16], 100, 120, 150, 700,
+                      900)
     # the runner's clock behind the caller's: no stage, not a negative one
-    _record_rpc_parts(pack(50, 300, 0, 0, 0), 100, 900)
-    _record_rpc_parts(pack(200, 300, 0, 500, 0), 100, 900)
-    assert all(added(t0, p)[0] == 0 for p in RPC_PARTS)
-    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 900)
-    assert {p: added(t0, p)[1] for p in RPC_PARTS} == {
+    _record_rpc_parts(pack(50, 300, 0, 0, 0), 100, 120, 150, 700, 900)
+    _record_rpc_parts(pack(200, 300, 0, 500, 0), 100, 120, 150, 700, 900)
+    # the recv thread's stamp before the runner's `ready`, or after the
+    # waiter's wake; a hand-off never stamped: all or none
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, 150, 250, 900)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, 150, 950, 900)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, None, 150, 700, 900)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, 150, None, 900)
+    assert all(added(t0, p)[0] == 0 for p in every)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, 150, 700, 900)
+    assert {p: added(t0, p)[1] for p in every} == {
         "rpc_out": 100, "runner_h2d": 0, "runner_device": 60,
-        "runner_d2h": 0, "runner_other": 40, "rpc_back": 600}
+        "runner_d2h": 0, "runner_other": 40, "rpc_back": 600,
+        "rpc_send_wake": 20, "rpc_send": 30, "rpc_wire_out": 50,
+        "rpc_recv": 400, "rpc_wake": 200}
+    # the send thread stamped late (it lost the interpreter after
+    # `sendall`), or had not stamped yet: its part ends at the runner's
+    # `recv` at the latest
+    t0 = totals()
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, 260, 700, 900)
+    _record_rpc_parts(pack(200, 300, 0, 60, 0), 100, 120, None, 700, 900)
+    assert {p: added(t0, p)[1] for p in HANDOFFS[:3]} == {
+        "rpc_send_wake": 40, "rpc_send": 160, "rpc_wire_out": 0}
+    assert added(t0, "rpc_out") == (2, 200)
 
 
 def test_the_runners_loop_counters_track_wall_time(live):
@@ -310,26 +352,30 @@ def test_profile_puts_the_runners_spans_beside_the_devices_operations(
     from jax.profiler import ProfileData
 
     sup, knn = live
-    note = {}
+    note, held = {}, []
     window = threading.Thread(
         target=lambda: note.update(sup.profile(str(tmp_path), 1.0)))
     window.start()
     time.sleep(0.3)
     for _ in range(4):
-        knn()
+        recv, ready = proto.REPLY_T.unpack(knn()[1]["t"])[:2]
+        held.append((recv, ready))
         time.sleep(0.05)
     window.join(60)
     assert note["window_s"] >= 1.0 and note["stop_s"] >= 0
     paths = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
                           / "*.xplane.pb"))
     assert len(paths) == 1
-    spans, xla = {}, []
+    spans, xla, t_recvs = {}, [], []
     for plane in ProfileData.from_file(paths[0]).planes:
         for line in plane.lines:
             for ev in line.events:
                 span = (ev.start_ns, ev.start_ns + ev.duration_ns)
                 if ev.name.startswith("runner:"):
                     spans.setdefault(ev.name, []).append(span)
+                    if ev.name == "runner:vec_knn":
+                        t_recvs.append(next(
+                            int(v) for k, v in ev.stats if k == "t_recv"))
                 elif "xla" in line.name.lower() and ev.duration_ns > 0:
                     xla.append(span)
     assert len(spans["runner:vec_knn"]) == 4
@@ -344,6 +390,42 @@ def test_profile_puts_the_runners_spans_beside_the_devices_operations(
     assert all(any(s <= ps and pe <= e for s, e in ops)
                for ps, pe in spans["runner:device"])
     assert "runner:h2d" not in spans and "runner:d2h" not in spans
+    # each op's span carries the `recv` stamp its reply carried: the
+    # trace's clock laid over CLOCK_MONOTONIC
+    assert sorted(t_recvs) == [recv for recv, _ready in held]
+    # the same window of this process: every stage's interval, on that
+    # clock, beside the trace
+    assert telemetry._TIMELINE is None
+    doc = json.load(open(note["host_stages"]))
+    assert note["host_stages"] == str(tmp_path / "host_stages.json")
+    w0, w1 = doc["window_ns"]
+    assert (w1 - w0) / 1e9 == pytest.approx(note["window_s"], abs=1e-6)
+    rows = doc["stages"]
+    assert all(start <= end for _st, _tid, start, end in rows)
+    # (the window's own start and stop calls are in it too)
+    assert {r[3] for r in rows if r[0] == "rpc_out"} >= {
+        recv for recv, _ready in held}
+    assert {r[2] for r in rows if r[0] == "rpc_back"} >= {
+        ready for _recv, ready in held}
+    for name in HANDOFFS + ("device_rpc",):
+        assert sum(1 for r in rows if r[0] == name) >= 4, name
+    # the runner held an op from each `recv` to its `ready` (and for a
+    # moment at either edge: the window's own start and stop calls);
+    # the rest is idle, and every idle second has one cause
+    busy = sum(ready - recv for recv, ready in held) / 1e9
+    assert busy <= note["runner_busy_s"] < busy + 0.005
+    assert note["runner_busy_s"] + note["runner_idle_s"] == pytest.approx(
+        note["window_s"], abs=1e-6)
+    by = note["runner_idle_by"]
+    assert list(by) == ["request_out", "reply_back", "dispatch_host",
+                        "riders_queued", "no_rider"]
+    assert sum(by.values()) == pytest.approx(note["runner_idle_s"],
+                                             abs=1e-6)
+    # one caller that waits between its calls: nearly all of it is
+    # nobody asking, the hand-offs are the rest, and no batcher ran
+    assert by["no_rider"] > 0.8 * note["window_s"]
+    assert by["request_out"] > 0 and by["reply_back"] > 0
+    assert by["dispatch_host"] == by["riders_queued"] == 0
     # and the runner still serves
     tag, _meta, bufs = knn()
     assert tag == "ok" and bufs[1][:, 0].tolist() == [0, 1, 2]
@@ -360,16 +442,184 @@ def test_an_interrupted_profile_leaves_no_trace_open(live, tmp_path,
     def interrupted(s):
         if threading.get_ident() != me:
             return sleep(s)  # the supervisor's own threads
+        assert telemetry._TIMELINE is not None  # the window is open
         raise KeyboardInterrupt
 
     monkeypatch.setattr(S.time, "sleep", interrupted)
     with pytest.raises(KeyboardInterrupt):
         sup.profile(str(tmp_path / "a"), 1.0)
     monkeypatch.undo()
-    # the window was closed on the way out: a second one can open
+    # the window was closed on the way out, in both processes: stages
+    # are no longer kept, and a second one can open
+    assert telemetry._TIMELINE is None
+    t0 = totals()
+    assert knn()[0] == "ok" and added(t0, "rpc_out")[0] == 1
+    assert telemetry._TIMELINE is None
     note = sup.profile(str(tmp_path / "b"), 0.05)
     assert note["window_s"] >= 0.05
     assert knn()[0] == "ok"
+
+
+# -- the idle seconds' causes, on hand-made intervals ------------------------------
+
+
+def rpc(tid, t_call, t_got, sent, recv, ready, t_in, t_wake):
+    """The timeline rows one call leaves (supervisor._record_rpc_parts
+    and `call`), in the order they are recorded."""
+    return [("rpc_out", tid, t_call, recv),
+            ("rpc_send_wake", tid, t_call, t_got),
+            ("rpc_send", tid, t_got, sent),
+            ("rpc_wire_out", tid, sent, recv),
+            ("rpc_back", tid, ready, t_wake),
+            ("rpc_recv", tid, ready, t_in),
+            ("rpc_wake", tid, t_in, t_wake),
+            ("device_rpc", tid, t_call, t_wake)]
+
+
+def test_interval_arithmetic():
+    from surrealdb_tpu.device.idle import subtract, total, union
+
+    assert union([(5, 9), (1, 3), (2, 4), (9, 9), (8, 12)]) == [
+        [1, 4], [5, 12]]
+    assert union([]) == []
+    a = [[0, 10], [20, 30]]
+    assert subtract(a, []) == a
+    assert subtract(a, [[0, 30]]) == []
+    assert subtract(a, [[2, 3], [5, 22], [29, 40]]) == [
+        [0, 2], [3, 5], [22, 29]]
+    assert subtract(a, [[10, 20]]) == a
+    assert total(a) == 20
+
+
+def test_runner_idle_by_names_each_gap_by_its_first_cause():
+    from surrealdb_tpu.device.idle import runner_busy, runner_idle_by
+
+    s = 10 ** 9  # the rows in seconds, the arithmetic in ns
+    rows = []
+    # a dispatcher (thread 1) works 1 s before its call and 0.5 after;
+    # the runner holds the op from 3.0 to 4.0
+    rows += rpc(1, 2 * s, 2.2 * s, 2.5 * s, 3 * s, 4 * s, 4.6 * s, 5 * s)
+    rows.append(("batch_dispatch", 1, 1 * s, 5.5 * s))
+    # a second call (thread 2) overlaps it: sent while the runner is
+    # busy, held from 4.0 to 6.0; its way out past 4.0 is no gap
+    rows += rpc(2, 3.5 * s, 3.6 * s, 3.7 * s, 4 * s, 6 * s, 6.1 * s,
+                6.3 * s)
+    # two riders queued: one while all that went on, one after it with
+    # no call in flight
+    rows.append(("batch_wait", 3, 2.5 * s, 5.2 * s))
+    rows.append(("batch_wait", 4, 7 * s, 8 * s))
+    rows = [(st, tid, int(a), int(b)) for st, tid, a, b in rows]
+    assert runner_busy(rows) == [[3 * s, 6 * s]]
+    out = runner_idle_by(rows, 0, 10 * s)
+    assert out["busy_s"] == 3.0 and out["idle_s"] == 7.0
+    assert out["by"] == {
+        "request_out": 1.0,     # 2.0-3.0, though a dispatch is open too
+        "reply_back": pytest.approx(0.3),    # 6.0-6.3
+        "dispatch_host": 1.0,   # 1.0-2.0; 5.0-5.5 lies under the busy
+        "riders_queued": 1.0,   # 7.0-8.0; 2.5-5.2 was named before
+        "no_rider": pytest.approx(3.7),      # 0-1, 6.3-7, 8-10
+    }
+    assert sum(out["by"].values()) == pytest.approx(out["idle_s"])
+    # a window clips what lies outside it, and a gap with nothing open
+    # is nobody's
+    out = runner_idle_by(rows, int(8.5 * s), int(9.5 * s))
+    assert out["busy_s"] == 0.0 and out["idle_s"] == 1.0
+    assert out["by"]["no_rider"] == 1.0
+    assert sum(out["by"].values()) == 1.0
+    out = runner_idle_by(rows, int(3.2 * s), int(3.8 * s))
+    assert out["busy_s"] == pytest.approx(0.6) and out["idle_s"] == 0.0
+    assert not any(out["by"].values())
+    # an empty timeline, an empty window
+    assert runner_idle_by([], 0, s)["by"]["no_rider"] == 1.0
+    assert runner_idle_by(rows, s, s) == {
+        "busy_s": 0.0, "idle_s": 0.0,
+        "by": dict.fromkeys(out["by"], 0.0)}
+
+
+def test_a_stage_keeps_its_interval_only_while_a_window_is_open():
+    assert telemetry._TIMELINE is None
+    t0 = totals()
+    telemetry.stage_record("t_closed", 5)
+    telemetry.timeline_arm()
+    try:
+        before = time.monotonic_ns()
+        telemetry.stage_record("t_now", 7)
+        after = time.monotonic_ns()
+        telemetry.stage_record("t_then", 30, end_ns=1000)
+    finally:
+        rows = telemetry.timeline_disarm()
+    telemetry.stage_record("t_closed", 5)
+    assert telemetry._TIMELINE is None and telemetry.timeline_disarm() == []
+    me = threading.get_ident()
+    assert [r[:2] for r in rows] == [("t_now", me), ("t_then", me)]
+    assert rows[1][2:] == (970, 1000)
+    assert rows[0][3] - rows[0][2] == 7 and before <= rows[0][3] <= after
+    # the sums are what they would be with no window
+    assert added(t0, "t_then") == (1, 30) and added(t0, "t_now") == (1, 7)
+    assert added(t0, "t_closed") == (2, 10)
+
+
+# -- `txn_open` by lock ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("stage, other", [
+    ("txn_lock_ds", "txn_lock_store"),
+    ("txn_lock_store", "txn_lock_ds"),
+])
+def test_a_transactions_wait_for_each_mutex_is_its_own_stage(
+        monkeypatch, stage, other):
+    """Another thread holds the mutex and, once the opener has read
+    its clock and gone for the lock, moves that clock 5 ms on and lets
+    go: the stage of that mutex reads the 5 ms, the other's nothing."""
+    import surrealdb_tpu.kvs.ds as D
+    import surrealdb_tpu.kvs.mem as M
+    from surrealdb_tpu import Datastore
+
+    ds = Datastore("pymem")
+    lock = ds.lock if stage == "txn_lock_ds" else ds.backend.vs.lock
+    now, opener_read = [10 ** 12], threading.Event()
+
+    def clock():
+        if threading.current_thread().name == "opener":
+            opener_read.set()
+        return now[0]
+
+    fake = types.SimpleNamespace(**vars(time))
+    fake.monotonic_ns = clock
+    monkeypatch.setattr(D, "time", fake)
+    monkeypatch.setattr(M, "time", fake)
+    # nobody holds either: no wait
+    t0 = totals()
+    ds.transaction(write=False).cancel()
+    assert added(t0, stage) == (1, 0) and added(t0, other) == (1, 0)
+    t0 = totals()
+    opened = []
+    opener = threading.Thread(
+        target=lambda: opened.append(ds.transaction(write=False)),
+        name="opener", daemon=True)
+    with lock:
+        opener.start()
+        assert opener_read.wait(10)
+        now[0] += 5_000_000
+    opener.join(10)
+    assert opened and not opener.is_alive()
+    opened[0].cancel()
+    assert added(t0, stage) == (1, 5_000_000)
+    assert added(t0, other) == (1, 0)
+
+
+def test_the_native_store_times_its_snapshot_call():
+    from surrealdb_tpu import Datastore
+    from surrealdb_tpu.native import available
+
+    if not available():
+        pytest.skip("no native memtable here")
+    ds = Datastore("memory")
+    t0 = totals()
+    ds.transaction(write=False).cancel()
+    (count, ns), (ds_count, _ns) = added(t0, "txn_lock_store"), \
+        added(t0, "txn_lock_ds")
+    assert count == ds_count == 1 and 0 < ns < 1e9
 
 
 # -- the server edge --------------------------------------------------------------
