@@ -282,6 +282,13 @@ class Datastore:
         from surrealdb_tpu.val import rid_renders as _rid_renders
 
         self.telemetry.register_counter("rid_renders", _rid_renders)
+        # native memtable calls made keeping the interpreter lock, and
+        # try-lock calls that found the store's mutex held and fell back
+        # to the releasing binding (native/__init__.py): process-wide too
+        from surrealdb_tpu.native import kv_native_busy, kv_native_kept
+
+        self.telemetry.register_counter("kv_native_kept", kv_native_kept)
+        self.telemetry.register_counter("kv_native_busy", kv_native_busy)
         # index-serving shard count across all sharded vector indexes
         # (0 on unsharded stores; pairs with the knn_shard_fanout /
         # knn_partial_results / knn_hedged_dispatches counters)

@@ -23,8 +23,9 @@ class NativeMemTx(BackendTx):
         self.write = write
         # stage `txn_lock_store`, as kvs/mem.py `snapshot` records it:
         # this store's mutex lies inside the library, so the reading is
-        # the whole `sdb_snapshot` call, which hands the interpreter
-        # away and has to get it back
+        # the whole call: `sdb_snapshot_try` keeping the interpreter, and
+        # where the mutex was held, the blocking `sdb_snapshot` after it,
+        # which hands the interpreter away and has to get it back
         t0 = time.monotonic_ns()
         self.snap = store.table.snapshot()
         stage_record("txn_lock_store", time.monotonic_ns() - t0)

@@ -11,6 +11,13 @@
 // All values returned to Python are copied into malloc'd buffers under the
 // store mutex (sdb_buf_free releases them) — no interior pointers escape,
 // so concurrent commits can never invalidate a buffer mid-read.
+//
+// The calls whose work under the mutex is bounded (a snapshot, its release,
+// one key's read, a commit of a few keys) have a `_try` twin that takes the
+// mutex only if it is free: the binding calls those while keeping the
+// Python interpreter lock, and a twin that finds the mutex held returns
+// SDB_BUSY (-1 where it returns an int), having touched nothing, so the
+// caller falls back to the blocking call, which releases the interpreter.
 
 #include <cstdint>
 #include <cstdlib>
@@ -74,38 +81,22 @@ char* copy_out(const std::string& s) {
     return buf;
 }
 
-}  // namespace
+constexpr uint64_t SDB_BUSY = UINT64_MAX;
 
-extern "C" {
+using TryLock = std::unique_lock<std::mutex>;
 
-void* sdb_memtable_new() { return new Memtable(); }
-
-void sdb_memtable_free(void* h) { delete static_cast<Memtable*>(h); }
-
-void sdb_buf_free(char* p) { std::free(p); }
-
-// snapshots ----------------------------------------------------------------
-
-uint64_t sdb_snapshot(void* h) {
-    auto* m = static_cast<Memtable*>(h);
-    std::lock_guard<std::mutex> lock(m->mu);
+uint64_t snapshot_locked(Memtable* m) {
     m->active.insert(m->version);
     return m->version;
 }
 
-void sdb_snapshot_release(void* h, uint64_t snap) {
-    auto* m = static_cast<Memtable*>(h);
-    std::lock_guard<std::mutex> lock(m->mu);
+void release_locked(Memtable* m, uint64_t snap) {
     auto it = m->active.find(snap);
     if (it != m->active.end()) m->active.erase(it);
 }
 
-// reads --------------------------------------------------------------------
-
-int sdb_get_at(void* h, const char* key, int64_t klen, uint64_t snap,
+int get_locked(Memtable* m, const char* key, int64_t klen, uint64_t snap,
                char** val, int64_t* vlen) {
-    auto* m = static_cast<Memtable*>(h);
-    std::lock_guard<std::mutex> lock(m->mu);
     auto it = m->chains.find(std::string(key, klen));
     if (it == m->chains.end()) return 0;
     const std::string* v = resolve(it->second, snap);
@@ -115,28 +106,16 @@ int sdb_get_at(void* h, const char* key, int64_t klen, uint64_t snap,
     return 1;
 }
 
-int64_t sdb_len(void* h) {
-    auto* m = static_cast<Memtable*>(h);
-    std::lock_guard<std::mutex> lock(m->mu);
-    int64_t n = 0;
-    for (auto& kv : m->chains)
-        if (!kv.second.empty() && !kv.second.back().tombstone) n++;
-    return n;
-}
-
 // commit: interleaved (key, val) pairs; vlen < 0 marks a tombstone.
 // Returns the new version, or 0 on write-write conflict (any written key
 // has a committed version newer than `snap`). With release_snap, the
 // committer's snapshot is removed from the active set under the SAME mutex
 // hold, after validation — releasing before validating would let a
 // concurrent delete prune a conflicting chain away and hide the conflict.
-
-uint64_t sdb_commit_batch(void* h, uint64_t snap, int64_t n,
-                          const char** keys, const int64_t* klens,
-                          const char** vals, const int64_t* vlens,
-                          int release_snap) {
-    auto* m = static_cast<Memtable*>(h);
-    std::lock_guard<std::mutex> lock(m->mu);
+uint64_t commit_locked(Memtable* m, uint64_t snap, int64_t n,
+                       const char** keys, const int64_t* klens,
+                       const char** vals, const int64_t* vlens,
+                       int release_snap) {
     bool conflict = false;
     for (int64_t i = 0; i < n && !conflict; i++) {
         auto it = m->chains.find(std::string(keys[i], klens[i]));
@@ -144,10 +123,7 @@ uint64_t sdb_commit_batch(void* h, uint64_t snap, int64_t n,
             it->second.back().ver > snap)
             conflict = true;
     }
-    if (release_snap) {
-        auto a = m->active.find(snap);
-        if (a != m->active.end()) m->active.erase(a);
-    }
+    if (release_snap) release_locked(m, snap);
     if (conflict) return 0;
     uint64_t ver = ++m->version;
     uint64_t min_active = m->active.empty() ? ver : *m->active.begin();
@@ -167,6 +143,92 @@ uint64_t sdb_commit_batch(void* h, uint64_t snap, int64_t n,
         prune(m->chains, it, min_active);
     }
     return ver;
+}
+
+}  // namespace
+
+extern "C" {
+
+void* sdb_memtable_new() { return new Memtable(); }
+
+void sdb_memtable_free(void* h) { delete static_cast<Memtable*>(h); }
+
+void sdb_buf_free(char* p) { std::free(p); }
+
+// snapshots ----------------------------------------------------------------
+
+uint64_t sdb_snapshot(void* h) {
+    auto* m = static_cast<Memtable*>(h);
+    std::lock_guard<std::mutex> lock(m->mu);
+    return snapshot_locked(m);
+}
+
+uint64_t sdb_snapshot_try(void* h) {
+    auto* m = static_cast<Memtable*>(h);
+    TryLock lock(m->mu, std::try_to_lock);
+    if (!lock.owns_lock()) return SDB_BUSY;
+    return snapshot_locked(m);
+}
+
+void sdb_snapshot_release(void* h, uint64_t snap) {
+    auto* m = static_cast<Memtable*>(h);
+    std::lock_guard<std::mutex> lock(m->mu);
+    release_locked(m, snap);
+}
+
+int sdb_snapshot_release_try(void* h, uint64_t snap) {
+    auto* m = static_cast<Memtable*>(h);
+    TryLock lock(m->mu, std::try_to_lock);
+    if (!lock.owns_lock()) return -1;
+    release_locked(m, snap);
+    return 0;
+}
+
+// reads --------------------------------------------------------------------
+
+int sdb_get_at(void* h, const char* key, int64_t klen, uint64_t snap,
+               char** val, int64_t* vlen) {
+    auto* m = static_cast<Memtable*>(h);
+    std::lock_guard<std::mutex> lock(m->mu);
+    return get_locked(m, key, klen, snap, val, vlen);
+}
+
+int sdb_get_at_try(void* h, const char* key, int64_t klen, uint64_t snap,
+                   char** val, int64_t* vlen) {
+    auto* m = static_cast<Memtable*>(h);
+    TryLock lock(m->mu, std::try_to_lock);
+    if (!lock.owns_lock()) return -1;
+    return get_locked(m, key, klen, snap, val, vlen);
+}
+
+int64_t sdb_len(void* h) {
+    auto* m = static_cast<Memtable*>(h);
+    std::lock_guard<std::mutex> lock(m->mu);
+    int64_t n = 0;
+    for (auto& kv : m->chains)
+        if (!kv.second.empty() && !kv.second.back().tombstone) n++;
+    return n;
+}
+
+// writes (commit_locked above) ---------------------------------------------
+
+uint64_t sdb_commit_batch(void* h, uint64_t snap, int64_t n,
+                          const char** keys, const int64_t* klens,
+                          const char** vals, const int64_t* vlens,
+                          int release_snap) {
+    auto* m = static_cast<Memtable*>(h);
+    std::lock_guard<std::mutex> lock(m->mu);
+    return commit_locked(m, snap, n, keys, klens, vals, vlens, release_snap);
+}
+
+uint64_t sdb_commit_batch_try(void* h, uint64_t snap, int64_t n,
+                              const char** keys, const int64_t* klens,
+                              const char** vals, const int64_t* vlens,
+                              int release_snap) {
+    auto* m = static_cast<Memtable*>(h);
+    TryLock lock(m->mu, std::try_to_lock);
+    if (!lock.owns_lock()) return SDB_BUSY;
+    return commit_locked(m, snap, n, keys, klens, vals, vlens, release_snap);
 }
 
 // range scans --------------------------------------------------------------
@@ -203,24 +265,14 @@ void* sdb_scan_new_at(void* h, const char* beg, int64_t blen, const char* end,
     return it;
 }
 
-int sdb_scan_next(void* hit, const char** key, int64_t* klen,
-                  const char** val, int64_t* vlen) {
-    auto* it = static_cast<ScanIter*>(hit);
-    if (it->pos >= it->items.size()) return 0;
-    auto& kv = it->items[it->pos++];
-    *key = kv.first.data();
-    *klen = static_cast<int64_t>(kv.first.size());
-    *val = kv.second.data();
-    *vlen = static_cast<int64_t>(kv.second.size());
-    return 1;
-}
-
 void sdb_scan_free(void* hit) { delete static_cast<ScanIter*>(hit); }
 
 // Batched drain: pack up to max_items [u32 klen][u32 vlen][key][val]
 // frames into buf (cap bytes). Returns the number of items packed and
 // writes the used byte count — one FFI crossing per few hundred rows
-// instead of one per row.
+// instead of one per row. A packed item's strings are freed here, a batch
+// at a time, so freeing a drained iterator is cheap (the binding frees it
+// while keeping the interpreter).
 int64_t sdb_scan_batch(void* hit, char* buf, int64_t cap,
                        int64_t max_items, int64_t* used) {
     auto* it = static_cast<ScanIter*>(hit);
@@ -240,6 +292,8 @@ int64_t sdb_scan_batch(void* hit, char* buf, int64_t cap,
         std::memcpy(buf + off + 4, &vl, 4);
         std::memcpy(buf + off + 8, kv.first.data(), kl);
         std::memcpy(buf + off + 8 + kl, kv.second.data(), vl);
+        std::string().swap(kv.first);
+        std::string().swap(kv.second);
         off += need;
         it->pos++;
         count++;

@@ -7,6 +7,7 @@ identities, never a time."""
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 
@@ -548,10 +549,19 @@ def test_concurrent_inserts_are_retried_not_refused():
                 errors.append(out[0].error)
 
     threads = [threading.Thread(target=writer, args=(w,)) for w in range(4)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    # the writers have to overlap for their commits to conflict: since the
+    # native memtable's short calls keep the interpreter lock (PR 36) a
+    # statement gives it away nowhere, so the test switches threads as
+    # often as those calls used to (~120 retries, as at the parent)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        sys.setswitchinterval(interval)
     # what was refused after every retry was refused as a conflict
     assert all("can be retried" in e for e in errors), errors[:2]
     assert len(errors) <= 8
