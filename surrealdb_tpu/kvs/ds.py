@@ -289,6 +289,14 @@ class Datastore:
 
         self.telemetry.register_counter("kv_native_kept", kv_native_kept)
         self.telemetry.register_counter("kv_native_busy", kv_native_busy)
+        # arrays of a JSON request body parsed natively, and bodies whose
+        # native pass was skipped or refused (native/jsonnum.py)
+        from surrealdb_tpu.native import jsonnum as _jsonnum
+
+        self.telemetry.register_counter(
+            "rpc_json_native_arrays", _jsonnum.rpc_json_native_arrays)
+        self.telemetry.register_counter(
+            "rpc_json_native_fallbacks", _jsonnum.rpc_json_native_fallbacks)
         # index-serving shard count across all sharded vector indexes
         # (0 on unsharded stores; pairs with the knn_shard_fanout /
         # knn_partial_results / knn_hedged_dispatches counters)

@@ -20,6 +20,7 @@ from urllib.parse import parse_qs, unquote, urlparse
 from surrealdb_tpu import inflight as _inflight
 from surrealdb_tpu.err import SdbError, ShedError
 from surrealdb_tpu.kvs.ds import Datastore, Session
+from surrealdb_tpu.native import jsonnum
 from surrealdb_tpu.rpc import RpcError, RpcSession
 from surrealdb_tpu.telemetry import stage_record
 from surrealdb_tpu.val import to_json
@@ -68,6 +69,17 @@ def _encode_reply(encode, payload) -> bytes:
     body = encode(payload)
     stage_record("reply_encode", time.perf_counter_ns() - t0)
     return body
+
+
+def _decode_body(decode, raw: bytes):
+    """A `/rpc` request's value, `decode(raw)`, under stage `body_decode`:
+    inside `request`, wall time only, as `_encode_reply`. JSON bodies
+    decode through `jsonnum.loads`, whose float arrays are parsed
+    natively."""
+    t0 = time.perf_counter_ns()
+    value = decode(raw)
+    stage_record("body_decode", time.perf_counter_ns() - t0)
+    return value
 
 
 def _json_bytes(payload) -> bytes:
@@ -519,13 +531,14 @@ class SurrealHandler(BaseHTTPRequestHandler):
                 if fmt_in == "cbor":
                     from surrealdb_tpu import wire
 
-                    decoded = wire.decode(raw)
+                    decode = wire.decode
                 elif fmt_in == "fb":
                     from surrealdb_tpu import fb
 
-                    decoded = fb.decode(raw)
+                    decode = fb.decode
                 else:
-                    decoded = json.loads(raw)
+                    decode = jsonnum.loads
+                decoded = _decode_body(decode, raw)
                 if not isinstance(decoded, dict):
                     # req stays {} so the error path can req.get("id")
                     raise SdbError("rpc request must be an object")
@@ -724,7 +737,7 @@ class SurrealHandler(BaseHTTPRequestHandler):
             jsonify = lambda v: v
         else:
             pack = json.dumps
-            unpack = lambda data: json.loads(data.decode())
+            unpack = jsonnum.loads
             jsonify = to_json
         encode = lambda payload: pack(jsonify(payload))  # a request's reply
 
@@ -784,8 +797,13 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     continue
                 if opcode not in (0x1, 0x2):
                     continue
+                # stage `request`, as `_dispatch_gated` records it: this
+                # thread does all of a WebSocket request's work, from
+                # the frame's decode on
+                t0 = time.perf_counter_ns()
+                cpu0 = time.thread_time_ns()
                 try:
-                    req = unpack(data)
+                    req = _decode_body(unpack, data)
                     if not isinstance(req, dict):
                         raise ValueError("request must be an object")
                 except Exception:
@@ -797,10 +815,6 @@ class SurrealHandler(BaseHTTPRequestHandler):
                     }))
                     continue
                 rid = req.get("id")
-                # stage `request`, as `_dispatch_gated` records it: this
-                # thread does all of a WebSocket request's work
-                t0 = time.perf_counter_ns()
-                cpu0 = time.thread_time_ns()
                 try:
                     # per-REQUEST admission + deadline: one connection
                     # cannot monopolize worker slots between queries,
@@ -873,6 +887,9 @@ def make_server(ds: Datastore, host="127.0.0.1", port=8000,
     from surrealdb_tpu.server.hangup import HangupWatch
     from surrealdb_tpu.server.stallwatch import StallWatch
 
+    # the JSON float parser is built (or found) now, not by the first
+    # request that decodes a body
+    jsonnum.load()
     if max_inflight is None:
         max_inflight = cnf.HTTP_MAX_INFLIGHT
     if queue_depth is None:
