@@ -42,10 +42,7 @@ BAG_PREWARM_RIDERS = 32
 
 
 def pow2_at_least(x: int) -> int:
-    p = 1
-    while p < x:
-        p *= 2
-    return p
+    return 1 << (max(int(x), 1) - 1).bit_length()
 
 
 @lru_cache(maxsize=256)

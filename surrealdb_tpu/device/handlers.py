@@ -20,6 +20,7 @@ from surrealdb_tpu import cnf
 MAX_VEC_STORES = 64
 MAX_CSR_STORES = 64
 MAX_ANN_STORES = 16
+MAX_FT_STORES = 16
 
 
 class DeviceBudgetError(RuntimeError):
@@ -64,6 +65,7 @@ class DeviceHost:
         self.vec: OrderedDict = OrderedDict()  # key -> (tag, VecStore)
         self.csr: OrderedDict = OrderedDict()  # key -> (tag, CsrStore)
         self.ann: OrderedDict = OrderedDict()  # key -> (tag, AnnStore)
+        self.ft: OrderedDict = OrderedDict()  # key -> (tag, FtStore)
         # multipart vec loads in flight: key -> (meta, vecs, valid).
         # Big stores (the 10M×768 regime is ~30 GB of f32 rows) ship as
         # begin/part.../end so no single frame has to hold the store.
@@ -109,7 +111,7 @@ class DeviceHost:
         plus multipart staging buffers (host-side in the runner, but
         they become device arrays at load_end — admitted up front)."""
         total = 0
-        for cache in (self.vec, self.csr, self.ann):
+        for cache in (self.vec, self.csr, self.ann, self.ft):
             for _tag, st in cache.values():
                 total += st.device_nbytes()
         for _m, vecs, valid in self._staging.values():
@@ -125,7 +127,7 @@ class DeviceHost:
         staging buffers + reservations) their whole estimate — the
         quantity the per-device budget admits against."""
         total = 0
-        for cache in (self.vec, self.csr, self.ann):
+        for cache in (self.vec, self.csr, self.ann, self.ft):
             for _tag, st in cache.values():
                 ndev = max(int(getattr(st, "mesh_ndev", 1) or 1), 1)
                 total += -(-st.device_nbytes() // ndev)
@@ -187,7 +189,7 @@ class DeviceHost:
         ship: a re-shipped store must never be refused because its own
         OUTDATED copy is counted against (and protected from) the
         budget."""
-        for cache in (self.vec, self.csr, self.ann):
+        for cache in (self.vec, self.csr, self.ann, self.ft):
             cache.pop(key, None)
 
     def _admit(self, incoming: int, keep_key: str = "", ndev: int = 1):
@@ -202,7 +204,7 @@ class DeviceHost:
         """Make room for `share` estimated device-0 bytes or raise
         DeviceBudgetError. Victims pop oldest-first within each cache
         (the per-kind OrderedDicts are LRU — every use move_to_end's),
-        in fixed kind order csr → vec → ann: ascending re-ship cost,
+        in fixed kind order csr → ft → vec → ann: ascending re-ship cost,
         since an evicted store only ever answers `stale` and gets
         re-shipped from KV truth. `keep_key` (the incoming store,
         whose old copy `_evict_key` already dropped) is never a
@@ -223,7 +225,7 @@ class DeviceHost:
             )
         while self.mem_used_device0() + share > self.budget_bytes:
             victim = None
-            for cache in (self.csr, self.vec, self.ann):
+            for cache in (self.csr, self.ft, self.vec, self.ann):
                 for key in cache:
                     if key != keep_key:
                         victim = (cache, key)
@@ -292,6 +294,7 @@ class DeviceHost:
             "vec_blocks": len(self.vec),
             "csr_blocks": len(self.csr),
             "ann_blocks": len(self.ann),
+            "ft_blocks": len(self.ft),
             "vec_bytes": sum(s.nbytes() for _t, s in self.vec.values()),
             "csr_bytes": sum(s.nbytes() for _t, s in self.csr.values()),
             "ann_bytes": sum(s.nbytes() for _t, s in self.ann.values()),
@@ -322,6 +325,8 @@ class DeviceHost:
             # the graph builds' kNN passes (kernelstats.GRAPH), and the
             # builds whose rows are on the device now
             "graph": dict(kernelstats.GRAPH, open=len(self._graphs)),
+            # the BM25 top-n riders over full-text blocks (kernelstats.FT)
+            "ft": dict(kernelstats.FT),
         }, []
 
     def op_profile(self, meta, bufs):
@@ -792,6 +797,46 @@ class DeviceHost:
                 b *= 2
 
         return self._prewarm_shapes(self.csr, meta, "hops", warm)
+
+    # -- full-text blocks (device/ftstore.py) ---------------------------------
+
+    def op_ft_load(self, meta, bufs):
+        """One index version's postings: `indptr`, `docs`, `tf`, `dl`
+        (int32), resident until the tag moves or the cache evicts it."""
+        from surrealdb_tpu.device.ftstore import FtStore
+
+        key = meta["key"]
+        st = FtStore(key, *bufs)
+        self._admit(st.device_nbytes(), keep_key=key)
+        st._ensure()
+        self.ft.pop(key, None)
+        self.ft[key] = (list(meta["tag"]), st)
+        while len(self.ft) > MAX_FT_STORES:
+            self.ft.popitem(last=False)
+        return "ok", {"rungs": list(st.rungs)}, []
+
+    def op_ft_bm25(self, meta, bufs):
+        """A batch of BM25 riders (device/ftstore.py): one int32 array
+        of riders a group, meta `groups` [[capacity, k], ...] beside them
+        and `consts` (k1, 1 - b, b / avg_len) of the store's version; one
+        packed int32 array back a group."""
+        ent = self.ft.get(meta["key"])
+        if ent is None or ent[0] != list(meta["tag"]):
+            return "stale", {}, []
+        self.ft.move_to_end(meta["key"])
+        return "ok", {}, [
+            ent[1].bm25(riders, meta["consts"], int(cap), int(k))
+            for riders, (cap, k) in zip(bufs, meta["groups"])]
+
+    def op_ft_prewarm(self, meta, bufs):
+        """The program of one shape (`cap`, `riders`, `terms`, `k`) of a
+        resident full-text block, compiled ahead of traffic."""
+        ent = self.ft.get(meta["key"])
+        if ent is None or ent[0] != list(meta["tag"]):
+            return "stale", {}, []
+        ent[1].warm(int(meta["terms"]), int(meta["k"]), int(meta["cap"]),
+                    int(meta["riders"]))
+        return "ok", {}, []
 
     def op_brute_knn(self, meta, bufs):
         """One-shot exact KNN over ephemeral rows (planner brute path —

@@ -88,6 +88,18 @@ APPEND = {"appends": 0, "rows": 0, "bytes": 0}
 # lint: mem-account(fixed-key int counters, not derived state)
 GRAPH = {"runs": 0, "calls": 0, "flops": 0.0, "bytes": 0.0,
          "device_ns": 0}
+# `ft_bm25`, the BM25 top-n over a resident full-text block
+# (device/ftstore.py, program `_bm25_impl`): `riders` answered and the
+# program calls they took (`dispatches`); `candidates`, the postings of
+# the riders' rarest terms (each read with its tf and its doc's length),
+# and `postings_read`, those plus every binary-search probe of the other
+# terms' slices, unpadded; `dense_bytes`, the dense tf rows and doc
+# lengths that riders on the dense rung read in place of a candidate list;
+# `overflows`, riders sent back because their rarest slice passed their
+# rung. `op_status` reports it as `ft`
+# lint: mem-account(fixed-key int counters, not derived state)
+FT = {"riders": 0, "dispatches": 0, "candidates": 0, "postings_read": 0,
+      "dense_bytes": 0, "overflows": 0}
 
 
 @contextmanager

@@ -136,6 +136,11 @@ class DeviceSupervisor:
             # graph builds whose kNN initialisation the runner computed
             # (`knn_graph`), and those that took the RP trees on the host
             "device_ann_device_builds": 0, "device_ann_host_builds": 0,
+            # full-text MATCHES legs: BM25 top-n served by the runner
+            # (`ft_bm25`, idx/ftdevice.py), answered by the host search,
+            # and the index versions shipped (`ft_load`)
+            "device_ft_device_searches": 0, "device_ft_host_searches": 0,
+            "device_ft_ships": 0,
         }
         # wall seconds spent shipping block caches to the runner
         self.ship_s = 0.0
@@ -225,6 +230,16 @@ class DeviceSupervisor:
         once a table version a metric, never with a query."""
         self.counters["device_col_ships"] += 1
         self.counters["device_col_ship_bytes"] += int(nbytes)
+
+    def note_ft_search(self, on_device: bool):
+        """One full-text MATCHES leg answered, by the runner's BM25
+        top-n or by the host search (idx/ftdevice.py, idx/fulltext.py)."""
+        self.counters["device_ft_device_searches" if on_device
+                      else "device_ft_host_searches"] += 1
+
+    def note_ft_ship(self):
+        """A full-text index version is about to be shipped."""
+        self.counters["device_ft_ships"] += 1
 
     def ensure_started(self):
         """Kick the async first spawn (idempotent, never blocks)."""
@@ -642,6 +657,9 @@ class DeviceSupervisor:
             "vec_full_ships": self.counters["device_vec_full_ships"],
             "ann_device_builds": self.counters["device_ann_device_builds"],
             "ann_host_builds": self.counters["device_ann_host_builds"],
+            "ft_device_searches": self.counters["device_ft_device_searches"],
+            "ft_host_searches": self.counters["device_ft_host_searches"],
+            "ft_ships": self.counters["device_ft_ships"],
             "last_error": self.last_error,
             "vec_blocks": sum(1 for k in loaded if k.startswith("vec/")),
             "csr_blocks": sum(1 for k in loaded if k.startswith("csr/")),
@@ -1371,7 +1389,8 @@ def attach_telemetry(telemetry):
                  "device_col_ship_bytes", "device_vec_appends",
                  "device_vec_append_rows", "device_vec_append_bytes",
                  "device_vec_full_ships", "device_ann_device_builds",
-                 "device_ann_host_builds"):
+                 "device_ann_host_builds", "device_ft_device_searches",
+                 "device_ft_host_searches", "device_ft_ships"):
         telemetry.register_gauge(
             name, lambda n=name: get_supervisor().counters.get(n, 0)
         )

@@ -2,8 +2,14 @@
 fulltext.rs Bm25Params/Scorer, analyzer/ tokenizers+filters).
 
 Postings live in KV under index-state keys: per-term doc maps with term
-frequencies and offsets; doc lengths and corpus stats alongside. BM25 at
-query time; hybrid rerank composes with the vector engine via search::rrf.
+frequencies and offsets; doc lengths and corpus stats alongside. Where BM25
+is scored: a single AND MATCHES whose SELECT projects `id` and
+`search::score` only and orders by that score descending with a LIMIT
+(`device_leg_for`) is scored on the device runner from the index's postings
+resident as CSR, and rescored in f64 on the host (idx/ftdevice.py,
+device/ftstore.py); every other shape here, by `_ft_search_impl` on the host
+(counted as `ft_host_searches`). Hybrid rerank composes with the vector
+engine via search::rrf.
 """
 
 from __future__ import annotations
@@ -403,6 +409,7 @@ def ft_result(idef, query: str, ctx, boolean: str = "AND") -> FtResult:
           tuple(idef.cols_str or ()))
     gk = (ns, db, tb, ix, query, boolean, fp)
     ent = cache.get(gk)
+    t0 = time.perf_counter_ns()
     if ent is not None and ent[0] == ver:
         res = ent[1]
     else:
@@ -419,7 +426,39 @@ def ft_result(idef, query: str, ctx, boolean: str = "AND") -> FtResult:
                 or not _txn_wrote(ctx.txn, _ver_key(ns, db, tb, ix)):
             cache.put(gk, (ver, res), cost=res.cost_bytes())
     ctx.record_cache[ck] = res
+    _note_host_search(time.perf_counter_ns() - t0)
     return res
+
+
+def _note_host_search(ns: int):
+    """A MATCHES leg answered by the host (its result computed or
+    taken from the cache): stage `ft_search` and the supervisor's
+    `ft_host_searches`, unless the device is switched off."""
+    from surrealdb_tpu.device import get_supervisor
+    from surrealdb_tpu.telemetry import stage_record
+
+    stage_record("ft_search", ns)
+    sup = get_supervisor()
+    if sup.mode != "off":
+        sup.note_ft_search(on_device=False)
+
+
+def device_leg_for(mt, idef, query: str, cond, ctx, stmt):
+    """The device's top-n result for this MATCHES node, or None where
+    the host answers: the node must be the whole condition of a SELECT
+    whose projection reads only `id` and `search::score` and that orders
+    by that score descending with a LIMIT and no START (`_ft_order_limit`;
+    idx/ftdevice.py serves it)."""
+    if stmt is None or cond is not mt \
+            or getattr(stmt, "with_index", None) is not None \
+            or not _score_only_projection(stmt, ctx):
+        return None
+    n = _ft_order_limit(stmt, [mt], ctx)
+    if n is None:
+        return None
+    from surrealdb_tpu.idx.ftdevice import device_leg
+
+    return device_leg(idef, mt, query, ctx, n)
 
 
 def ft_search(idef, query: str, ctx, boolean: str = "AND"):

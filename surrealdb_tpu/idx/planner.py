@@ -683,7 +683,7 @@ def _choose_index(indexes, eqs, ins, rngs, model="streaming"):
     return best[1], best[2], best[3], best[0][0]
 
 
-def _register_match_contexts(tb, cond, ctx):
+def _register_match_contexts(tb, cond, ctx, stmt=None):
     """The reference's QueryExecutor registers score/offset contexts for
     every indexed MATCHES in the cond even when the plan falls back to a
     table iterator (idx/planner/executor.rs QueryExecutor::new walks all
@@ -704,7 +704,7 @@ def _register_match_contexts(tb, cond, ctx):
     if not nodes:
         return
     from surrealdb_tpu.exec.eval import evaluate
-    from surrealdb_tpu.idx.fulltext import ft_result
+    from surrealdb_tpu.idx.fulltext import device_leg_for, ft_result
 
     indexes = get_indexes_for(tb, ctx)
     ft_ctx = dict(ctx.vars.get("__ft__") or {})
@@ -726,7 +726,9 @@ def _register_match_contexts(tb, cond, ctx):
             # the node-keyed entry below keeps membership exact per node
             # (plan_matches still rejects duplicates among AND-planned
             # matches, matching the reference's executor error)
-        res = ft_result(idef, q, ctx, boolean=mt.boolean)
+        res = device_leg_for(mt, idef, q, cond, ctx, stmt)
+        if res is None:
+            res = ft_result(idef, q, ctx, boolean=mt.boolean)
         entry = {
             "scores": res.scores,
             "offsets": res.offsets,
@@ -757,7 +759,7 @@ def plan_scan(tb: str, cond, ctx, stmt):
         with_index = getattr(stmt, "with_index", None) \
             if stmt is not None else None
         if with_index != []:
-            _register_match_contexts(tb, cond, ctx)
+            _register_match_contexts(tb, cond, ctx, stmt)
     try:
         return _plan_scan(tb, cond, ctx, stmt)
     finally:

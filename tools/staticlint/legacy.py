@@ -95,6 +95,7 @@ _MEM_ALLOW = {
     ("surrealdb_tpu/device/handlers.py", "vec"),
     ("surrealdb_tpu/device/handlers.py", "csr"),
     ("surrealdb_tpu/device/handlers.py", "ann"),
+    ("surrealdb_tpu/device/handlers.py", "ft"),
     ("surrealdb_tpu/device/handlers.py", "_staging"),
     ("surrealdb_tpu/device/handlers.py", "_ann_staging"),
     ("surrealdb_tpu/device/handlers.py", "_reserved"),
